@@ -9,8 +9,8 @@ backend-aware probe shifts).  Known-infeasible combinations are listed
 as such rather than skipped silently.
 
 Writes bench_rules_256.json (appends nothing; full rewrite per run).
-Chip-gated: refuses to run on the CPU fallback (minutes/round at this N
-tells nothing).
+Chip or fail: exits 2 unless ``jax.devices()`` is a TPU.  A rule that does
+not fit or run is recorded as such and the script then exits non-zero.
 """
 
 import json
@@ -49,8 +49,7 @@ def cfg(algo, params, exchange):
         "model": {"factory": "examples.leaf.LEAFFEMNISTModel", "params": {}},
         "backend": "tpu",
         "tpu": {"num_devices": 1, "compute_dtype": "bfloat16",
-                 "param_dtype": "bfloat16", "exchange": exchange,
-                 "compilation_cache_dir": "/tmp/murmura_jax_cache"},
+                 "param_dtype": "bfloat16", "exchange": exchange},
     }
     if algo == "evidential_trust":
         raw["model"]["params"] = {"evidential": True}
@@ -60,11 +59,10 @@ def cfg(algo, params, exchange):
 def main():
     import jax
 
-    if jax.default_backend() == "cpu":
-        raise SystemExit("chip-gated: refusing to run on the CPU fallback")
+    from bench import require_chip
     from murmura_tpu.utils.factories import build_network_from_config
 
-    device_kind = jax.devices()[0].device_kind
+    device = require_chip("bench_rules_256")
     results = {}
     for algo, params, exch in CASES:
         tag = f"{algo}/{exch}"
@@ -94,12 +92,20 @@ def main():
             net = None
         print(tag, results[tag], flush=True)
 
-    blob = {"device_kind": device_kind, "nodes": 256, "results": results}
+    blob = {
+        "platform": device.platform,
+        "device_kind": device.device_kind,
+        "device_count": len(jax.devices()),
+        "nodes": 256,
+        "results": results,
+    }
     Path(__file__).with_name("bench_rules_256.json").write_text(
         json.dumps(blob, indent=2) + "\n"
     )
     print(json.dumps({k: v.get("rounds_per_sec", "FAIL")
                       for k, v in results.items()}))
+    if not all(v["ok"] for v in results.values()):
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

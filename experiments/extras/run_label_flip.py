@@ -64,7 +64,6 @@ def run_cfg(cfg: dict, tag: str) -> dict:
         out_path = Path(td) / f"{tag}.json"
         cfg_path.write_text(yaml.safe_dump(cfg))
         env = dict(os.environ)
-        env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/murmura_jax_cache")
         proc = subprocess.run(
             [sys.executable, "-m", "murmura_tpu", "run", str(cfg_path),
              "-o", str(out_path)],

@@ -60,7 +60,6 @@ def run_cfg(cfg: dict, tag: str) -> dict:
         env = dict(os.environ)
         # Same persistent compile cache as the paper runner: runs sharing
         # a program shape compile once (one shape per rule x scenario).
-        env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/murmura_jax_cache")
         proc = subprocess.run(
             [sys.executable, "-m", "murmura_tpu", "run", str(cfg_path),
              "-o", str(out_path)],

@@ -37,7 +37,6 @@ def run_one(name: str, device: str, timeout: float) -> dict:
     out = DMTT_DIR / "results" / f"{name}.json"
     out.parent.mkdir(exist_ok=True)
     env = dict(os.environ)
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/murmura_jax_cache")
     cmd = [sys.executable, "-m", "murmura_tpu", "run",
            str(DMTT_DIR / f"{name}.yaml"), "-o", str(out), "--quiet"]
     if device:

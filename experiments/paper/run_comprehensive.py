@@ -33,11 +33,11 @@ def run_one(cfg_path: Path, out_json: Path, timeout: float,
     """Run one experiment through the CLI; returns a result record."""
     t0 = time.time()
     record = {"config": str(cfg_path.relative_to(CONFIG_DIR))}
-    # Persistent XLA compilation cache: the matrix reuses a handful of
+    # Each child applies the one compile-cache rule itself
+    # (factories.apply_compilation_cache): the matrix reuses a handful of
     # program shapes across hundreds of subprocesses, so all but the first
     # few runs skip compilation entirely.
     env = dict(os.environ)
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/murmura_jax_cache")
     cmd = [sys.executable, "-m", "murmura_tpu", "run", str(cfg_path),
            "-o", str(out_json), "--quiet"]
     if device:
@@ -148,8 +148,10 @@ def main():
                     help="Force the JAX platform for every run (a single "
                          "TPU chip runs the matrix serially: --jobs 1)")
     args = ap.parse_args()
-    if args.device == "tpu" and args.jobs > 1:
-        sys.exit("--device tpu requires --jobs 1 (single-tenant chip)")
+    if args.jobs > 1 and args.device != "cpu":
+        # A chip belongs to one process: concurrent children may only run
+        # where the platform is pinned to the CPU.
+        sys.exit("--jobs > 1 requires --device cpu (one process per chip)")
 
     RESULTS_DIR.mkdir(parents=True, exist_ok=True)
     results_file = RESULTS_DIR / "results.json"

@@ -275,10 +275,20 @@ def pairwise_l2_distances(
         da, db = a32.astype(in_dtype), b32.astype(in_dtype)
     else:
         da, db = a32, b32
+    # The Gram runs at the MXU's default precision whatever the ambient
+    # jax.default_matmul_precision: the identity cancels the dot against
+    # the f32 VPU norms above, and on a v5e a "high"/"highest" [N, 6.6M]
+    # dot disagrees with those norms by 5e-4 of their value (measured, PR
+    # 22) — under an attack whose noise inflates the centred norms that is
+    # several times d2 itself, every d2 clamps to 0 and every score with it.
+    # The default-precision dot agrees with the norms to 3e-6.
     d2 = (
         sq_a[:, None]
         + sq_b[None, :]
-        - 2.0 * jnp.dot(da, db.T, preferred_element_type=jnp.float32)
+        - 2.0 * jnp.dot(
+            da, db.T, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT,
+        )
     )
     return jnp.sqrt(jnp.maximum(d2, 0.0))
 

@@ -21,7 +21,6 @@ workflow is: run ``--update-budgets``, review the diff, commit.
 import contextlib
 import json
 import math
-import os
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -50,11 +49,8 @@ PALLAS_BUDGET_RULES: Tuple[str, ...] = ("krum", "median", "trimmed_mean")
 
 
 def normalize_cost_analysis(cost) -> Dict[str, float]:
-    """Flatten the cross-version shapes of ``Compiled.cost_analysis()``
-    (older jax returns ``[dict]``, newer a plain dict, either may be empty)
-    into one dict.  Shared with ``Network.step_cost_analysis``."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
+    """``Compiled.cost_analysis()`` as a plain dict (it may be None or
+    empty).  Shared with ``Network.step_cost_analysis``."""
     return dict(cost or {})
 
 
@@ -120,35 +116,6 @@ def measure_cell(
     }
 
 
-def apply_persistent_cache() -> Optional[str]:
-    """Honor JAX's persistent compilation cache for the AOT budget sweep.
-
-    The sweep compiles every (rule x n x dim x mode) grid cell; on a repeat
-    ``check --ir`` run (the battery pre-flight, CI, a `--update-budgets`
-    after review) each identical XLA compile becomes a disk hit instead of
-    seconds of compilation.  The cache dir comes from the
-    ``MURMURA_COMPILATION_CACHE_DIR`` env var — the process-level twin of
-    ``tpu.compilation_cache_dir``, exported by
-    ``factories.apply_compilation_cache`` when a config sets it (so
-    ``murmura run`` and the check sweep in one battery share one cache)
-    and by ``run_tpu_battery.sh``.  Returns the applied dir, or None.
-    """
-    cache_dir = os.environ.get("MURMURA_COMPILATION_CACHE_DIR")
-    if not cache_dir:
-        return None
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # The default minimum compile time gates tiny programs out of the
-    # cache; the budget cells are exactly such small programs, so cache
-    # them regardless of how fast they compile.
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except AttributeError:  # older jax without the knob
-        pass
-    return cache_dir
-
-
 _MEASURE_MEMO: Optional[Dict[str, Dict[str, float]]] = None
 
 
@@ -163,7 +130,9 @@ def measure_all(force: bool = False) -> Dict[str, Dict[str, float]]:
     from murmura_tpu.analysis import ir
 
     ir._ensure_host_devices()
-    apply_persistent_cache()
+    from murmura_tpu.utils.factories import apply_compilation_cache
+
+    apply_compilation_cache()
     out: Dict[str, Dict[str, float]] = {}
     for name in sorted(AGGREGATORS):
         if name not in ir.AGG_CASES:

@@ -132,11 +132,11 @@ def _quiet_tracing():
 
 def _closed(sub) -> Any:
     """Normalize an eqn param that holds a jaxpr into a ClosedJaxpr."""
-    import jax
+    from jax.extend import core as jex_core
 
-    if isinstance(sub, jax.core.ClosedJaxpr):
+    if isinstance(sub, jex_core.ClosedJaxpr):
         return sub
-    return jax.core.ClosedJaxpr(sub, ())
+    return jex_core.ClosedJaxpr(sub, ())
 
 
 def _sub_jaxpr(eqn):
@@ -151,15 +151,12 @@ def _sub_jaxpr(eqn):
 def eqn_source(eqn) -> Optional[Tuple[str, int]]:
     """(path, line) of the user frame that created this equation, if the
     traceback survived tracing (it does for normal python-traced code)."""
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is None:
-            return None
-        return str(frame.file_name), int(frame.start_line)
-    except Exception:  # noqa: BLE001 — source info is best-effort
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
         return None
+    return str(frame.file_name), int(frame.start_line)
 
 
 # --------------------------------------------------------------------------
@@ -225,9 +222,9 @@ class TaintEval:
             env[var] = pair
 
         def read(atom):
-            import jax
+            from jax.extend import core as jex_core
 
-            if isinstance(atom, jax.core.Literal):
+            if isinstance(atom, jex_core.Literal):
                 v = np.asarray(atom.val)
                 return v, _tz(self.L, v.shape)
             return env[atom]
@@ -362,6 +359,16 @@ class TaintEval:
             slice(s, l, st) for s, l, st in zip(starts, limits, strides)
         )
         return [(outs[0], t[sl])]
+
+    def _t_split(self, eqn, pairs):
+        # jnp.split traces to one multi-result ``split`` in the installed
+        # JAX (it was a run of ``slice``s): each piece keeps its own
+        # elements' taint.
+        (v, t), = pairs
+        outs = self._concrete(eqn, [v])
+        cuts = np.cumsum(eqn.params["sizes"])[:-1]
+        pieces = np.split(t, cuts, axis=eqn.params["axis"] + 1)
+        return list(zip(outs, pieces))
 
     def _t_concatenate(self, eqn, pairs):
         outs = self._concrete(eqn, [p[0] for p in pairs])
@@ -788,9 +795,9 @@ class IntervalEval:
             env[var] = dataclasses.replace(v, ids=v.ids | {id(var)})
 
         def read(atom) -> IVal:
-            import jax
+            from jax.extend import core as jex_core
 
-            if isinstance(atom, jax.core.Literal):
+            if isinstance(atom, jex_core.Literal):
                 a = np.asarray(atom.val)
                 if a.size == 0:
                     return _iv(0.0, 0.0)
@@ -853,11 +860,11 @@ class IntervalEval:
         survive value-CHANGING ops like reduce_max/floor, so using them
         here would constant-fold ``x == max(x)``-style data-dependent
         masks — verified unsound.)"""
-        import jax
+        from jax.extend import core as jex_core
 
         return (
             len(eqn.invars) == 2
-            and not isinstance(eqn.invars[0], jax.core.Literal)
+            and not isinstance(eqn.invars[0], jex_core.Literal)
             and eqn.invars[0] is eqn.invars[1]
         )
 
@@ -1115,6 +1122,10 @@ class IntervalEval:
 
     def _i_concatenate(self, eqn, ins):
         return [self._join(ins)]
+
+    def _i_split(self, eqn, ins):
+        # Every piece is a shape-only view of the operand (_iv_view).
+        return [ins[0]] * len(eqn.outvars)
 
     def _i_dynamic_update_slice(self, eqn, ins):
         return [self._join(ins[:2])]

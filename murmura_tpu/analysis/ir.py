@@ -110,6 +110,48 @@ _COLL_RE = re.compile(
 
 _ALIAS_RE = re.compile(r"\b(?:may|must)-alias\b")
 
+# `= <result shape(s)> all-reduce(`: the statement whose OPCODE is an
+# all-reduce, with its result shapes captured.
+_ALL_REDUCE_STMT_RE = re.compile(
+    r"=\s*(\S.*?)\s(all-reduce(?:-start)?)\(", re.MULTILINE
+)
+_ANY_SHAPE_RE = re.compile(r"\b([a-z]+[0-9]*)\[([0-9,]*)\]")
+
+# The one all-reduce traffic MUR303 licenses in the faulted round: its two
+# scalar fault metrics, ``agg_alive`` and ``agg_quarantined`` (the
+# ``alive.sum()`` / quarantined count of core/rounds.py), one rank-0 f32
+# each.  This XLA spells a scalar sum over the sharded node axis as an
+# all-reduce (and fuses the two into one tuple op); the last one spelled it
+# all-gather + local reduce, inside the ``all_gather`` every round has.
+FAULT_METRIC_ALL_REDUCES = ("f32[]", "f32[]")
+
+
+def all_reduce_results(hlo_text: str) -> tuple:
+    """Sorted ``dtype[dims]`` of every result of every all-reduce statement
+    in an HLO module (tuple results flattened)."""
+    return tuple(sorted(
+        f"{dtype}[{dims}]"
+        for result, _op in _ALL_REDUCE_STMT_RE.findall(hlo_text)
+        for dtype, dims in _ANY_SHAPE_RE.findall(result)
+    ))
+
+
+def collective_names(hlo_text: str, licensed_all_reduces: tuple = ()) -> frozenset:
+    """Canonical names of the collectives in an HLO module, by op name.
+
+    ``licensed_all_reduces``: the exact multiset of all-reduce results
+    (:func:`all_reduce_results`) the caller's contract allows by count,
+    dtype and shape.  Only when the module's all-reduces are exactly those
+    is ``all_reduce`` left out; one more scalar, another dtype (a
+    ``pred[]`` any-non-finite sync) or any row-shaped result keeps the
+    name in the inventory, where the caller's comparison finds it."""
+    names = {_HLO_COLLECTIVES[m] for m in _COLL_RE.findall(hlo_text)}
+    if licensed_all_reduces and all_reduce_results(hlo_text) == tuple(
+        sorted(licensed_all_reduces)
+    ):
+        names.discard("all_reduce")
+    return frozenset(names)
+
 
 # Registry of round-program-level check families ``check_ir`` runs after
 # the per-rule canonical sweep: name -> (callable, crash rule id, crash
@@ -452,6 +494,12 @@ def _mode(circulant: bool) -> str:
     return "circulant" if circulant else "dense"
 
 
+def is_host_callback(primitive_name: str) -> bool:
+    """pure_callback / io_callback / debug_callback, and ``debug_print`` —
+    the primitive ``jax.debug.print`` traces to in the installed JAX."""
+    return "callback" in primitive_name or primitive_name == "debug_print"
+
+
 def _check_callbacks(name: str, prog: CanonicalProgram, jaxpr) -> List[Finding]:
     """MUR200: host callback primitives in the aggregation jaxpr."""
     path, line = _rule_anchor(name)
@@ -459,7 +507,7 @@ def _check_callbacks(name: str, prog: CanonicalProgram, jaxpr) -> List[Finding]:
         {
             eqn.primitive.name
             for eqn in iter_eqns(jaxpr)
-            if "callback" in eqn.primitive.name
+            if is_host_callback(eqn.primitive.name)
         }
     )
     if not found:
@@ -688,7 +736,10 @@ def check_fault_round() -> List[Finding]:
     sharding the faulted round over a node mesh must lower to exactly the
     collective inventory of the unfaulted round — the sentinel's
     isfinite/where/rollback plumbing is elementwise over node-local rows
-    and may not grow cross-device communication.
+    and may not grow cross-device communication.  One exception, licensed
+    by count, dtype and shape (:data:`FAULT_METRIC_ALL_REDUCES`): the
+    round's two scalar fault metrics are summed over the node axis; any
+    further all-reduce, scalar or not, is a finding.
     """
     import jax
     import jax.numpy as jnp
@@ -785,15 +836,15 @@ def check_fault_round() -> List[Finding]:
     mesh = Mesh(np.array(devices[: max(usable)]), ("nodes",))
     node_s = NamedSharding(mesh, P("nodes"))
 
-    def inventory(prog):
+    def inventory(prog, licensed_all_reduces=()):
         sharded = _shard_round_fn(
             prog.train_step, prog, mesh, node_s, donate=False,
             alive_sharding=node_s,
         )
         txt = sharded.lower(*args_for(prog, masks[1], 1)).compile().as_text()
-        return frozenset(_HLO_COLLECTIVES[m] for m in _COLL_RE.findall(txt))
+        return collective_names(txt, licensed_all_reduces)
 
-    stray = inventory(faulted) - inventory(base)
+    stray = inventory(faulted, FAULT_METRIC_ALL_REDUCES) - inventory(base)
     if stray:
         findings.append(Finding(
             "MUR303", anchor, 1,
@@ -1195,19 +1246,24 @@ _COMPRESS_BLOCK = 64
 
 # Only lines whose OPCODE is a collective (`= <shape> <op>(...)`), not
 # every line that references a collective's result name as a fusion
-# operand; the operand shapes inside the parens are what crosses the wire.
+# operand.  The capture starts at the RESULT shape: this XLA prints operands
+# by name only (`collective-permute(%slice.1)`), so the result shape is the
+# one place the moved dtype and width are written — equal to the operand's
+# for a permute/all-to-all, its gathered/scattered twin otherwise (the
+# exchanged width P survives in both).
 _COLL_OP_LINE_RE = re.compile(
-    r"^.*=\s*\S+\s+(?:collective-permute|all-gather|all-to-all|"
-    r"reduce-scatter)(?:-start)?\((.*)$",
+    r"^.*?=\s*(\S.*?\s(?:collective-permute|all-gather|all-to-all|"
+    r"reduce-scatter)(?:-start)?\(.*)$",
     re.MULTILINE,
 )
 _FLOAT_SHAPE_RE = re.compile(r"\b(f32|bf16|f64)\[([0-9,]*)\]")
 
 
 def float_exchange_operands(hlo_text: str, width: int):
-    """(offending floats, collective operand strings) of an HLO module:
+    """(offending floats, collective statements) of an HLO module:
     floating shapes of exchanged width (any dim >= ``width`` — boundary
-    roll slices are [o, P]) appearing in collective ops.  The MUR700 scan,
+    roll slices are [o, P]) appearing as the result or an operand of a
+    collective op.  The MUR700 scan,
     factored out so its negatives are unit-testable
     (tests/test_analysis_ir.py)."""
     coll_lines = _COLL_OP_LINE_RE.findall(hlo_text)

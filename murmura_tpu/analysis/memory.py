@@ -11,7 +11,7 @@ evidence, the way MUR206 made FLOPs/bytes reviewable perf history:
 - **MUR1500 — peak-HBM accounting.**  Every (rule x dense/circulant/
   sparse x plain/int8+EF/stale/pipeline) round-program cell is
   AOT-lowered and ``compile().memory_analysis()`` (temp/argument/output/
-  generated, normalized across jax versions by
+  generated, flattened by
   :func:`normalize_memory_analysis` — the memory twin of
   ``normalize_cost_analysis``) is gated against the committed
   ``analysis/MEMORY.json`` within tolerance.  A change that silently
@@ -54,8 +54,8 @@ Every contract shares ONE memoized AOT compile per grid cell
 alias header, MUR1503 its optimized HLO — the new family costs one
 compile sweep, not three (the flow-memoization precedent from PR 8, and
 the same sharing `budgets.compiled_cell` / `Network.step_memory_analysis`
-apply on their grids).  The sweep honors the persistent compilation cache
-(``MURMURA_COMPILATION_CACHE_DIR``), so battery re-runs are disk hits.
+apply on their grids).  The sweep uses the persistent compilation cache
+(``factories.apply_compilation_cache``).
 """
 
 import contextlib
@@ -146,26 +146,19 @@ _MEMORY_FIELDS: Tuple[Tuple[str, str], ...] = (
 
 
 def normalize_memory_analysis(mem) -> Dict[str, float]:
-    """Flatten the cross-version shapes of ``Compiled.memory_analysis()``
-    (a ``CompiledMemoryStats`` object, a dict on some builds, a list on
-    multi-device executables, or None) into one flat dict.  Shared with
-    ``Network.step_memory_analysis`` and the bench ``memory{}`` blocks.
+    """Flatten ``Compiled.memory_analysis()`` (a ``CompiledMemoryStats``
+    object, or None where the backend reports nothing) into one flat dict.
+    Shared with ``Network.step_memory_analysis`` and the bench
+    ``memory{}`` blocks.
 
     ``peak_bytes`` is the derived live-footprint bound XLA does not
     expose directly: arguments + outputs - aliased (donated buffers are
     counted once) + temporaries + generated code.
     """
-    if isinstance(mem, (list, tuple)):
-        mem = mem[0] if mem else None
-    out: Dict[str, float] = {}
-    for key, attr in _MEMORY_FIELDS:
-        if mem is None:
-            val = 0.0
-        elif isinstance(mem, dict):
-            val = mem.get(key, mem.get(attr, 0.0))
-        else:
-            val = getattr(mem, attr, 0.0)
-        out[key] = float(val or 0.0)
+    out: Dict[str, float] = {
+        key: float(getattr(mem, attr, 0.0) or 0.0)
+        for key, attr in _MEMORY_FIELDS
+    }
     out["peak_bytes"] = (
         out["argument_bytes"] + out["output_bytes"] - out["alias_bytes"]
         + out["temp_bytes"] + out["generated_bytes"]
@@ -320,12 +313,12 @@ def cell_artifacts(rule: str, topo: str, feature: str):
     honors the persistent compilation cache."""
     import jax
 
-    from murmura_tpu.analysis.budgets import apply_persistent_cache
+    from murmura_tpu.utils.factories import apply_compilation_cache
 
     key = (rule, topo, feature)
     if key in _CELL_MEMO:
         return _CELL_MEMO[key]
-    apply_persistent_cache()
+    apply_compilation_cache()
     prog, args = build_memory_cell(rule, topo, feature)
     dev = _cpu_device()
     cm = (

@@ -158,7 +158,8 @@ def _train_with_retries(orchestrator, train, *, retries, config,
 
 def _enforce_require_tpu(config, require_tpu_flag: bool) -> None:
     """The --require-tpu / durability.require_tpu / MURMURA_REQUIRE_TPU=1
-    hard-fail: abort loudly instead of silently falling back to CPU."""
+    hard-fail: abort loudly instead of running on a device that is not
+    a TPU."""
     from murmura_tpu.durability.dispatch import (
         BackendRequirementError,
         require_tpu,
@@ -196,12 +197,12 @@ def _enforce_require_tpu(config, require_tpu_flag: bool) -> None:
                    "appends; default: durability.resume)")
 @click.option("--require-tpu", is_flag=True, default=False,
               help="Abort loudly unless the default JAX backend is a TPU "
-                   "— replaces the silent CPU fallback. Env twin: "
+                   "— never run elsewhere under its name. Env twin: "
                    "MURMURA_REQUIRE_TPU=1; config twin: "
                    "durability.require_tpu")
 @click.option("--retries", type=int, default=None,
               help="Retry the training dispatch on classified-transient "
-                   "errors (device/tunnel), restoring from the last "
+                   "errors (device/transport), restoring from the last "
                    "snapshot with exponential backoff + jitter. Requires "
                    "--checkpoint-dir. Default: durability.retries")
 @click.option("--device", type=click.Choice(["cpu", "tpu"]), default=None,

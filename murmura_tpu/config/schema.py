@@ -429,7 +429,7 @@ class DurabilityConfig(_Strict):
         default=0, ge=0,
         description=(
             "Transient-error retries for the training dispatch: on a "
-            "classified-transient failure (device/tunnel/transport — "
+            "classified-transient failure (device/transport — "
             "durability/dispatch.py) the run restores from its last "
             "snapshot and retries with exponential backoff + jitter.  "
             "Requires checkpoint_dir (retrying consumed/donated buffers "
@@ -1069,14 +1069,6 @@ class TPUConfig(_Strict):
     )
     donate_state: bool = Field(
         default=True, description="Donate round-step input buffers to XLA"
-    )
-    compilation_cache_dir: Optional[str] = Field(
-        default=None,
-        description=(
-            "Enable JAX's persistent compilation cache at this path: "
-            "recompiles of an identical round program (across runs and "
-            "processes) become disk hits instead of 10-60s XLA compiles."
-        ),
     )
     rounds_per_dispatch: int = Field(
         default=1,

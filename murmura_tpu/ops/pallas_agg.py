@@ -1,8 +1,8 @@
 """Pallas TPU kernels for the aggregation hot loop: fused distance
 accumulation and candidate selection (docs/PERFORMANCE.md).
 
-BENCH_r02 pins the round at ~1.4% MFU — exchange/aggregation-bound, not
-FLOP-bound.  The aggregation hot loop's HBM traffic is dominated by
+The round is exchange/aggregation-bound, not FLOP-bound.  The
+aggregation hot loop's HBM traffic is dominated by
 re-reading the [N, P] broadcast tensor: the circulant distance pass reads
 it once per offset (k rolled passes), and the candidate-stack rules
 materialize rolled copies before sorting.  These kernels stream the
@@ -28,10 +28,12 @@ read:
 
 Deployment contract (mirrors ``ops/pallas_sketch.py``):
 
-- ``interpret=True`` on non-TPU backends, automatically — the tier-1 suite
-  (pinned to CPU) runs every kernel through the Pallas interpreter, so
-  parity with the lax reference path is tested everywhere
-  (tests/test_pallas_agg.py).
+- ``interpret=True`` exactly when the default backend is not a TPU — the
+  tier-1 suite (pinned to CPU) runs every kernel through the Pallas
+  interpreter, so parity with the lax reference path is tested everywhere
+  (tests/test_pallas_agg.py); on a TPU the kernels are always compiled
+  (``chip_smoke.py`` asserts ``tpu_custom_call`` in the compiled round,
+  tests/test_chip_compile.py compiles them for a described v5e).
 - Opt-in via ``tpu.pallas_agg: true`` (or ``MURMURA_PALLAS_AGG=1``), wired
   by the factories as an aggregator param; off by default.  Sharded-axis
   policy (precise, per entry point): a sharded **nodes** axis is refused
@@ -45,9 +47,13 @@ Deployment contract (mirrors ``ops/pallas_sketch.py``):
   (both axes sharded, a width the shard count does not divide) falls back
   to lax by returning ``None``.
 - Each entry point returns ``None`` when the shapes fall outside the
-  kernel's support envelope (tiling alignment on a real TPU, VMEM budget);
+  kernel's support envelope — compiled mode needs the resident node dim
+  ``N % 128 == 0`` (in-kernel rolls wrap at the block's row count and N
+  is the lane dim of the [k, N]/[N, M] outputs), plus the VMEM budget;
   callers (aggregation/base.py) fall back to the lax path, so enabling the
-  toggle is always safe.
+  toggle is always safe.  Every such refusal raises a
+  :class:`PallasEnvelopeWarning` naming the kernel and the shape that fell
+  out, so an armed toggle never runs lax without a word.
 - Parity is to documented tolerance, not bit-exact: the kernels accumulate
   chunk sums in float32 like the lax kernels but group them differently,
   and candidate stacks are compared/summed in f32 before the final cast.
@@ -58,7 +64,7 @@ fused formulation is committed, reviewable perf history.
 """
 
 import functools
-import os
+import warnings
 from typing import Optional, Sequence
 
 import jax
@@ -73,6 +79,23 @@ _VMEM_BLOCK_BYTES = 4 * 1024 * 1024
 # Hard cap on the resident accumulator (pairwise kernel holds [N, M] f32
 # in VMEM for the whole sweep).
 _MAX_PAIRWISE_CELLS = 1024 * 1024
+
+
+class PallasEnvelopeWarning(UserWarning):
+    """A requested aggregation kernel fell outside its envelope and the
+    caller runs the lax path instead."""
+
+
+def _refuse(kernel: str, shapes, reason: str) -> None:
+    """Report an out-of-envelope request (trace time) and return ``None``
+    for the caller's lax fallback."""
+    warnings.warn(
+        f"pallas_agg.{kernel}: {reason}; shapes "
+        f"{[tuple(s) for s in shapes]} run the lax path",
+        PallasEnvelopeWarning,
+        stacklevel=3,
+    )
+    return None
 
 
 def _sharded_axis_mode():
@@ -107,7 +130,6 @@ def _param_shard_map(fn, mesh, n_in: int, reduce_out: bool):
     ``psum``s over the param groups (distance accumulations: the one
     small scalar collective of the sharded-P contract) or stays a
     column-sharded map (candidate selection)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     col = P(None, "param")
@@ -118,12 +140,12 @@ def _param_shard_map(fn, mesh, n_in: int, reduce_out: bool):
             out = jax.lax.psum(out, "param")
         return out
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(col,) * n_in,
         out_specs=P() if reduce_out else col,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -134,10 +156,8 @@ def _param_shards_of(mesh) -> int:
 
 
 def _interpret_default() -> bool:
-    """Interpreter mode everywhere but a real TPU (the test-suite path);
-    MURMURA_PALLAS_INTERPRET=1 forces it for on-chip debugging."""
-    if os.environ.get("MURMURA_PALLAS_INTERPRET") == "1":
-        return True
+    """Interpreter mode exactly when there is no TPU (the test-suite
+    path): nothing can put a TPU run into interpret mode unnoticed."""
     return jax.default_backend() != "tpu"
 
 
@@ -149,19 +169,16 @@ def _chunk_cols(n_rows: int, p: int, copies: int) -> int:
     return min(c, max(128, (-(-p // 128)) * 128))
 
 
-def _pad_cols(x: jnp.ndarray, width: int) -> jnp.ndarray:
-    if x.shape[-1] == width:
-        return x
-    return jnp.pad(x, ((0, 0), (0, width - x.shape[-1])))
-
-
-def _tiling_ok(interpret: bool, *dims) -> bool:
-    """Compiled Mosaic wants sublane-aligned logical rows; the interpreter
-    takes anything.  (Lane dims are always padded to 128 via _chunk_cols /
-    output padding.)"""
-    if interpret:
-        return True
-    return all(d % 8 == 0 for d in dims)
+def _mask_tail(blk, i, chunk: int, p: int):
+    """Zero the columns of grid step ``i``'s [rows, chunk] block that lie
+    past the true width ``p``.  The grid is ``cdiv(p, chunk)`` over the
+    UNPADDED inputs (padding them would copy both full [N, P] operands in
+    HBM), so the last block's out-of-bounds lanes hold unspecified values;
+    a select (not arithmetic) discards them, NaNs included."""
+    if p % chunk == 0:
+        return blk
+    col = i * chunk + jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    return jnp.where(col < p, blk, jnp.zeros_like(blk))
 
 
 # ---------------------------------------------------------------------------
@@ -169,15 +186,16 @@ def _tiling_ok(interpret: bool, *dims) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def _circ_dist_kernel(own_ref, b_ref, out_ref, *, offsets, k_pad):
+def _circ_dist_kernel(own_ref, b_ref, out_ref, *, offsets, k_pad, chunk, p):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    o_blk = own_ref[:].astype(jnp.float32)
-    b_blk = b_ref[:].astype(jnp.float32)
+    # Masked tail columns contribute (0 - 0)^2 to every distance.
+    o_blk = _mask_tail(own_ref[:].astype(jnp.float32), i, chunk, p)
+    b_blk = _mask_tail(b_ref[:].astype(jnp.float32), i, chunk, p)
     rows = []
     for off in offsets:
         d = o_blk - jnp.roll(b_blk, -off, axis=0)
@@ -195,22 +213,17 @@ def _circ_dist_call(own, bcast, offsets, interpret):
     n, p = bcast.shape
     k = len(offsets)
     chunk = _chunk_cols(n, p, 2)
-    p_pad = -(-p // chunk) * chunk
-    # Zero padding is inert: both operands pad identically, so padded
-    # columns contribute (0 - 0)^2 to every distance.
-    own_p = _pad_cols(own.astype(jnp.float32), p_pad)
-    b_p = _pad_cols(bcast.astype(jnp.float32), p_pad)
     k_pad = k if interpret else -(-k // 8) * 8
-    n_pad = n if interpret else -(-n // 128) * 128
-    if n_pad != n:
+    if not interpret and n % 128:
         # Row padding would corrupt the wrap-around of in-kernel rolls;
         # the caller falls back (see circulant_sq_distances).
         raise ValueError("unaligned n reached the kernel")
     out = pl.pallas_call(
         functools.partial(
-            _circ_dist_kernel, offsets=tuple(offsets), k_pad=k_pad
+            _circ_dist_kernel, offsets=tuple(offsets), k_pad=k_pad,
+            chunk=chunk, p=p,
         ),
-        grid=(p_pad // chunk,),
+        grid=(pl.cdiv(p, chunk),),
         in_specs=[
             pl.BlockSpec((n, chunk), lambda i: (0, i)),
             pl.BlockSpec((n, chunk), lambda i: (0, i)),
@@ -218,7 +231,7 @@ def _circ_dist_call(own, bcast, offsets, interpret):
         out_specs=pl.BlockSpec((k_pad, n), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((k_pad, n), jnp.float32),
         interpret=interpret,
-    )(own_p, b_p)
+    )(own, bcast)
     return out[:k]
 
 
@@ -234,21 +247,23 @@ def circulant_sq_distances(
     if interpret is None:
         interpret = _interpret_default()
     n, p = bcast.shape
+    refuse = functools.partial(
+        _refuse, "circulant_sq_distances", (own.shape, bcast.shape)
+    )
     if not offsets or own.shape != bcast.shape:
-        return None
+        return refuse("no offsets or own/bcast shapes differ")
     # Compiled mode: in-kernel rolls wrap at the block's row count, so the
     # node dim must be exactly resident (no row padding) and lane-aligned
     # for the [k, N] output.
-    if not interpret and (n % 128 != 0):
-        return None
-    if not _tiling_ok(interpret, n):
-        return None
+    if not interpret and n % 128 != 0:
+        return refuse("compiled mode needs N % 128 == 0")
     mode, mesh = _sharded_axis_mode()
     if mode == "nodes":
-        return None  # rolls wrap at the resident row count — lax path
+        # rolls wrap at the resident row count — lax path
+        return refuse("the node axis is sharded")
     if mode == "param":
         if p % _param_shards_of(mesh):
-            return None
+            return refuse("the param shard count does not divide P")
         return _param_shard_map(
             lambda o_l, b_l: _circ_dist_call(
                 o_l, b_l, tuple(int(o) for o in offsets), interpret
@@ -263,7 +278,9 @@ def circulant_sq_distances(
 # ---------------------------------------------------------------------------
 
 
-def _pairwise_kernel(a_ref, b_ref, out_ref, g_ref, sa_ref, sb_ref):
+def _pairwise_kernel(
+    a_ref, b_ref, out_ref, g_ref, sa_ref, sb_ref, *, chunk, p
+):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -272,8 +289,8 @@ def _pairwise_kernel(a_ref, b_ref, out_ref, g_ref, sa_ref, sb_ref):
         sa_ref[:] = jnp.zeros_like(sa_ref)
         sb_ref[:] = jnp.zeros_like(sb_ref)
 
-    a = a_ref[:].astype(jnp.float32)
-    b = b_ref[:].astype(jnp.float32)
+    a = _mask_tail(a_ref[:].astype(jnp.float32), i, chunk, p)
+    b = _mask_tail(b_ref[:].astype(jnp.float32), i, chunk, p)
     g_ref[:] += jax.lax.dot_general(
         a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -294,17 +311,14 @@ def _pairwise_call(a, b, interpret):
     n, p = a.shape
     m = b.shape[0]
     chunk = _chunk_cols(max(n, m), p, 2)
-    p_pad = -(-p // chunk) * chunk
-    a_p = _pad_cols(a.astype(jnp.float32), p_pad)
-    b_p = _pad_cols(b.astype(jnp.float32), p_pad)
     scratch = [
         pltpu.VMEM((n, m), jnp.float32),
         pltpu.VMEM((1, n), jnp.float32),
         pltpu.VMEM((1, m), jnp.float32),
     ]
     return pl.pallas_call(
-        _pairwise_kernel,
-        grid=(p_pad // chunk,),
+        functools.partial(_pairwise_kernel, chunk=chunk, p=p),
+        grid=(pl.cdiv(p, chunk),),
         in_specs=[
             pl.BlockSpec((n, chunk), lambda i: (0, i)),
             pl.BlockSpec((m, chunk), lambda i: (0, i)),
@@ -313,7 +327,7 @@ def _pairwise_call(a, b, interpret):
         out_shape=jax.ShapeDtypeStruct((n, m), jnp.float32),
         scratch_shapes=scratch,
         interpret=interpret,
-    )(a_p, b_p)
+    )(a, b)
 
 
 def pairwise_sq_distances(
@@ -329,18 +343,23 @@ def pairwise_sq_distances(
         interpret = _interpret_default()
     n, p = a.shape
     m = b.shape[0]
+    refuse = functools.partial(
+        _refuse, "pairwise_sq_distances", (a.shape, b.shape)
+    )
     if b.shape[1] != p:
-        return None
+        return refuse("a/b widths differ")
     if n * m > _MAX_PAIRWISE_CELLS:
-        return None  # the [N, M] accumulator must stay VMEM-resident
+        # the [N, M] accumulator must stay VMEM-resident
+        return refuse(f"N*M exceeds {_MAX_PAIRWISE_CELLS} accumulator cells")
     if not interpret and (n % 8 != 0 or m % 128 != 0):
-        return None
+        return refuse("compiled mode needs N % 8 == 0 and M % 128 == 0")
     mode, mesh = _sharded_axis_mode()
     if mode == "nodes":
-        return None  # the [N, M] accumulator spans the split node axis
+        # the [N, M] accumulator spans the split node axis
+        return refuse("the node axis is sharded")
     if mode == "param":
         if p % _param_shards_of(mesh):
-            return None
+            return refuse("the param shard count does not divide P")
         # Shard-local Gram/norm partials over each device's columns, one
         # [N, M] psum at the end: d2 = sum over shards of local d2.
         return _param_shard_map(
@@ -388,26 +407,25 @@ def _candidate_call(own, bcast, offsets, trim, median, interpret):
     n, p = bcast.shape
     m = len(offsets) + 1
     chunk = _chunk_cols(n, p, m + 2)
-    p_pad = -(-p // chunk) * chunk
-    own_p = _pad_cols(own, p_pad)
-    b_p = _pad_cols(bcast, p_pad)
-    out = pl.pallas_call(
+    # Coordinate-wise along P: the last block's out-of-bounds lanes are
+    # computed on unspecified values and dropped on the write-back, so the
+    # unpadded [N, P] operands stream through with no HBM copy.
+    return pl.pallas_call(
         functools.partial(
             _candidate_kernel,
             offsets=tuple(offsets),
             trim=trim,
             median=median,
         ),
-        grid=(p_pad // chunk,),
+        grid=(pl.cdiv(p, chunk),),
         in_specs=[
             pl.BlockSpec((n, chunk), lambda i: (0, i)),
             pl.BlockSpec((n, chunk), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((n, chunk), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n, p_pad), own.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, p), own.dtype),
         interpret=interpret,
-    )(own_p, b_p)
-    return out[:, :p]
+    )(own, bcast)
 
 
 def candidate_select_supported(
@@ -422,18 +440,26 @@ def candidate_select_supported(
     traced operand, MUR001-clean) at trace time."""
     if interpret is None:
         interpret = _interpret_default()
-    if not offsets or tuple(own.shape) != tuple(bcast.shape):
+
+    def refuse(reason: str) -> bool:
+        _refuse("fused_candidate_select", (own.shape, bcast.shape), reason)
         return False
+
+    if not offsets or tuple(own.shape) != tuple(bcast.shape):
+        return refuse("no offsets or own/bcast shapes differ")
     m = len(offsets) + 1
     if trim < 0 or m - 2 * trim < 1:
-        return False
+        return refuse(f"trim {trim} leaves no candidate of {m}")
     if not interpret and bcast.shape[0] % 128 != 0:
-        return False  # in-kernel rolls wrap at the resident row count
+        # in-kernel rolls wrap at the resident row count
+        return refuse("compiled mode needs N % 128 == 0")
     mode, mesh = _sharded_axis_mode()
     if mode == "nodes":
-        return False  # rolls wrap at the resident row count — lax path
+        # rolls wrap at the resident row count — lax path
+        return refuse("the node axis is sharded")
     if mode == "param" and bcast.shape[1] % _param_shards_of(mesh):
-        return False  # columns must split evenly into shard-local grids
+        # columns must split evenly into shard-local grids
+        return refuse("the param shard count does not divide P")
     return True
 
 

@@ -38,18 +38,23 @@ def count_sketch(
     On TPU this dispatches to the Pallas MXU kernel
     (ops/pallas_sketch.py) — XLA lowers segment_sum with random indices
     to a serialized scatter, the one non-vectorizing op in the
-    Sketchguard round.  Elsewhere (CPU tests) it stays a segment_sum.
+    Sketchguard round.  Elsewhere (CPU tests) it stays a segment_sum; an
+    explicit ``use_pallas=True`` there runs the kernel interpreted.  The
+    kernel is compiled exactly when the default backend is a TPU
+    (``chip_smoke.py`` asserts ``tpu_custom_call`` in the compiled
+    sketchguard round).
     """
+    on_tpu = jax.default_backend() == "tpu"
     if use_pallas is None:
         from murmura_tpu.ops.pallas_sketch import MAX_SKETCH_PAD
 
-        use_pallas = (
-            jax.default_backend() == "tpu" and sketch_size <= MAX_SKETCH_PAD
-        )
+        use_pallas = on_tpu and sketch_size <= MAX_SKETCH_PAD
     if use_pallas:
         from murmura_tpu.ops.pallas_sketch import count_sketch_pallas
 
-        return count_sketch_pallas(vector, hash_table, sign_table, sketch_size)
+        return count_sketch_pallas(
+            vector, hash_table, sign_table, sketch_size, interpret=not on_tpu
+        )
     return jax.ops.segment_sum(
         sign_table * vector, hash_table, num_segments=sketch_size
     )

@@ -35,15 +35,9 @@ def backend_initialized() -> bool:
     """
     from jax._src import xla_bridge
 
-    # backends_are_initialized() is the helper jax.distributed itself uses;
-    # the _backends dict is the fallback for versions without it.  Both are
-    # private (jax._src has no stability guarantee), so a future rename
-    # fails OPEN — the guard stops firing rather than breaking every
-    # init_multihost call; MUR005 remains the static line of defense.
-    probe = getattr(xla_bridge, "backends_are_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    return bool(getattr(xla_bridge, "_backends", None))
+    # The helper jax.distributed itself uses (private: jax._src has no
+    # stability guarantee; MUR005 remains the static line of defense).
+    return bool(xla_bridge.backends_are_initialized())
 
 
 def init_multihost(
@@ -60,7 +54,7 @@ def init_multihost(
     Must run before anything initializes the XLA backend; a duplicate call
     in the same process is ignored.
     """
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         return
     if backend_initialized():
         raise RuntimeError(

@@ -40,9 +40,9 @@ type            meaning
                 own events to ``*.prev``)
 ``backend_degraded``
                 the dispatch envelope observed a degradation: a
-                transient device/tunnel failure being retried with
-                backoff (``reason``, ``retry``, ``delay_s``), a bench
-                CPU fallback, or a frozen gang member lane
+                transient device/transport failure being retried with
+                backoff (``reason``, ``retry``, ``delay_s``), or a
+                frozen gang member lane
                 (``member``, ``reason`` — core/gang.py freeze_member)
 ``counter``     distributed-backend node counters folded by the Monitor
                 (reconnects, send retries/failures, skipped frames,

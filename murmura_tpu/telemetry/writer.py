@@ -205,20 +205,17 @@ class TelemetryWriter:
         self.emit("round", **fields)
 
     def memory_event(self, round_idx: int) -> None:
-        """Sample device memory_stats() (no-op unless enabled; tolerates
-        platforms that expose none — CPU returns None)."""
+        """Sample device memory_stats() (no-op unless enabled).  CPU
+        legitimately reports ``None``; an error from an accelerator
+        surfaces — a TPU run whose memory line silently vanished is the
+        kind of hidden degradation the run manifest exists to prevent."""
         if not self.memory_stats:
             return
-        stats = None
-        kind = None
-        try:
-            import jax
+        import jax
 
-            dev = jax.local_devices()[0]
-            kind = dev.device_kind
-            stats = dev.memory_stats()
-        except Exception:  # noqa: BLE001 — sampling must never kill the run
-            pass
+        dev = jax.local_devices()[0]
+        kind = dev.device_kind
+        stats = dev.memory_stats()
         self.emit("memory", round=int(round_idx), device_kind=kind, stats=stats)
 
     def checkpoint_event(

@@ -6,6 +6,8 @@ a ready-to-train Network for the simulation and tpu backends; the ZMQ
 distributed backend reuses the component builders for its per-process nodes.
 """
 
+import os
+from pathlib import Path
 from typing import Optional
 
 import numpy as np
@@ -469,29 +471,49 @@ def resolve_model(config: Config, data):
     return model
 
 
-def apply_compilation_cache(config: Config) -> None:
-    """Enable JAX's persistent compilation cache when configured.
+# The one fixed compile-cache location when JAX_COMPILATION_CACHE_DIR is
+# unset: inside the checkout (git-ignored) because the path is part of the
+# cache key — a directory that moves between runs never hits.
+COMPILATION_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
-    Shared by the in-process backends (via build_network_from_config) and
-    the ZMQ worker processes (NodeProcess.run), so ``murmura run`` pays an
-    identical round program's XLA compile once per machine, not once per
-    run per process.
+
+def apply_compilation_cache() -> Optional[str]:
+    """The one compile-cache rule; returns the directory in use.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX's own handling of that variable
+    is the only cache setting and nothing here touches the config.  Unset:
+    the persistent cache goes to :data:`COMPILATION_CACHE_DIR`.  Every
+    entry point that compiles round programs (``build_network_from_config``,
+    the ZMQ workers, the ``check`` sweeps, the bench scripts,
+    ``chip_smoke.py``) calls this and nothing else configures the cache.
+
+    When that fixed directory cannot be created or written — the package
+    installed non-editable under a read-only prefix, where ``parents[2]`` is
+    not a checkout — there is no persistent cache: a warning names the
+    variable to set and ``None`` is returned.
     """
-    if config.tpu.compilation_cache_dir:
-        import jax
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    try:
+        COMPILATION_CACHE_DIR.mkdir(parents=True, exist_ok=True)
+        writable = os.access(COMPILATION_CACHE_DIR, os.W_OK | os.X_OK)
+    except OSError:
+        writable = False
+    if not writable:
+        import warnings
 
-        jax.config.update(
-            "jax_compilation_cache_dir", config.tpu.compilation_cache_dir
+        warnings.warn(
+            f"compile cache directory {COMPILATION_CACHE_DIR} is not "
+            "writable: running without a persistent compile cache (set "
+            "JAX_COMPILATION_CACHE_DIR to place one)",
+            stacklevel=2,
         )
-        # Process-level twin for jax-config-free consumers (the check
-        # --ir budget sweep — analysis/budgets.apply_persistent_cache —
-        # and any subprocess this run spawns): one cache per battery.
-        import os
+        return None
+    import jax
 
-        os.environ.setdefault(
-            "MURMURA_COMPILATION_CACHE_DIR",
-            config.tpu.compilation_cache_dir,
-        )
+    jax.config.update("jax_compilation_cache_dir", str(COMPILATION_CACHE_DIR))
+    return str(COMPILATION_CACHE_DIR)
 
 
 def _node_axis_sharded(config: Config, mesh=None) -> bool:
@@ -745,7 +767,7 @@ def build_gang_from_config(config: Config, seeds=None, mesh=None,
             num_processes=config.tpu.num_processes,
             process_id=config.tpu.process_id,
         )
-    apply_compilation_cache(config)
+    apply_compilation_cache()
 
     try:
         members = resolve_members(config, seeds)
@@ -900,7 +922,7 @@ def build_network_from_config(
             process_id=config.tpu.process_id,
         )
 
-    apply_compilation_cache(config)
+    apply_compilation_cache()
 
     n = config.topology.num_nodes
     seed = config.experiment.seed

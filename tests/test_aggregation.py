@@ -54,6 +54,30 @@ class TestPairwiseDistances:
         np.testing.assert_allclose(d, direct, rtol=0.05, atol=1e-4)
 
 
+    @pytest.mark.parametrize("ambient", ["highest", "high", None])
+    def test_gram_dot_ignores_the_ambient_matmul_precision(self, ambient):
+        """The identity cancels the dot against f32 VPU norms; on a v5e a
+        "high"/"highest" [16, 6.6M] dot disagrees with them by 5e-4 of their
+        value and every d2 clamps to 0 (chip_smoke's f32 runs found Krum
+        scores of 0.0).  The dot is pinned to the default precision."""
+        import contextlib
+
+        import jax
+
+        a = jnp.ones((4, 8), jnp.float32)
+        scope = (
+            jax.default_matmul_precision(ambient) if ambient
+            else contextlib.nullcontext()
+        )
+        with scope:
+            jaxpr = jax.make_jaxpr(pairwise_l2_distances)(a)
+        dots = [e for e in jaxpr.eqns if e.primitive.name == "dot_general"]
+        assert len(dots) == 1
+        assert dots[0].params["precision"] == (
+            jax.lax.Precision.DEFAULT, jax.lax.Precision.DEFAULT
+        )
+
+
 class TestCirculantChunking:
     """The P-chunked circulant kernels (base.py _CIRCULANT_CHUNK_BYTES —
     the 256-node OOM fix) must reproduce the single-chunk computation."""

@@ -45,7 +45,7 @@ class TestJaxprSnapshots:
         callbacks = [
             e.primitive.name
             for e in ir.iter_eqns(jaxpr)
-            if "callback" in e.primitive.name
+            if ir.is_host_callback(e.primitive.name)
         ]
         assert callbacks == []
         assert ir._check_callbacks(name, prog, jaxpr) == []
@@ -59,7 +59,7 @@ class TestJaxprSnapshots:
         jaxpr = jax.make_jaxpr(prog.fn)(*prog.args)
         fs = ir._check_callbacks("custom", prog, jaxpr)
         assert [f.rule for f in fs] == ["MUR200"]
-        assert "debug_callback" in fs[0].message
+        assert "debug_print" in fs[0].message
 
     def test_pure_callback_is_a_finding(self):
         def hosty(own, bcast, adj, ridx, state):
@@ -452,6 +452,75 @@ class TestJsonOutput:
         assert result.exit_code == 0
 
 
+class TestFaultRoundInventory:
+    """MUR303's inventory (ir.collective_names): by op name, with the two
+    scalar fault-metric sums licensed by exact count, dtype and shape —
+    pinned on synthetic HLO in the installed XLA's spelling."""
+
+    METRICS = (
+        "  %all-reduce.2 = (f32[], f32[]) all-reduce(%bitcast.75, %fusion), "
+        "channel_id=4, replica_groups=[1,4]<=[4], to_apply=%region\n"
+    )
+    GATHER = (
+        "  %all-gather = f32[4,244]{1,0} all-gather(%concatenate.15), "
+        "channel_id=1, dimensions={0}\n"
+    )
+
+    def test_unlicensed_inventory_is_by_op_name(self):
+        from murmura_tpu.analysis.ir import collective_names
+
+        assert collective_names(self.GATHER + self.METRICS) == {
+            "all_gather", "all_reduce"
+        }
+
+    def test_exactly_the_two_metric_sums_are_licensed(self):
+        from murmura_tpu.analysis.ir import (
+            FAULT_METRIC_ALL_REDUCES, all_reduce_results, collective_names,
+        )
+
+        txt = self.GATHER + self.METRICS
+        assert all_reduce_results(txt) == ("f32[]", "f32[]")
+        assert collective_names(txt, FAULT_METRIC_ALL_REDUCES) == {"all_gather"}
+        # The same two sums spelled as separate statements.
+        split = (
+            "  %all-reduce.3 = f32[] all-reduce(%a), channel_id=4\n"
+            "  %all-reduce.4 = f32[] all-reduce-start(%b), channel_id=5\n"
+        )
+        assert collective_names(
+            self.GATHER + split, FAULT_METRIC_ALL_REDUCES
+        ) == {"all_gather"}
+
+    @pytest.mark.parametrize(
+        "extra",
+        [
+            # a global any-non-finite sentinel sync
+            "  %all-reduce.5 = pred[] all-reduce(%any), channel_id=7\n",
+            # a third scalar sum, separate or fused into the tuple
+            "  %all-reduce.5 = f32[] all-reduce(%x), channel_id=7\n",
+            # rows
+            "  %all-reduce.7 = f32[4,244]{1,0} all-reduce(%f), channel_id=5\n",
+            "  %all-reduce.8 = f32[4]{0} all-reduce-start(%x), channel_id=6\n",
+        ],
+    )
+    def test_any_further_all_reduce_is_a_finding(self, extra):
+        from murmura_tpu.analysis.ir import (
+            FAULT_METRIC_ALL_REDUCES, collective_names,
+        )
+
+        txt = self.GATHER + self.METRICS + extra
+        assert "all_reduce" in collective_names(txt, FAULT_METRIC_ALL_REDUCES)
+
+    def test_a_fused_third_scalar_is_a_finding(self):
+        from murmura_tpu.analysis.ir import (
+            FAULT_METRIC_ALL_REDUCES, collective_names,
+        )
+
+        fused = self.METRICS.replace("(f32[], f32[])", "(f32[], f32[], f32[])")
+        assert "all_reduce" in collective_names(
+            self.GATHER + fused, FAULT_METRIC_ALL_REDUCES
+        )
+
+
 class TestMUR700CompressedPayload:
     """The MUR700 HLO scan (ir.float_exchange_operands): the compressed
     payload — not a dequantized float tensor — is what crosses the
@@ -481,6 +550,24 @@ class TestMUR700CompressedPayload:
         )
         offending, lines = float_exchange_operands(txt, 256)
         assert offending == []
+        assert len(lines) == 2
+        assert any("s8[" in ln for ln in lines)
+
+    def test_operands_printed_by_name_are_read_from_the_result(self):
+        # The installed XLA prints `collective-permute(%slice.1)`: no
+        # operand shape inside the parens, so the result shape carries the
+        # moved dtype — a scan of the parens alone went vacuous ("no int8
+        # collective at all") on a program that does move int8.
+        from murmura_tpu.analysis.ir import float_exchange_operands
+
+        txt = (
+            "  %collective-permute = s8[1,256]{1,0} collective-permute("
+            "%wrapped_slice), channel_id=2, source_target_pairs={{0,1}}\n"
+            "  %all-gather.3 = f32[8,256]{1,0} all-gather(%fusion.9), "
+            "channel_id=3, dimensions={0}\n"
+        )
+        offending, lines = float_exchange_operands(txt, 256)
+        assert offending == ["f32[8,256]"]
         assert len(lines) == 2
         assert any("s8[" in ln for ln in lines)
 

@@ -47,7 +47,7 @@ def _write_budgets(tmp_path, budgets, tolerance=None):
 
 
 class TestNormalize:
-    def test_object_and_dict_and_none(self):
+    def test_object_and_none(self):
         class Stats:
             temp_size_in_bytes = 10
             argument_size_in_bytes = 20
@@ -55,15 +55,10 @@ class TestNormalize:
             alias_size_in_bytes = 6
             generated_code_size_in_bytes = 2
 
-        for raw in (Stats(), {
-            "temp_size_in_bytes": 10, "argument_size_in_bytes": 20,
-            "output_size_in_bytes": 8, "alias_size_in_bytes": 6,
-            "generated_code_size_in_bytes": 2,
-        }, [Stats()]):
-            m = memory.normalize_memory_analysis(raw)
-            assert m["temp_bytes"] == 10.0
-            # peak = args + outs - alias + temp + generated
-            assert m["peak_bytes"] == 20 + 8 - 6 + 10 + 2
+        m = memory.normalize_memory_analysis(Stats())
+        assert m["temp_bytes"] == 10.0
+        # peak = args + outs - alias + temp + generated
+        assert m["peak_bytes"] == 20 + 8 - 6 + 10 + 2
         empty = memory.normalize_memory_analysis(None)
         assert empty["peak_bytes"] == 0.0
 

@@ -98,7 +98,7 @@ class TestErrorClassification:
 
     def test_marker_substrings_are_transient(self):
         for msg in ("DEADLINE_EXCEEDED while waiting", "socket closed",
-                    "tunnel reset by peer", "heartbeat lost",
+                    "transport reset by peer", "heartbeat lost",
                     "UNAVAILABLE: connection to TPU worker"):
             assert ddispatch.classify_error(RuntimeError(msg)) == "transient", msg
 
@@ -111,7 +111,7 @@ class TestErrorClassification:
     def test_backend_requirement_is_always_fatal(self):
         # Even though the message contains transient-looking markers,
         # retrying cannot conjure a chip.
-        exc = ddispatch.BackendRequirementError("tunnel unavailable timeout")
+        exc = ddispatch.BackendRequirementError("device unavailable timeout")
         assert ddispatch.classify_error(exc) == "fatal"
 
 
@@ -143,7 +143,7 @@ class TestRetryPolicy:
         def attempt(try_idx):
             calls.append(try_idx)
             if try_idx < 2:
-                raise ConnectionError("tunnel died")
+                raise ConnectionError("link died")
             return "done"
 
         result = ddispatch.run_with_retry(
@@ -192,7 +192,7 @@ class TestRequireTpu:
         # The suite pins jax to CPU (conftest) — exactly the silent
         # fallback the flag exists to refuse.
         with pytest.raises(ddispatch.BackendRequirementError,
-                           match="silent CPU fallback"):
+                           match="refusing to run on another device"):
             ddispatch.require_tpu(source="--require-tpu")
 
     def test_tpu_required_env_and_config(self, monkeypatch):

@@ -8,7 +8,6 @@ sharded over a mesh) — only the compilation of the step differs.
 """
 
 import contextlib
-import time
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -17,6 +16,7 @@ import numpy as np
 
 from murmura_tpu.attacks.base import Attack
 from murmura_tpu.core.rounds import RoundProgram
+from murmura_tpu.telemetry.host_spans import span
 from murmura_tpu.topology.base import Topology
 from murmura_tpu.topology.dynamic import MobilityModel
 
@@ -692,82 +692,103 @@ class Network:
                 )
         return self._fused_cache[key]
 
+    def _fused_inputs(self, round0: int, k: int, comp) -> List[Any]:
+        """Stage one fused chunk's inputs (the murmura.host.stage span)."""
+        adj_stack = self._stage(
+            np.stack(
+                [self._adjacency_for_round(round0 + i) for i in range(k)]
+            ),
+            self._adj_stack_s,
+        )
+        step_args = [
+            self.params,
+            self.agg_state,
+            self._stage(self._rng, self._repl),
+            adj_stack,
+            comp,
+            self._stage(np.asarray(round0, np.int32), self._repl),
+            self._data,
+        ]
+        if self.program.faulted:
+            # Per-round alive masks ride the scan like the adj stack.
+            step_args.insert(
+                5,
+                self._stage(
+                    np.stack(
+                        [self._alive_for_round(round0 + i) for i in range(k)]
+                    ),
+                    self._adj_stack_s,
+                ),
+            )
+        return step_args
+
     def _train_fused(
         self, rounds, verbose, eval_every, checkpoint_dir, checkpoint_every,
         chunk,
     ) -> None:
+        from murmura_tpu.analysis.sanitizers import compile_count
+
         comp = self._stage(self.compromised, self._node_s)
+        overlap = self._phase_overlap()
         done = 0
         while done < rounds:
             k = min(chunk, rounds - done)
             step = self._fused_step(k, eval_every)
             round0 = self.current_round
             self._profile_window_start(round0, span=k)
-            t0 = time.perf_counter()
             program_key = ("fused", k, eval_every)
-            if self._tracker is not None:
-                self._tracker.begin(f"rounds {round0}..{round0 + k - 1}")
-            adj_stack = self._stage(
-                np.stack(
-                    [self._adjacency_for_round(round0 + i) for i in range(k)]
-                ),
-                self._adj_stack_s,
-            )
-            step_args = [
-                self.params,
-                self.agg_state,
-                self._stage(self._rng, self._repl),
-                adj_stack,
-                comp,
-                self._stage(np.asarray(round0, np.int32), self._repl),
-                self._data,
-            ]
-            if self.program.faulted:
-                # Per-round alive masks ride the scan like the adj stack.
-                step_args.insert(
-                    5,
-                    self._stage(
-                        np.stack(
-                            [self._alive_for_round(round0 + i) for i in range(k)]
-                        ),
-                        self._adj_stack_s,
-                    ),
-                )
-            self.params, self.agg_state, rows = step(*step_args)
-            rows = jax.device_get(rows)
-            chunk_warmup = program_key not in self._warmed
-            self._warmed.add(program_key)
-            self.current_round = round0 + k
+            # One chunk is one murmura.round span (rounds=k): the chunk
+            # runs as a single device program, so the host sees no round
+            # boundary inside it.
+            with span(
+                "murmura.round", round=round0, step=True, rounds=k
+            ) as chunk_span:
+                if self._tracker is not None:
+                    self._tracker.begin(f"rounds {round0}..{round0 + k - 1}")
+                with span("murmura.host.stage", round=round0):
+                    step_args = self._fused_inputs(round0, k, comp)
+                with span(
+                    "murmura.host.dispatch", round=round0,
+                    compiles=compile_count, program="fused",
+                ):
+                    self.params, self.agg_state, rows = step(*step_args)
+                with span("murmura.host.fetch", round=round0):
+                    rows = jax.device_get(rows)
+                chunk_warmup = program_key not in self._warmed
+                self._warmed.add(program_key)
+                self.current_round = round0 + k
             # Keep round_times in per-round units across dispatch modes:
-            # one amortized entry per round, not one per chunk (the chunk
-            # runs as a single device program, so per-round wall times
-            # inside it are not observable).
-            elapsed = time.perf_counter() - t0
+            # one amortized entry per round, not one per chunk (per-round
+            # wall times inside one device program are not observable).
+            # The chunk's time is the span's: one clock, read once.
+            elapsed = chunk_span.seconds
             self.round_times.extend([elapsed / k] * k)
             done += k
             if self.telemetry is not None:
-                # One amortized phase_times record per round — per-round
-                # wall times inside a single device dispatch are not
-                # observable, so the chunk's elapsed/k is the honest unit
-                # (same semantics as round_times; mode records the split).
-                for i in range(k):
-                    self.telemetry.phase_times(
-                        round0 + i, "fused", elapsed / k, chunk=k,
-                        **self._phase_overlap(),
-                    )
-                self.telemetry.memory_event(self.current_round - 1)
+                # One amortized phase_times record per round, in the same
+                # unit as round_times (mode records the split).
+                with span("murmura.host.record", round=round0):
+                    for i in range(k):
+                        self.telemetry.phase_times(
+                            round0 + i, "fused", elapsed / k, chunk=k, **overlap
+                        )
+                    self.telemetry.memory_event(self.current_round - 1)
                 self._profile_window_stop(self.current_round)
-            for i in range(k):
-                if rows["evaluated"][i]:
-                    self._record(
-                        round0 + i + 1,
-                        {
-                            m: v[i]
-                            for m, v in rows.items()
-                            if m != "evaluated"
-                        },
-                        verbose,
-                    )
+            # The chunk's bookkeeping follows its span: round_times and
+            # phase_times exclude it, and are recorded before anything
+            # here can raise beside the already-advanced params.
+            with span("murmura.host.record", round=round0):
+                for i in range(k):
+                    if rows["evaluated"][i]:
+                        self._record(
+                            round0 + i + 1,
+                            {
+                                m: v[i]
+                                for m, v in rows.items()
+                                if m != "evaluated"
+                            },
+                            verbose,
+                        )
             # After the bookkeeping: a guard raise must leave
             # current_round/history aligned with the already-advanced
             # (donated) params, or a catch-and-checkpoint caller would
@@ -780,72 +801,100 @@ class Network:
             if checkpoint_dir and (crossed_cadence or done >= rounds):
                 self.save_checkpoint(checkpoint_dir)
 
+    def _round_inputs(self, round_idx: int, comp) -> List[Any]:
+        """Stage one round's step inputs (the murmura.host.stage span)."""
+        adj = self._stage(self._adjacency_for_round(round_idx), self._adj_s)
+        # 0-d numpy staging: scalar conversions from numpy ARRAYS are
+        # explicit transfers (transfer_guard-clean); Python/numpy
+        # scalars would be implicit and trip the sanitizer.
+        step_key = self._stage(
+            self._fold_in(
+                self._rng, jnp.asarray(np.asarray(round_idx, np.uint32))
+            ),
+            self._repl,
+        )
+        step_args = [
+            self.params,
+            self.agg_state,
+            step_key,
+            adj,
+            comp,
+            self._stage(np.asarray(round_idx, np.float32), self._repl),
+            self._data,
+        ]
+        if self.program.faulted:
+            step_args.insert(
+                5, self._stage(self._alive_for_round(round_idx), self._node_s)
+            )
+        return step_args
+
     def _train_rounds(
         self, rounds, verbose, eval_every, checkpoint_dir, checkpoint_every,
         defer_metrics=False,
     ) -> None:
+        from murmura_tpu.analysis.sanitizers import compile_count
+
         comp = self._stage(self.compromised, self._node_s)
+        overlap = self._phase_overlap()
         last_saved = -1
         pending: List[Any] = []
         for _ in range(rounds):
             round_idx = self.current_round
+            evaluated = (round_idx + 1) % eval_every == 0
             self._profile_window_start(round_idx)
-            t0 = time.perf_counter()
-            warmup = "step" not in self._warmed
-            if self._tracker is not None:
-                self._tracker.begin(f"round {round_idx}")
-            adj = self._stage(self._adjacency_for_round(round_idx), self._adj_s)
-            # 0-d numpy staging: scalar conversions from numpy ARRAYS are
-            # explicit transfers (transfer_guard-clean); Python/numpy
-            # scalars would be implicit and trip the sanitizer.
-            step_key = self._stage(
-                self._fold_in(
-                    self._rng, jnp.asarray(np.asarray(round_idx, np.uint32))
-                ),
-                self._repl,
-            )
-            step_args = [
-                self.params,
-                self.agg_state,
-                step_key,
-                adj,
-                comp,
-                self._stage(np.asarray(round_idx, np.float32), self._repl),
-                self._data,
-            ]
-            if self.program.faulted:
-                step_args.insert(
-                    5, self._stage(self._alive_for_round(round_idx), self._node_s)
-                )
-            self.params, self.agg_state, agg_metrics = self._step(*step_args)
-            self._warmed.add("step")
-            self.current_round = round_idx + 1
-            if self.current_round % eval_every == 0:
-                # Close the step phase before eval runs: eval's own warmup
-                # must not whitelist a post-warmup step recompile landing
-                # in the same round (and vice versa).
+            with span("murmura.round", round=round_idx, step=True) as round_span:
+                warmup = "step" not in self._warmed
                 if self._tracker is not None:
-                    self._tracker.mark(allow=warmup)
-                warmup = "eval" not in self._warmed
-                metrics = {**self._eval(self.params, self._data), **agg_metrics}
-                self._warmed.add("eval")
-                if defer_metrics:
-                    pending.append((self.current_round, metrics))
-                else:
-                    metrics = jax.device_get(metrics)
-                    self._record(self.current_round, metrics, verbose)
-            if self._tracker is not None:
-                self._tracker.end(allow=warmup)
-            wall = time.perf_counter() - t0
+                    self._tracker.begin(f"round {round_idx}")
+                with span("murmura.host.stage", round=round_idx):
+                    step_args = self._round_inputs(round_idx, comp)
+                with span(
+                    "murmura.host.dispatch", round=round_idx,
+                    compiles=compile_count, program="step",
+                ):
+                    self.params, self.agg_state, agg_metrics = self._step(
+                        *step_args
+                    )
+                self._warmed.add("step")
+                self.current_round = round_idx + 1
+                if evaluated:
+                    # Close the step phase before eval runs: eval's own
+                    # warmup must not whitelist a post-warmup step
+                    # recompile landing in the same round (and vice versa).
+                    if self._tracker is not None:
+                        self._tracker.mark(allow=warmup)
+                    warmup = "eval" not in self._warmed
+                    with span(
+                        "murmura.host.dispatch", round=round_idx,
+                        compiles=compile_count, program="eval",
+                    ):
+                        metrics = {
+                            **self._eval(self.params, self._data), **agg_metrics
+                        }
+                    self._warmed.add("eval")
+                    if defer_metrics:
+                        pending.append((self.current_round, metrics))
+                    else:
+                        # Where the host waits for the device: the span an
+                        # operator sees the round's device time in.
+                        with span("murmura.host.fetch", round=round_idx):
+                            metrics = jax.device_get(metrics)
+                        with span("murmura.host.record", round=round_idx):
+                            self._record(self.current_round, metrics, verbose)
+                if self._tracker is not None:
+                    self._tracker.end(allow=warmup)
+            # The round's wall time is the span's: one clock, read once.
+            wall = round_span.seconds
             self.round_times.append(wall)
             if self.telemetry is not None:
-                self.telemetry.phase_times(
-                    round_idx, "per_round", wall,
-                    evaluated=bool(self.current_round % eval_every == 0),
-                    deferred=bool(defer_metrics),
-                    **self._phase_overlap(),
-                )
-                self.telemetry.memory_event(round_idx)
+                with span("murmura.host.record", round=round_idx):
+                    self.telemetry.phase_times(
+                        round_idx, "per_round", wall,
+                        evaluated=evaluated,
+                        deferred=bool(defer_metrics),
+                        **overlap,
+                    )
+                    self.telemetry.memory_event(round_idx)
                 self._profile_window_stop(self.current_round)
             if (
                 checkpoint_dir
@@ -867,7 +916,8 @@ class Network:
             if jax.process_count() == 1:
                 # Jitted: eager [0]-indexing stages its slice start as an
                 # implicit scalar transfer and trips tpu.transfer_guard.
-                jax.device_get(self._first_scalar(self.params))
+                with span("murmura.host.fetch", round=self.current_round - 1):
+                    jax.device_get(self._first_scalar(self.params))
             else:
                 # Multi-host: params are sharded across non-addressable
                 # devices, so a scalar fetch would raise; block on the
@@ -878,7 +928,10 @@ class Network:
 
     def _drain_pending(self, pending: List[Any], verbose: bool) -> None:
         for round_num, metrics in pending:
-            self._record(round_num, jax.device_get(metrics), verbose)
+            with span("murmura.host.fetch", round=round_num - 1):
+                metrics = jax.device_get(metrics)
+            with span("murmura.host.record", round=round_num - 1):
+                self._record(round_num, metrics, verbose)
         pending.clear()
 
     def save_checkpoint(self, directory: str) -> None:
@@ -887,11 +940,13 @@ class Network:
         path)."""
         from murmura_tpu.durability.snapshot import save_run_snapshot
 
-        t0 = time.perf_counter()
-        save_run_snapshot(directory, self)
+        with span(
+            "murmura.host.checkpoint", round=self.current_round, action="save"
+        ) as saved:
+            save_run_snapshot(directory, self)
         if self.telemetry is not None:
             self.telemetry.checkpoint_event(
-                self.current_round, time.perf_counter() - t0,
+                self.current_round, saved.seconds,
                 action="save", path=str(directory),
             )
 
@@ -907,11 +962,11 @@ class Network:
         """
         from murmura_tpu.durability.snapshot import restore_run_snapshot
 
-        t0 = time.perf_counter()
-        round_num = restore_run_snapshot(directory, self)
+        with span("murmura.host.checkpoint", action="restore") as restored:
+            round_num = restore_run_snapshot(directory, self)
         if self.telemetry is not None:
             self.telemetry.checkpoint_event(
-                round_num, time.perf_counter() - t0,
+                round_num, restored.seconds,
                 action="restore", path=str(directory),
             )
             self.telemetry.emit(

@@ -665,11 +665,18 @@ def build_round_program(
             # exchange mode runs the same fold in [k, N] edge-mask space.)
             adj = _edges_mask_both(adj, alive)
             train_mask = train_mask * alive
-            pre_flat = constrain_flat(jax.vmap(ravel)(params))
+            with jax.named_scope("murmura.flatten"):
+                pre_flat = constrain_flat(jax.vmap(ravel)(params))
         # named_scope brackets label the `# murmura: traced` phases in
-        # profiler traces (xprof/perfetto op names) — metadata only, the
-        # lowered program is identical (the telemetry-off byte-identity
-        # contract, tests/test_telemetry.py).
+        # profiler traces (xprof/perfetto op names; the device rows that
+        # the orchestrator's host spans, telemetry/host_spans.py, lie
+        # beside) — metadata only, the lowered program is identical (the
+        # telemetry-off byte-identity contract, tests/test_telemetry.py).
+        # murmura.flatten names the parameter tree's way to [N, P] and
+        # back, which belongs to no stage: it first occurs before
+        # murmura.train in a faulted program and after it otherwise, so
+        # it is no levers.STAGE_ORDER label (docs/OBSERVABILITY.md "Host
+        # spans and device scopes").
         with jax.named_scope("murmura.train"):
             params = local_training(params, d, train_mask, train_key, round_idx)
 
@@ -677,7 +684,8 @@ def build_round_program(
         # constrain_flat pins the [N, P] tensors to ("nodes", "param")
         # when a param-sharded mesh scope is active (parallel/mesh.py) —
         # identity otherwise, so unsharded programs are byte-identical.
-        own_flat = constrain_flat(jax.vmap(ravel)(params))
+        with jax.named_scope("murmura.flatten"):
+            own_flat = constrain_flat(jax.vmap(ravel)(params))
         fault_stats = {}
         if _inject_rows is not None:
             # Deterministic divergence injection (chaos testing): scheduled
@@ -955,7 +963,8 @@ def build_round_program(
             fault_stats["alive"] = alive.sum()
             if audit_taps:
                 fault_stats["tap_alive"] = alive
-        params = jax.vmap(unravel)(new_flat)
+        with jax.named_scope("murmura.flatten"):
+            params = jax.vmap(unravel)(new_flat)
 
         metrics = {f"agg_{k}": v for k, v in agg_stats.items()}
         metrics.update({f"agg_{k}": v for k, v in dmtt_stats.items()})
@@ -1066,7 +1075,8 @@ def build_round_program(
                 fault_stats["alive"] = alive.sum()
                 if audit_taps:
                     fault_stats["tap_alive"] = alive
-            params = jax.vmap(unravel)(new_flat)
+            with jax.named_scope("murmura.flatten"):
+                params = jax.vmap(unravel)(new_flat)
         buffer_updates = {
             PIPE_OWN_KEY: own_flat,
             PIPE_ADJ_KEY: prod["adj"].T if sparse else prod["adj"],

@@ -727,32 +727,55 @@ def kernel_direct_phase(size: Size) -> None:
             failures.append(name)
         del got, ref
 
-    # Count-Sketch at the flagship width and sketchguard's default size.
+    # Count-Sketch at the flagship width and sketchguard's default size: a
+    # [P] vector (the N = 1 case), then all rows of [kernel_nodes, P] in
+    # both resident dtypes, each against segment_sum of the float32-lifted
+    # values.  The first rows are also held against float64 bincount on the
+    # host: the two device paths differ in accumulation order only, and this
+    # says which of them carries the error.
+    del own, bcast
     sp, sketch_size = size.sketch_width, 1000
     hash_np, sign_np = make_sketch_tables(sp, sketch_size, 42)
     hash_table, sign_table = jnp.asarray(hash_np), jnp.asarray(sign_np)
-    vec = jax.random.normal(kv, (sp,), jnp.float32)
-    sketch = jax.jit(
-        lambda v: count_sketch(
-            v, hash_table, sign_table, sketch_size, use_pallas=True
+
+    def sketch_with(use_pallas):
+        return jax.jit(
+            lambda x: count_sketch(
+                x, hash_table, sign_table, sketch_size, use_pallas=use_pallas
+            )
         )
-    )
-    _expect_kernel("count_sketch", sketch.lower(vec).compile().as_text(), size)
-    got = sketch(vec)
-    ref = jax.jit(
-        lambda v: count_sketch(
-            v, hash_table, sign_table, sketch_size, use_pallas=False
+
+    kernel, lax_path = sketch_with(True), sketch_with(False)
+    rows = jax.random.normal(kv, (n, sp), jnp.float32)
+    for label, x in (
+        ("", rows[0]),
+        (" rows float32", rows),
+        (" rows bfloat16", rows.astype(jnp.bfloat16)),
+    ):
+        name = f"count_sketch{label}"
+        _expect_kernel(name, kernel.lower(x).compile().as_text(), size)
+        got = np.asarray(kernel(x))
+        lifted = x.astype(jnp.float32)
+        ref = np.asarray(lax_path(lifted))
+        first = np.asarray(lifted.reshape(-1, sp)[:2], dtype=np.float64)
+        exact = np.stack([
+            np.bincount(hash_np, weights=sign_np * row, minlength=sketch_size)
+            for row in first
+        ])
+        # Float accumulation order differs over ~P/S terms per bucket; the
+        # interpret-mode tests use 1e-5 at P=5000, scaled here by sqrt(P)
+        # growth.
+        ok = np.allclose(got, ref, rtol=1e-4, atol=1e-2)
+        say(
+            f"{name} {list(x.shape)} -> {sketch_size}: max rel err "
+            f"{_max_rel_err(got, ref):.3g} (against float64 bincount: kernel "
+            f"{_max_rel_err(got.reshape(-1, sketch_size)[:2], exact):.3g}, "
+            f"segment_sum {_max_rel_err(ref.reshape(-1, sketch_size)[:2], exact):.3g}) "
+            f"{'ok' if ok else 'MISMATCH'}"
         )
-    )(vec)
-    # Float accumulation order differs over ~P/S terms per bucket; the
-    # interpret-mode tests use 1e-5 at P=5000, scaled here by sqrt(P) growth.
-    ok = np.allclose(np.asarray(got), np.asarray(ref), rtol=1e-4, atol=1e-2)
-    say(
-        f"count_sketch [{sp}] -> {sketch_size}: max rel err "
-        f"{_max_rel_err(got, ref):.3g} {'ok' if ok else 'MISMATCH'}"
-    )
-    if not ok:
-        failures.append("count_sketch")
+        if not ok:
+            failures.append(name)
+        del got, ref, lifted
     require(not failures, f"kernels disagree with their lax paths: {failures}")
 
 

@@ -11,7 +11,6 @@ rates drops below 0.3 (attack detection — sketchguard.py:189-204); that
 
 from typing import Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -63,9 +62,8 @@ def make_sketchguard(
         }
 
     def aggregate(own, bcast, adj, round_idx, state, ctx: AggContext):
-        sketch_one = lambda v: count_sketch(v, hash_table, sign_table, sketch_size)
-        own_sk = jax.vmap(sketch_one)(own)
-        bcast_sk = jax.vmap(sketch_one)(bcast)
+        own_sk = count_sketch(own, hash_table, sign_table, sketch_size)
+        bcast_sk = count_sketch(bcast, hash_table, sign_table, sketch_size)
 
         own_sk_norm = jnp.sqrt(jnp.sum(own_sk * own_sk, axis=-1))
 

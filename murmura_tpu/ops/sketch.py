@@ -1,9 +1,10 @@
 """Count-Sketch compression (reference: murmura/aggregation/sketchguard.py:71-124).
 
-The reference computes the sketch host-side with ``np.bincount``; here it is
-``jax.ops.segment_sum`` of the sign-flipped parameter vector, so sketching all
-N nodes is one vmapped traced op inside the round step and the sketch itself
-is what would travel on the wire (sketchguard.py:126-155).
+The reference computes the sketch host-side with ``np.bincount``; here
+sketching all N nodes is one traced op inside the round step — on a TPU the
+one-hot MXU kernel of ``ops/pallas_sketch.py`` over the whole [N, P] matrix,
+elsewhere a vmapped ``jax.ops.segment_sum`` of the sign-flipped rows — and
+the sketch itself is what would travel on the wire (sketchguard.py:126-155).
 """
 
 from typing import Tuple
@@ -26,23 +27,24 @@ def make_sketch_tables(
 
 
 def count_sketch(
-    vector: jnp.ndarray,
+    rows: jnp.ndarray,
     hash_table: jnp.ndarray,
     sign_table: jnp.ndarray,
     sketch_size: int,
     use_pallas: "bool | None" = None,
 ) -> jnp.ndarray:
-    """Compress a [P] vector to a [sketch_size] Count-Sketch
+    """Compress the rows of an [N, P] matrix to [N, sketch_size]
+    Count-Sketches, or a [P] vector to [sketch_size]
     (reference: sketchguard.py:91-112).
 
     On TPU this dispatches to the Pallas MXU kernel
-    (ops/pallas_sketch.py) — XLA lowers segment_sum with random indices
-    to a serialized scatter, the one non-vectorizing op in the
-    Sketchguard round.  Elsewhere (CPU tests) it stays a segment_sum; an
-    explicit ``use_pallas=True`` there runs the kernel interpreted.  The
-    kernel is compiled exactly when the default backend is a TPU
-    (``chip_smoke.py`` asserts ``tpu_custom_call`` in the compiled
-    sketchguard round).
+    (ops/pallas_sketch.py), which sketches all rows in one call — XLA
+    lowers segment_sum with random indices to a serialized scatter, the one
+    non-vectorizing op in the Sketchguard round.  Elsewhere (CPU tests) it
+    stays a segment_sum, vmapped over the rows; an explicit
+    ``use_pallas=True`` there runs the kernel interpreted.  The kernel is
+    compiled exactly when the default backend is a TPU (``chip_smoke.py``
+    asserts ``tpu_custom_call`` in the compiled sketchguard round).
     """
     on_tpu = jax.default_backend() == "tpu"
     if use_pallas is None:
@@ -53,8 +55,12 @@ def count_sketch(
         from murmura_tpu.ops.pallas_sketch import count_sketch_pallas
 
         return count_sketch_pallas(
-            vector, hash_table, sign_table, sketch_size, interpret=not on_tpu
+            rows, hash_table, sign_table, sketch_size, interpret=not on_tpu
         )
-    return jax.ops.segment_sum(
-        sign_table * vector, hash_table, num_segments=sketch_size
-    )
+
+    def sketch_one(vector):
+        return jax.ops.segment_sum(
+            sign_table * vector, hash_table, num_segments=sketch_size
+        )
+
+    return jax.vmap(sketch_one)(rows) if rows.ndim == 2 else sketch_one(rows)

@@ -108,15 +108,36 @@ def test_candidate_select_kernel_compiles(one_chip, median, trim, dtype):
     )
 
 
-def test_count_sketch_kernel_compiles(one_chip):
+@pytest.mark.parametrize(
+    "shape,dtype",
+    [
+        ((FEMNIST_CNN_PARAMS,), jnp.float32),  # a [P] vector: the N = 1 case
+        # cnn_sketchguard_er_n64's own operand: 64 bf16-resident nodes
+        ((64, FEMNIST_CNN_PARAMS), jnp.bfloat16),
+        # the paper's 20-node jobs keep float32 states: three bf16 parts
+        ((20, FEMNIST_CNN_PARAMS), jnp.float32),
+        # more float32 rows than one block holds
+        ((NODES, FEMNIST_CNN_PARAMS), jnp.float32),
+    ],
+    ids=["vector_f32", "cell_n64_bf16", "paper_n20_f32", "n128_f32"],
+)
+def test_count_sketch_kernel_compiles(one_chip, shape, dtype):
     p = FEMNIST_CNN_PARAMS
-    vec = jax.ShapeDtypeStruct((p,), jnp.float32, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     hashes = jax.ShapeDtypeStruct((p,), jnp.int32, sharding=one_chip)
-    _compiled_text(
+    signs = jax.ShapeDtypeStruct((p,), jnp.float32, sharding=one_chip)
+    text = _compiled_text(
         count_sketch_pallas.lower(
-            vec, hashes, vec, sketch_size=SKETCH_SIZE, interpret=False
+            rows, hashes, signs, sketch_size=SKETCH_SIZE, interpret=False
         )
     )
+    n_rows = shape[0] if len(shape) == 2 else 1
+    if n_rows % 128:
+        # The kernel reads the states where they lie, in their own dtype:
+        # no pad, no float32 copy, no relayout of the tables.  (At a
+        # multiple of 128 rows the standalone compile picks a column-major
+        # entry layout and copies once, as for the kernels below.)
+        assert " copy(" not in text and " pad(" not in text, text
 
 
 # The flagship width rounded up to the 128-lane tile.  At an unaligned

@@ -56,12 +56,17 @@ class Cell:
     def chunk(self) -> int:
         return int(self.job["dispatch"].get("chunk", 1))
 
-    def train_kwargs(self) -> Dict[str, int]:
+    def train_kwargs(self) -> Dict[str, Any]:
+        """One call of the window: ``chunk`` rounds.  With
+        ``dispatch.defer_metrics`` the program dispatches them all ahead and
+        fetches their metrics afterwards (``Network.train``'s throughput
+        mode); the call returns when the last of them has run."""
         d = self.job["dispatch"]
         return {
             "rounds": self.chunk,
             "eval_every": int(d.get("eval_every", 1)),
             "rounds_per_dispatch": int(d.get("rounds_per_dispatch", 1)),
+            "defer_metrics": bool(d.get("defer_metrics", False)),
         }
 
     def program_config(self, seed: int) -> Dict[str, Any]:

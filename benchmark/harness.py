@@ -39,6 +39,37 @@ def p95(values: List[float]) -> float:
     return float(np.percentile(np.asarray(values, np.float64), 95))
 
 
+STALL = 1.5  # a call over this many medians is counted as a stall
+
+
+def window_numbers(calls: List[float], chunk: int, window_s: float) -> Dict[str, Any]:
+    """The window's statistics, from its calls' wall times (seconds).
+
+    ``round_ms``: the window's wall time over all its rounds, the time
+    between the calls included; ``round_ms_p95``: the 95th percentile of
+    the calls, over ``chunk``.  A stall of the host (a call of twice the
+    length, once or twice in a window) moves the first and not the second.
+    So that a reader can tell a stall from a change of level, the median
+    call is printed beside them (``median_round_ms``, judged by nothing)
+    with the stalls counted: ``stall_calls`` over ``STALL`` medians,
+    ``stall_s`` the seconds they took beyond a median each, ``longest`` the
+    three longest calls as [index, seconds].
+    """
+    times = np.asarray(calls, np.float64)
+    median = float(np.median(times))
+    over = times[times > STALL * median]
+    longest = np.argsort(-times)[:3]
+    return {
+        "round_ms": window_s / (len(calls) * chunk) * 1e3,
+        "round_ms_p95": p95(calls) / chunk * 1e3,
+        "median_round_ms": median / chunk * 1e3,
+        "calls": len(calls),
+        "stall_calls": int(over.size),
+        "stall_s": float((over - median).sum()),
+        "longest": [[int(i), float(times[i])] for i in longest],
+    }
+
+
 class Spans:
     """Host-clock spans of the harness's own calls into the program."""
 
@@ -120,6 +151,8 @@ def reference_job(cell: Cell, inputs: Dict[str, Any]):
         compute_dtype=cell.config["compute_dtype"],
         param_dtype=inputs["param_dtype"],
         node_block=int(job["correct"].get("node_block", 32)),
+        loss=cell.config.get("loss", "label"),
+        loss_params=dict(cell.config.get("loss_params") or {}),
     )
 
 
@@ -267,12 +300,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     program_seed = int(seed) % (2**31 - 1)
 
     network, inputs, captured, one_call = first_calls(cell, program_seed, spans)
-    chunk = cell.chunk
+    chunk, call_kwargs = cell.chunk, cell.train_kwargs()
     # Warm until a call compiles nothing (the layout of a step's own
-    # outputs can cost one more compile), at least two calls.
+    # outputs can cost one more compile), at least two calls.  A warm call
+    # is the window's call cut to two rounds (or one period of the eval):
+    # per-round dispatch runs the same programs whatever ``rounds`` is, and
+    # ``window_compiles`` holds the window to that.
+    warm_rounds = min(chunk, max(2, int(call_kwargs["eval_every"])))
+    warm_calls = []
     for i in range(8):
         before = counter.compiles
-        spans.timed("warm", one_call)
+        warm_calls.append(
+            spans.timed("warm", lambda: one_call(rounds=warm_rounds)) / warm_rounds
+        )
         if i >= 1 and counter.compiles == before:
             break
     setup_s = time.perf_counter() - t_start
@@ -283,7 +323,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     say(
         f"set-up {setup_s:.2f}s: build {spans.seconds['build']:.2f}s inputs "
         f"{spans.seconds['inputs']:.2f}s snapshots {spans.seconds['snapshot']:.2f}s first calls "
-        f"{spans.seconds['first_calls']:.2f}s warm {spans.seconds['warm']:.2f}s; "
+        f"{spans.seconds['first_calls']:.2f}s warm {spans.seconds['warm']:.2f}s "
+        f"(calls of {warm_rounds} rounds, a round {[round(c * 1e3, 2) for c in warm_calls]} ms); "
         f"compile {counter.compile_s:.2f}s in {counter.compiles} programs, "
         f"cache hits {counter.hits} misses {counter.misses}"
     )
@@ -313,11 +354,20 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     failed = int(sum(1 for v in window_loss if not np.isfinite(v)))
     peak = memory_peak_bytes()
     samples = samples_per_round(inputs, cell.job["training"]["local_epochs"])
-    round_ms = window_s / rounds * 1e3
+    tokens = samples * inputs["positions"]
+    window = window_numbers(calls, chunk, window_s)
     say(
         f"window {window_s:.3f}s: {rounds} rounds in {len(calls)} calls, "
         f"{rounds / window_s:.4f} rounds/s, {samples * rounds / window_s:.1f} "
-        f"samples/s, compiles in window {window_compiles}, peak {peak} bytes"
+        f"samples/s ({samples} samples"
+        + (f", {tokens} tokens" if tokens else "")
+        + f" a round), compiles in window {window_compiles}, peak {peak} bytes"
+    )
+    say(
+        f"window round_ms: {window['round_ms']:.4f} p95 "
+        f"{window['round_ms_p95']:.4f} median call {window['median_round_ms']:.4f}; "
+        f"{window['stall_calls']} calls over {STALL} medians took "
+        f"{window['stall_s']:.3f}s beyond them; longest [call, s] {window['longest']}"
     )
 
     hlo = program_texts(network) if trace else []
@@ -356,13 +406,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     device["memory_peak_bytes"] = peak
     result: Dict[str, Any] = {
         "correct": bool(correct), "attempted": rounds, "failed": failed,
+        "window": window,
     }
     if not trace:
-        values = {
-            "round_ms": round_ms,
-            "round_ms_p95": p95(calls) / chunk * 1e3,
-            "setup_s": setup_s,
-        }
+        values = {**window, "setup_s": setup_s}
         result["metrics"] = {
             m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
             for m in cell.metrics("end_to_end")
@@ -383,7 +430,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             f"trace {traced['rounds']} rounds in {traced['window_s']:.3f}s: device "
             f"busy {reduction.busy_s:.4f}s of {reduction.window_s:.4f}s; scopes "
             f"{ {k: round(v, 5) for k, v in sorted(reduction.scope_s.items())} } "
-            f"no scope {reduction.unscoped_s:.5f}s; programs "
+            f"no scope {reduction.unscoped_s:.5f}s; innermost "
+            f"{ {k: round(v, 5) for k, v in sorted(reduction.leaf_s.items())} } "
+            f"no scope {reduction.leaf_unscoped_s:.5f}s; programs "
             f"{ {k: round(v, 5) for k, v in sorted(reduction.program_s.items())} }"
         )
         context = {

@@ -10,13 +10,13 @@ topology every round and compiles the second in); they are read off the
 built network and held to what the workload file states (``problems``).
 
 Weights: the configuration's plain reference draws one node's
-(``reference/<model>.py init``); every node gets its own key.  Data:
-labelled clusters, ``x = centre[y] + cluster_std * noise`` with class
-centres of ``centre_std`` an element and uniform labels, the first
-``train`` samples of a node trained on and the rest held out.
+(``reference/<model>.py init``); every node gets its own key.  Data: the
+generator the configuration's ``data.generator`` names
+(``data/<generator>.py make``: ``clusters``, labelled float clusters, or
+``tokens``, integer id sequences), the first ``train`` samples of a node
+trained on and the rest held out.
 """
 
-import functools
 import importlib
 from typing import Any, Dict, List
 
@@ -24,12 +24,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmark.data import stream_key
+
 DATA_KEYS = ("x", "y", "mask", "num_samples", "eff_batch", "steps", "eval_x",
              "eval_y", "eval_mask", "probe_x", "probe_y", "probe_mask")
-
-
-def _key(seed: int, stream: int):
-    return jax.random.fold_in(jax.random.PRNGKey(seed), stream)
 
 
 def make_params(model: str, doc: dict, n: int, seed: int, dtype) -> Any:
@@ -41,30 +39,14 @@ def make_params(model: str, doc: dict, n: int, seed: int, dtype) -> Any:
         tree = jax.vmap(lambda k: init(k, doc))(keys)
         return jax.tree_util.tree_map(lambda l: l.astype(dtype), tree)
 
-    return draw(jax.random.split(_key(seed, 1), n))
-
-
-@functools.partial(jax.jit, static_argnames=("n", "train", "held", "shape", "classes"))
-def _clusters(key, n, train, held, shape, classes, centre_std, cluster_std):
-    kc, ky, kx = jax.random.split(key, 3)
-    centres = centre_std * jax.random.normal(kc, (classes,) + shape, jnp.float32)
-    y = jax.random.randint(ky, (n, train + held), 0, classes, jnp.int32)
-    noise = jax.random.normal(kx, (n, train + held) + shape, jnp.float32)
-    x = centres[y] + cluster_std * noise
-    return x[:, :train], y[:, :train], x[:, train:], y[:, train:]
+    return draw(jax.random.split(stream_key(seed, 1), n))
 
 
 def make_data(doc: dict, n: int, seed: int) -> Dict[str, Any]:
-    """``x, y`` [n, train, ...] and ``eval_x, eval_y`` [n, held, ...]."""
-    data = doc["data"]
-    held = int(data["held_out_per_node"])
-    train = int(data["samples_per_node"]) - held
-    x, y, ex, ey = _clusters(
-        _key(seed, 2), n, train, held, tuple(data["params"]["input_shape"]),
-        int(data["params"]["num_classes"]), float(data["centre_std"]),
-        float(data["cluster_std"]),
-    )
-    return {"x": x, "y": y, "eval_x": ex, "eval_y": ey}
+    """``x, y`` [n, train, ...] and ``eval_x, eval_y`` [n, held, ...] by the
+    generator the configuration names."""
+    generator = importlib.import_module(f"benchmark.data.{doc['data']['generator']}")
+    return generator.make(doc, n, seed)
 
 
 def _like(new, old):
@@ -72,6 +54,12 @@ def _like(new, old):
     if new.shape != old.shape:
         raise ValueError(
             f"the benchmark's input has shape {new.shape}, the program's {old.shape}"
+        )
+    integers = [jnp.issubdtype(a.dtype, jnp.integer) for a in (new, old)]
+    if integers[0] != integers[1]:
+        raise ValueError(
+            f"the benchmark's input is {new.dtype}, the program's {old.dtype}: "
+            "ids are not cast to floats, nor floats to ids"
         )
     return jax.device_put(new.astype(old.dtype), old.sharding)
 
@@ -113,8 +101,12 @@ def read(network, cell, seed: int) -> Dict[str, Any]:
     the batch layout read off the built network."""
     small = {k: np.asarray(network._data[k]) for k in DATA_KEYS
              if k not in ("x", "y", "eval_x", "eval_y", "probe_x", "probe_y")}
+    x = network._data["x"]
     return {
         "seed": int(seed),
+        # Positions of a sample: the ids of a token sequence [n, samples, T].
+        "positions": int(x.shape[2])
+        if x.ndim == 3 and jnp.issubdtype(x.dtype, jnp.integer) else 0,
         "adjacency": np.asarray(network.topology.mask(), np.float32),
         "compromised": np.asarray(network.compromised, np.float32),
         "data": small,

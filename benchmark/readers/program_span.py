@@ -12,7 +12,9 @@
   ``spans``: its rise since the session began less the ring.  The same
   loop with no Python tracer on it: what the traced reading costs shows as
   the difference.  A span's self time is there its seconds less those of
-  the spans named in ``less``, its children;
+  the spans named in ``less`` as far as the ring shows them under it: all
+  of a fetch under a fetch every round, none of it where the rounds are
+  dispatched ahead and fetched after their round spans have closed;
 - ``table`` with ``spans``: seconds of the whole run from an always-on
   table (``first_dispatch``: the dispatches during which a program was
   compiled or loaded).
@@ -42,6 +44,16 @@ def self_of(record, records):
     return seconds(record) - sum(
         seconds(r) for r in records if r["parent"] == record["id"]
     )
+
+
+def share_under(records, name, parent_name):
+    """The share of the ring's seconds under spans ``name`` that lies in
+    children of a ``parent_name`` span."""
+    parents = {r["id"] for r in records if r["name"] == parent_name}
+    rows = [r for r in records if r["name"] == name]
+    total = sum(seconds(r) for r in rows)
+    under = sum(seconds(r) for r in rows if r["parent"] in parents)
+    return under / total if total > 0 else 0.0
 
 
 def ring_rounds(records):
@@ -95,6 +107,7 @@ def read(context, spans=(), self_of_span=None, table=None, untraced=False,
     total = sum(after.get(name, none)[1] for name in spans)
     if self_of_span is not None:
         total += after.get(self_of_span, none)[1] - sum(
-            after.get(name, none)[1] for name in less
+            after.get(name, none)[1] * share_under(records, name, self_of_span)
+            for name in less
         )
     return total / rounds * 1e3

@@ -5,8 +5,8 @@ broadcasts by the job's rule, and every node is evaluated.
 
 It imports nothing of the program.  Its inputs are the cell's inputs (the
 initial parameters, the data, the graph, the compromised set, the seed)
-and the job as the workload file states it.  The model, the rule and the
-attack are modules of this directory found by name.
+and the job as the workload file states it.  The model, its loss, the rule and
+the attack are modules of this directory found by name.
 
 The batch schedule is part of the job's definition: round r draws from
 ``fold_in(PRNGKey(seed), r)``; the first half of its split is the training
@@ -17,7 +17,7 @@ the node's samples by ``argsort(uniform)``; batch t takes positions
 
 import importlib
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
 import jax
@@ -48,6 +48,10 @@ class Job:
     # "no_exchange" leaves the rule out: every node keeps what it trained.
     fault: Optional[str] = None
     node_block: int = 32
+    # ``loss_<loss>.py`` of this directory: the training loss and the
+    # evaluation; ``loss_params`` as the configuration states them.
+    loss: str = "label"
+    loss_params: Dict[str, Any] = field(default_factory=dict)
 
 
 def _module(kind: str, name: str):
@@ -82,20 +86,17 @@ def batch_schedule(seed: int, round_idx: int, data: dict, job: Job):
     return np.stack(epochs).astype(np.int32), batch_mask, live, attack_key
 
 
-def _losses(apply, dtype):
-    def loss(params, x, y, m):
-        logp = jax.nn.log_softmax(apply(params, x, dtype), axis=-1)
-        nll = -jnp.take_along_axis(logp, y[:, None], axis=-1)[:, 0]
-        return (nll * m).sum() / jnp.maximum(m.sum(), 1.0)
-
-    return loss
+def _loss(job: Job, part: str):
+    """``training`` or ``evaluation`` of the job's loss for its model."""
+    return getattr(_module("loss_", job.loss), part)(
+        _module("", job.model).apply, job.compute_dtype, job.loss_params
+    )
 
 
 def make_trainer(job: Job):
     """Jitted ``(params, x, y, idx [E*T, n, B], bmask [n, B], upd [E*T, n])
     -> params`` for a block of nodes."""
-    loss = _losses(_module("", job.model).apply, job.compute_dtype)
-    grad = jax.vmap(jax.grad(loss))
+    grad = jax.vmap(jax.grad(_loss(job, "training")))
 
     @jax.jit
     def train(params, x, y, idx, bmask, upd):
@@ -117,22 +118,19 @@ def make_trainer(job: Job):
 
 
 def make_evaluator(job: Job):
-    apply = _module("", job.model).apply
+    """Jitted ``(params, x, y, m) -> (loss [n], accuracy [n])`` for a block
+    of nodes' held-out samples."""
+    evaluate = _loss(job, "evaluation")
 
     @jax.jit
-    def evaluate(params, x, y, m):
+    def evaluate_block(params, x, y, m):
         def node(p, xi, yi, mi):
             p = jax.tree_util.tree_map(lambda l: l.astype(jnp.float32), p)
-            logits = apply(p, xi, job.compute_dtype)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(logp, yi[:, None], axis=-1)[:, 0]
-            total = jnp.maximum(mi.sum(), 1.0)
-            hit = (jnp.argmax(logits, -1) == yi).astype(jnp.float32)
-            return (nll * mi).sum() / total, (hit * mi).sum() / total
+            return evaluate(p, xi, yi, mi)
 
         return jax.vmap(node)(params, x, y, m)
 
-    return evaluate
+    return evaluate_block
 
 
 def _after(tree) -> float:

@@ -1,17 +1,21 @@
 """From a profiler trace (``.xplane.pb``) to device numbers.
 
 The one reduction of the benchmark: which intervals the device was busy
-in, how much device time each ``murmura.*`` scope took, which operations
-took most, and what the host was doing in the longest idle gaps.  Read with
-``jax.profiler.ProfileData`` and nothing else.
+in, how much device time each ``murmura.*`` scope took (by the outermost
+events, and by the innermost under their whole chain of labels), which
+operations took most, and what the host was doing in the longest idle gaps.
+Read with ``jax.profiler.ProfileData`` and nothing else.
 
 What a v5e trace holds (looked at by hand, PR 25): one plane per chip named
 ``/device:TPU:<i>``; on it the line ``XLA Ops`` carries one event per
 executed HLO operation, with its start and duration, and a ``tf_op`` (or
 ``name``/``long_name``) stat that holds the operation's ``op_name``
 metadata, which is where ``jax.named_scope`` puts ``murmura.train`` and the
-rest; the line ``XLA Modules`` carries one event per executed program.  The
-host's threads are lines of the plane ``/host:CPU``.
+rest; the line ``XLA Modules`` carries one event per executed program.  An
+operation that runs others (a ``while``, a ``call``) is one event with the
+others' events inside its interval, two levels deep at most in the round
+program (my look, PR 29).  The host's threads are lines of the plane
+``/host:CPU``.
 """
 
 import glob
@@ -50,18 +54,29 @@ def gaps(intervals: Iterable[Tuple[int, int]]) -> List[Tuple[int, int]]:
     return out
 
 
+_LABEL = re.compile(re.escape(SCOPE_PREFIX) + r"[^/ )\"']*")
+
+
+def chain_of(texts: Iterable[str]) -> Optional[str]:
+    """Every ``murmura.<scope>`` named in an operation's metadata, outermost
+    first, joined by ``/``: ``murmura.train/murmura.attention`` for an
+    operation under a label inside the training loop's.  A label that a
+    transform repeats (``murmura.x/transpose(jvp(murmura.x))/mul``: the
+    backward pass of what ran under ``murmura.x``) counts once."""
+    for text in texts:
+        labels = _LABEL.findall(text)
+        if labels:
+            return "/".join(
+                label for i, label in enumerate(labels)
+                if i == 0 or label != labels[i - 1]
+            )
+    return None
+
+
 def scope_of(texts: Iterable[str]) -> Optional[str]:
     """The outermost ``murmura.<scope>`` named in an operation's metadata."""
-    for text in texts:
-        at = text.find(SCOPE_PREFIX)
-        if at >= 0:
-            rest = text[at:]
-            for stop in "/ )\"'":
-                cut = rest.find(stop)
-                if cut >= 0:
-                    rest = rest[:cut]
-            return rest
-    return None
+    chain = chain_of(texts)
+    return None if chain is None else chain.split("/", 1)[0]
 
 
 _HLO_LINE = re.compile(
@@ -75,18 +90,19 @@ ScopeMap = Dict[str, Dict[str, str]]
 
 
 def scope_map_from_hlo(texts: Iterable[str]) -> ScopeMap:
-    """Program name -> HLO operation name -> ``murmura.*`` scope, from the
-    ``op_name`` metadata of compiled programs' text (``compiled.as_text()``):
-    the join for traces whose events carry no metadata of their own.  A
-    fusion has the metadata of its root."""
+    """Program name -> HLO operation name -> chain of ``murmura.*`` labels
+    (``chain_of``), from the ``op_name`` metadata of compiled programs' text
+    (``compiled.as_text()``, every computation of it, a ``while``'s body
+    among them): the join for traces whose events carry no metadata of
+    their own.  A fusion has the metadata of its root."""
     out: ScopeMap = {}
     for text in texts:
         module = _HLO_MODULE.search(text)
         ops = out.setdefault(module.group(1) if module else "", {})
         for name, op_name in _HLO_LINE.findall(text):
-            scope = scope_of([op_name])
-            if scope is not None:
-                ops.setdefault(name, scope)
+            chain = chain_of([op_name])
+            if chain is not None:
+                ops.setdefault(name, chain)
     return out
 
 
@@ -97,10 +113,10 @@ def op_id(event_name: str) -> str:
     return event_name.split(" = ", 1)[0].strip().lstrip("%")
 
 
-def _scope_from_map(scope_map: ScopeMap, program: Optional[str], op: str):
-    """The scope of operation ``op`` of the program whose trace name is
+def _chain_from_map(scope_map: ScopeMap, program: Optional[str], op: str):
+    """The label chain of operation ``op`` of the program whose trace name is
     ``program`` (the HLO module's name, at times with a suffix); without a
-    program, the scope every program that has such an operation agrees on."""
+    program, the chain every program that has such an operation agrees on."""
     if program is not None:
         for module, ops in scope_map.items():
             if module and program.startswith(module):
@@ -117,6 +133,11 @@ class Reduction:
     busy_s: float = 0.0  # mean over devices of the union of op intervals
     window_s: float = 0.0  # first op's start to last op's end, widest device
     scope_s: Dict[str, float] = field(default_factory=dict)  # mean over devices
+    # Seconds of the innermost events (those that contain no other event)
+    # by their whole chain of labels, ``murmura.train/murmura.<inner>``; an
+    # event without a label of its own has that of the event around it.
+    leaf_s: Dict[str, float] = field(default_factory=dict)  # mean over devices
+    leaf_unscoped_s: float = 0.0
     op_s: Dict[str, float] = field(default_factory=dict)  # mean over devices
     program_s: Dict[str, float] = field(default_factory=dict)
     idle_gaps: List[Tuple[str, float]] = field(default_factory=list)
@@ -186,34 +207,55 @@ def reduce_space(space, scope_map: Optional[ScopeMap] = None) -> Reduction:
         red.window_s = max(red.window_s, window)
         # Scope and operation times count each event's own duration; a
         # nested (child) event lies inside its parent on this line, so
-        # only events that no other event contains are summed.
+        # only events that no other event contains are summed there, and
+        # only those that contain no other in the innermost table.
         order = sorted(range(len(ops)), key=lambda i: (spans[i][0], -spans[i][1]))
         runs = sorted(
             (int(e.start_ns), int(e.start_ns + e.duration_ns), e.name)
             for e in programs
         )
-        run_at, outer_end = 0, -1
+        run_at = 0
+        open_events: List[list] = []  # [end, chain, seconds, contains another]
+
+        def close(event):
+            _, chain, seconds, parent = event
+            if parent:
+                return
+            if chain is None:
+                red.leaf_unscoped_s += seconds
+            else:
+                red.leaf_s[chain] = red.leaf_s.get(chain, 0.0) + seconds
+
         for i in order:
             a, b = spans[i]
-            if b <= outer_end:
-                continue
-            outer_end = b
             ev = ops[i]
             seconds = (b - a) / 1e9 / red.devices
-            scope = scope_of(_event_texts(ev))
-            if scope is None and scope_map:
+            chain = chain_of(_event_texts(ev))
+            if chain is None and scope_map:
                 while run_at + 1 < len(runs) and runs[run_at][1] <= a:
                     run_at += 1
                 inside = runs and runs[run_at][0] <= a < runs[run_at][1]
-                scope = _scope_from_map(
+                chain = _chain_from_map(
                     scope_map, runs[run_at][2] if inside else None, op_id(ev.name)
                 )
+            while open_events and b > open_events[-1][0]:
+                close(open_events.pop())
+            if open_events:
+                if b == a:
+                    continue  # a marker of no length makes no parent
+                open_events[-1][3] = True
+                open_events.append([b, chain or open_events[-1][1], seconds, False])
+                continue
+            open_events.append([b, chain, seconds, False])
+            scope = scope_of([chain or ""])
             if scope is None:
                 red.unscoped_s += seconds
             else:
                 red.scope_s[scope] = red.scope_s.get(scope, 0.0) + seconds
             label = f"%{op_id(ev.name)} [{scope or 'no scope'}]"
             red.op_s[label] = red.op_s.get(label, 0.0) + seconds
+        while open_events:
+            close(open_events.pop())
         for ev in programs:
             red.program_s[ev.name] = (
                 red.program_s.get(ev.name, 0.0) + ev.duration_ns / 1e9 / red.devices
@@ -249,11 +291,12 @@ def reduce_dir(trace_dir: str, scope_map: Optional[ScopeMap] = None) -> Reductio
 
 
 def cut_to_text(space, rounds: int = 1, scope_map: Optional[ScopeMap] = None,
-                host_min_ns: int = 200_000) -> str:
+                host_min_ns: int = 200_000, nested: bool = False) -> str:
     """A small copy of a trace as an ``XSpace`` text proto: the device
-    planes' program line and outermost operations, under their short names
-    and with the scope the join gave each as a stat of its own, and the
-    host's longer events; from the first device operation to the
+    planes' program line and outermost operations (with ``nested`` the
+    operations inside them too), under their short names and with the
+    label chain the join gave each as a stat of its own, and the host's
+    longer events; from the first device operation to the
     ``rounds + 1``-th run of the first program (a round's first program
     is the same every round).  How the recorded trace under ``testdata/``
     was made; ``ProfileData.from_text_proto`` reads it back."""
@@ -291,17 +334,17 @@ def cut_to_text(space, rounds: int = 1, scope_map: Optional[ScopeMap] = None,
                     continue
                 stats = ""
                 if ops_line:
-                    if a + d <= outer_end:
-                        continue  # a child of the event before: not summed
-                    outer_end = a + d
-                    scope = scope_of(_event_texts(ev))
-                    if scope is None and scope_map:
+                    if a + d <= outer_end and not nested:
+                        continue  # a child of the event before
+                    outer_end = max(outer_end, a + d)
+                    chain = chain_of(_event_texts(ev))
+                    if chain is None and scope_map:
                         inside = [r[2] for r in runs if r[0] <= a < r[1]]
-                        scope = _scope_from_map(
+                        chain = _chain_from_map(
                             scope_map, inside[0] if inside else None, op_id(ev.name)
                         )
-                    if scope is not None:
-                        stats = f" stats {{ metadata_id: 1 str_value: {quote(scope)} }}"
+                    if chain is not None:
+                        stats = f" stats {{ metadata_id: 1 str_value: {quote(chain)} }}"
                 mid = names.setdefault(
                     f"%{op_id(ev.name)}" if ops_line else ev.name, len(names) + 1
                 )
@@ -344,9 +387,9 @@ def dump(path: str, events: int = 6) -> None:
 
 if __name__ == "__main__":
     # ``trace_reduce.py <dir>``: print what the trace holds.
-    # ``trace_reduce.py <dir> --cut <rounds> <out>``: write the small copy
-    # (``<dir>/scope_map.json``, which a ``--keep-trace`` run leaves, gives
-    # the join).
+    # ``trace_reduce.py <dir> --cut <rounds> <out> [nested]``: write the
+    # small copy (``<dir>/scope_map.json``, which a ``--keep-trace`` run
+    # leaves, gives the join), with ``nested`` the inner operations too.
     import json
     import sys
 
@@ -358,7 +401,8 @@ if __name__ == "__main__":
     if len(sys.argv) > 2 and sys.argv[2] == "--cut":
         from jax.profiler import ProfileData
 
-        text = cut_to_text(ProfileData.from_file(found), int(sys.argv[3]), scope_map)
+        text = cut_to_text(ProfileData.from_file(found), int(sys.argv[3]), scope_map,
+                           nested=sys.argv[5:] == ["nested"])
         with open(sys.argv[4], "w") as f:
             f.write(text)
         print(f"{sys.argv[4]}: {len(text)} bytes")

@@ -47,7 +47,13 @@ def make_root(tmp: Path) -> Path:
         job["correct"]["limits"] = limits
         job["training"]["batch_size"] = 8
         job["correct"]["node_block"] = 4
-        job["trace_rounds"] = 2 * job["dispatch"]["chunk"]
+        # The cell's own mode (rounds dispatched ahead, two to a call) in
+        # one job, a fetch every round, one round to a call, in the other.
+        job["dispatch"].update(
+            {"chunk": 1, "defer_metrics": False} if "kreg" in name else {"chunk": 2}
+        )
+        assert job["dispatch"]["defer_metrics"] is ("kreg" not in name)
+        job["trace_rounds"] = 2
         (root / "benchmark" / "workloads" / f"{name}.json").write_text(json.dumps(job))
         workloads.append({"name": name, "config": "tiny_cnn", "traffic": name,
                           "chips": 1, "why": "a test's cell"})

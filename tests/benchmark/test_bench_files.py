@@ -77,6 +77,30 @@ def test_cell_resolves_by_name(name):
     _resolves(Cell(name))
 
 
+TRAFFIC_FILES = sorted(p.stem for p in (BENCH / "workloads").glob("*.json"))
+
+
+@pytest.mark.parametrize("name", TRAFFIC_FILES)
+def test_a_call_covers_one_period_of_what_recurs(name):
+    """``round_ms_p95`` is a percentile of calls: every call has to be
+    alike, so a chunk holds whole periods of the job's evaluation."""
+    dispatch = json.loads((BENCH / "workloads" / f"{name}.json").read_text())["dispatch"]
+    every, chunk = int(dispatch.get("eval_every", 1)), int(dispatch.get("chunk", 1))
+    assert chunk >= 1 and every >= 1
+    if every > 1:
+        assert chunk % every == 0, (chunk, every)
+
+
+@pytest.mark.parametrize("name", CONFIG_FILES)
+def test_configuration_names_its_generator_and_its_loss(name):
+    doc = json.loads((BENCH / "configs" / f"{name}.json").read_text())
+    generator = importlib.import_module(f"benchmark.data.{doc['data']['generator']}")
+    assert callable(generator.make)
+    loss = importlib.import_module(
+        f"benchmark.reference.loss_{doc.get('loss', 'label')}")
+    assert callable(loss.training) and callable(loss.evaluation)
+
+
 def test_every_file_belongs_to_a_cell():
     """No workload, configuration, rule or roofline is kept that no cell of
     ``BENCHMARK.json`` reaches."""

@@ -36,6 +36,9 @@ def test_result_object_keeps_the_contract(timed_run, root):
     assert set(r["metrics"]) == {"round_ms", "round_ms_p95", "setup_s"}
     for m in r["metrics"].values():
         assert m["value"] > 0 and m["unit"] in ("ms", "s")
+    window = r["window"]
+    assert r["metrics"]["round_ms"]["value"] == window["round_ms"]
+    assert window["calls"] * 2 == r["attempted"] and window["median_round_ms"] > 0
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(r["device"])
     limits = Cell("tiny_sketchguard", root=root).job["correct"]["limits"]
     assert set(r["checks"]) == set(limits) | {"window_compiles", "inputs_off"}
@@ -64,7 +67,7 @@ def test_traced_run_reports_what_its_readers_find(root, monkeypatch):
         r["metrics"]
     )
     for absent in ("train_scope_ms", "aggregate_roofline", "device_idle_pct",
-                   "host_gap_ms"):
+                   "host_gap_ms", "train_leaf_ops_ms"):
         assert absent not in r["metrics"]
     assert "busy_s" in r["device"] and "breakdown" in r
     assert r["correct"] is True
@@ -200,6 +203,33 @@ def test_no_chip_no_result():
     )
     assert out.returncode == 2 and out.stdout.strip() == ""
     assert "needs 1 TPU chip" in out.stderr
+
+
+def test_a_stall_moves_round_ms_and_not_the_median_call():
+    """One planted call of ten times the length among 300 alike: the
+    end-to-end ``round_ms`` is taken over all the time of the window and
+    moves; the median call, printed beside it, says it was a stall."""
+    rng = np.random.default_rng(3)
+    calls = list(0.129 + 0.0002 * rng.standard_normal(300))
+    quiet = harness.window_numbers(calls, 1, sum(calls))
+    stalled = calls[:140] + [1.29] + calls[141:]
+    hit = harness.window_numbers(stalled, 1, sum(stalled))
+    assert hit["round_ms"] == pytest.approx(sum(stalled) / 300 * 1e3, rel=1e-12)
+    assert hit["round_ms"] > 1.025 * quiet["round_ms"]
+    assert hit["median_round_ms"] == pytest.approx(quiet["median_round_ms"], rel=1e-4)
+    assert hit["round_ms_p95"] == pytest.approx(quiet["round_ms_p95"], rel=2e-3)
+    assert quiet["median_round_ms"] == pytest.approx(quiet["round_ms"], rel=1e-3)
+    assert (quiet["stall_calls"], quiet["stall_s"]) == (0, 0.0)
+    assert hit["stall_calls"] == 1 and hit["longest"][0] == [140, 1.29]
+    assert hit["stall_s"] == pytest.approx(1.29 - 0.129, rel=1e-2)
+    # The time between the calls is the window's too.
+    gaps = harness.window_numbers(calls, 1, sum(calls) + 0.3)
+    assert gaps["round_ms"] == pytest.approx(quiet["round_ms"] + 1.0, rel=1e-9)
+    assert gaps["median_round_ms"] == quiet["median_round_ms"]
+    # A chunk of several rounds a call: every number is per round.
+    pairs = harness.window_numbers([2 * c for c in calls], 2, 2 * sum(calls))
+    for k in ("round_ms", "round_ms_p95", "median_round_ms"):
+        assert pairs[k] == pytest.approx(quiet[k], rel=1e-12)
 
 
 def test_samples_per_round_counts_the_honest_nodes_batches():

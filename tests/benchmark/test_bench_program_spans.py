@@ -97,6 +97,30 @@ def test_per_round_after_the_session_from_the_table(monkeypatch, capsys, args, w
     assert "4 rounds after the session" in capsys.readouterr().out
 
 
+def test_spans_outside_the_round_are_not_taken_from_its_self_time(monkeypatch):
+    """Rounds dispatched ahead: the fetch and the record come after the
+    round spans have closed, so the round's own time is its seconds less
+    the stage and the dispatches alone."""
+    ring = []
+    for r in _ring():
+        if r["name"] in ("murmura.host.fetch", "murmura.host.record"):
+            r = {**r, "parent": None}
+        elif r["name"] == "murmura.round":  # 4 ms: stage 1, dispatch 3
+            r = {**r, "end_ns": r["start_ns"] + 4 * MS}
+        ring.append(r)
+    spans, before = _tables()
+    spans["murmura.round"][1] -= 2 * 6e-3 + 4 * 5.25e-3  # rounds of 4 and 2.75 ms
+    monkeypatch.setattr(program_span, "host_spans",
+                        lambda: _program(ring, spans=spans, before=before))
+    args = {"self_of_span": "murmura.round",
+            "less": ["murmura.host.stage", "murmura.host.dispatch",
+                     "murmura.host.fetch", "murmura.host.record"]}
+    assert program_span.read({"traced_rounds": 2}, **{"self_of_span": "murmura.round"}) \
+        == pytest.approx(0.0, abs=1e-9)
+    got = program_span.read({"traced_rounds": 2}, untraced=True, **args)
+    assert got == pytest.approx(2.75 - 0.5 - 1.5)
+
+
 def test_a_window_that_ends_with_the_session_has_no_untraced_round(monkeypatch):
     spans, before = _tables()
     for name, row in spans.items():  # take the four rounds after it away
@@ -190,7 +214,7 @@ def test_traced_run_reports_the_programs_host_spans(tmp_path, monkeypatch):
     )
     root = make_root(tmp_path)
     r = harness.run_cell(Cell("tiny_sketchguard", root=root), seed=2**31 + 26,
-                         seconds=4.0, trace=True)
+                         seconds=8.0, trace=True)  # a traced call of two rounds, then one more
     assert r["correct"] is True
     metrics = r["metrics"]
     hosts = ("host_stage_ms", "host_dispatch_ms", "host_record_ms",

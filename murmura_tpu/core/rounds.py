@@ -479,8 +479,7 @@ def build_round_program(
         data_arrays["hp_attack_scale"] = np.asarray(1.0, np.float32)
 
     # ---- per-node loss ----------------------------------------------------
-    def node_loss(params_i, xb, yb, mb, key, round_idx):  # murmura: traced
-        outputs = model.apply(params_i, xb, key, True)
+    def loss_of_outputs(outputs, yb, mb, round_idx):  # murmura: traced
         if evidential:
             lambda_t = (
                 jnp.minimum(1.0, round_idx / max(1, annealing_rounds)) * lambda_weight
@@ -489,7 +488,29 @@ def build_round_program(
         loss, _ = masked_cross_entropy(outputs, yb, mb)
         return loss
 
-    grad_fn = jax.grad(node_loss)
+    def node_loss(params_i, xb, yb, mb, key, round_idx):  # murmura: traced
+        outputs = model.apply(params_i, xb, key, True)
+        return loss_of_outputs(outputs, yb, mb, round_idx)
+
+    def summed_loss(params, xb, yb, mb, keys, round_idx):  # murmura: traced
+        # Nodes share nothing in apply_stacked, so the gradient of the sum
+        # with respect to the stacked tree is the per-node gradients.
+        outputs = model.apply_stacked(params, xb, keys, True)
+        losses = jax.vmap(loss_of_outputs, in_axes=(0, 0, 0, None))(
+            outputs, yb, mb, round_idx
+        )
+        return losses.sum()
+
+    # A model that offers a stacked forward (models/core.py Model) trains
+    # and evaluates through it; every other model through vmap(apply).
+    if model.apply_stacked is not None:
+        grads_fn = jax.grad(summed_loss)
+
+        def stacked_apply(params, x):  # murmura: traced
+            return model.apply_stacked(params, x, None, False)
+    else:
+        grads_fn = jax.vmap(jax.grad(node_loss), in_axes=(0, 0, 0, 0, 0, None))
+        stacked_apply = jax.vmap(lambda p, x: model.apply(p, x, None, False))
 
     def local_training(params, d, honest, key, round_idx):  # murmura: traced
         """local_epochs x masked-batch SGD (reference: node.py:59-109)."""
@@ -517,7 +538,7 @@ def build_round_program(
                 batch_mask = (j[None, :] < d["eff_batch"][:, None]).astype(jnp.float32)
 
                 node_keys = jax.random.split(jax.random.fold_in(step_key, t), n)
-                grads = jax.vmap(grad_fn, in_axes=(0, 0, 0, 0, 0, None))(
+                grads = grads_fn(
                     params, xb, yb, batch_mask, node_keys, round_idx
                 )
                 update = honest * (t < d["steps"]).astype(jnp.float32)  # [N]
@@ -527,13 +548,14 @@ def build_round_program(
                 # Update math in float32, cast back: keeps bf16 params
                 # (tpu.param_dtype) dtype-stable through the scan carry and
                 # rounds once per step instead of per multiply.
-                new_params = jax.tree_util.tree_map(
-                    lambda p, g: (
-                        p - eff_lr * _broadcast_to_leaf(update, p) * g.astype(jnp.float32)
-                    ).astype(p.dtype),
-                    params,
-                    grads,
-                )
+                with jax.named_scope("murmura.update"):
+                    new_params = jax.tree_util.tree_map(
+                        lambda p, g: (
+                            p - eff_lr * _broadcast_to_leaf(update, p) * g.astype(jnp.float32)
+                        ).astype(p.dtype),
+                        params,
+                        grads,
+                    )
                 return new_params, None
 
             params, _ = jax.lax.scan(step_body, params, jnp.arange(max_steps))
@@ -554,48 +576,47 @@ def build_round_program(
             y = jnp.pad(y, [(0, 0), (0, pad)])
             mask = jnp.pad(mask, [(0, 0), (0, pad)])
 
-        def eval_node(params_i, x_i, y_i, m_i):
-            def chunk_body(carry, sl):
-                xc = jax.lax.dynamic_slice_in_dim(x_i, sl * chunk, chunk, 0)
-                yc = jax.lax.dynamic_slice_in_dim(y_i, sl * chunk, chunk, 0)
-                mc = jax.lax.dynamic_slice_in_dim(m_i, sl * chunk, chunk, 0)
-                outputs = model.apply(params_i, xc, None, False)
-                cnt = mc.sum()
-                if evidential:
-                    unc = uncertainty_metrics(outputs)
-                    probs = unc["probs"]
-                    nll = -jnp.log(
-                        jnp.take_along_axis(probs, yc[:, None], axis=-1)[:, 0] + 1e-10
-                    )
-                    row = {
-                        "loss": (nll * mc).sum(),
-                        "correct": (
-                            (jnp.argmax(outputs, -1) == yc).astype(jnp.float32) * mc
-                        ).sum(),
-                        "vacuity": (unc["vacuity"] * mc).sum(),
-                        "entropy": (unc["entropy"] * mc).sum(),
-                        "strength": (unc["strength"] * mc).sum(),
-                        "count": cnt,
-                    }
-                else:
-                    logp = jax.nn.log_softmax(outputs, -1)
-                    nll = -jnp.take_along_axis(logp, yc[:, None], axis=-1)[:, 0]
-                    row = {
-                        "loss": (nll * mc).sum(),
-                        "correct": (
-                            (jnp.argmax(outputs, -1) == yc).astype(jnp.float32) * mc
-                        ).sum(),
-                        "count": cnt,
-                    }
-                return carry, row
+        def chunk_rows(outputs, yc, mc):  # one node's chunk
+            cnt = mc.sum()
+            if evidential:
+                unc = uncertainty_metrics(outputs)
+                probs = unc["probs"]
+                nll = -jnp.log(
+                    jnp.take_along_axis(probs, yc[:, None], axis=-1)[:, 0] + 1e-10
+                )
+                return {
+                    "loss": (nll * mc).sum(),
+                    "correct": (
+                        (jnp.argmax(outputs, -1) == yc).astype(jnp.float32) * mc
+                    ).sum(),
+                    "vacuity": (unc["vacuity"] * mc).sum(),
+                    "entropy": (unc["entropy"] * mc).sum(),
+                    "strength": (unc["strength"] * mc).sum(),
+                    "count": cnt,
+                }
+            logp = jax.nn.log_softmax(outputs, -1)
+            nll = -jnp.take_along_axis(logp, yc[:, None], axis=-1)[:, 0]
+            return {
+                "loss": (nll * mc).sum(),
+                "correct": (
+                    (jnp.argmax(outputs, -1) == yc).astype(jnp.float32) * mc
+                ).sum(),
+                "count": cnt,
+            }
 
-            _, rows = jax.lax.scan(chunk_body, 0, jnp.arange(n_chunks))
-            total = jnp.maximum(rows["count"].sum(), 1.0)
-            out = {k: v.sum() / total for k, v in rows.items() if k != "count"}
-            out["accuracy"] = out.pop("correct")
-            return out
+        def chunk_body(carry, sl):
+            xc, yc, mc = (
+                jax.lax.dynamic_slice_in_dim(a, sl * chunk, chunk, 1)
+                for a in (x, y, mask)
+            )
+            outputs = stacked_apply(params, xc)  # [N, chunk, K]
+            return carry, jax.vmap(chunk_rows)(outputs, yc, mc)
 
-        return jax.vmap(eval_node)(params, x, y, mask)
+        _, rows = jax.lax.scan(chunk_body, 0, jnp.arange(n_chunks))  # [chunks, N]
+        total = jnp.maximum(rows["count"].sum(0), 1.0)
+        out = {k: v.sum(0) / total for k, v in rows.items() if k != "count"}
+        out["accuracy"] = out.pop("correct")
+        return out
 
     # ---- the round --------------------------------------------------------
     ctx = AggContext(

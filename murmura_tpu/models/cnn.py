@@ -19,11 +19,14 @@ import jax.numpy as jnp
 from murmura_tpu.models.core import (
     Model,
     conv2d,
+    conv2d_folded,
     conv_init,
     dense,
     dense_init,
+    fold_nodes,
     max_pool,
     resolve_dtype,
+    unfold_nodes,
 )
 
 FEMNIST_VARIANTS = {
@@ -34,6 +37,54 @@ FEMNIST_VARIANTS = {
     "large": ((64, 128), 5, (4096,)),
     "xlarge": ((64, 128, 256), 3, (4096, 2048)),
 }
+
+
+def _stacked_forward(pool_after: Sequence[bool], cd, ci: str):
+    """The one definition of both CNNs, over leaves with a leading node axis:
+    (params[N, ...], x[N, B, H, W, C], keys, train) -> [N, B, K], a relu
+    after every convolution and a 2x2 max-pool where ``pool_after`` says.
+
+    The convolution stack keeps the node folded into the channel axis from
+    the input to the flatten before the first dense layer (``conv2d_folded``);
+    the dense layers are ``vmap(dense)``.  ``murmura.conv`` and
+    ``murmura.dense`` label the two stacks in a trace (and their backward
+    passes: docs/OBSERVABILITY.md).  ``im2col`` convolves one node only.
+    """
+
+    def forward(params, x, keys=None, train=False):
+        if x.ndim == 4:  # grayscale without a channel axis
+            x = x[..., None]
+        n, b = x.shape[:2]
+        with jax.named_scope("murmura.conv"):
+            x = fold_nodes(x)
+            for conv_p, pool in zip(params["convs"], pool_after):
+                if ci == "direct":
+                    x = conv2d_folded(conv_p, x, dtype=cd)
+                else:
+                    one = {k: v[0] for k, v in conv_p.items()}
+                    x = conv2d(one, x, dtype=cd, impl=ci)
+                x = jax.nn.relu(x)
+                if pool:
+                    x = max_pool(x)
+            # Flatten order (h, w, c) a node, as x.reshape((B, -1)) of NHWC.
+            x = unfold_nodes(x, n).reshape(n, b, -1)
+        with jax.named_scope("murmura.dense"):
+            layer = jax.vmap(lambda fc, h: dense(fc, h, cd))
+            for fc in params["fcs"][:-1]:
+                x = jax.nn.relu(layer(fc, x))
+            return layer(params["fcs"][-1], x)
+
+    return forward
+
+
+def _one_node(forward):
+    """``apply`` of one node: the stacked forward at N = 1."""
+
+    def apply(params, x, key=None, train=False):
+        stacked = jax.tree_util.tree_map(lambda l: l[None], params)
+        return forward(stacked, x[None], key, train)[0]
+
+    return apply
 
 
 def make_femnist_cnn(
@@ -58,8 +109,6 @@ def make_femnist_cnn(
     conv_channels, kernel, fc_dims = FEMNIST_VARIANTS[variant]
     cd = resolve_dtype(compute_dtype)
     ci = conv_impl
-    # xlarge applies conv1,conv2 then pool, conv3 then pool (reference:
-    # examples/leaf/models.py:159-169); others pool after every conv.
     final_hw = image_size // 4
     flat_dim = final_hw * final_hw * conv_channels[-1]
     dense_dims = [flat_dim] + list(fc_dims) + [num_classes]
@@ -79,33 +128,20 @@ def make_femnist_cnn(
             )
         return params
 
-    def apply(params, x, key=None, train=False):
-        if x.ndim == 3:
-            x = x[..., None]
-        n_conv = len(params["convs"])
-        if n_conv == 2:
-            for conv_p in params["convs"]:
-                x = jax.nn.relu(conv2d(conv_p, x, dtype=cd, impl=ci))
-                x = max_pool(x)
-        else:
-            x = jax.nn.relu(conv2d(params["convs"][0], x, dtype=cd, impl=ci))
-            x = jax.nn.relu(conv2d(params["convs"][1], x, dtype=cd, impl=ci))
-            x = max_pool(x)
-            x = jax.nn.relu(conv2d(params["convs"][2], x, dtype=cd, impl=ci))
-            x = max_pool(x)
-        x = x.reshape((x.shape[0], -1))
-        for fc in params["fcs"][:-1]:
-            x = jax.nn.relu(dense(fc, x, cd))
-        return dense(params["fcs"][-1], x, cd)
+    # xlarge applies conv1,conv2 then pool, conv3 then pool (reference:
+    # examples/leaf/models.py:159-169); others pool after every conv.
+    pool_after = (True, True) if len(conv_channels) == 2 else (False, True, True)
+    stacked = _stacked_forward(pool_after, cd, ci)
 
     return Model(
         name=name or f"leaf.femnist.{variant}",
         init=init,
-        apply=apply,
+        apply=_one_node(stacked),
         evidential=False,
         input_shape=(image_size, image_size, channels_in),
         num_classes=num_classes,
         meta={"variant": variant},
+        apply_stacked=stacked if ci == "direct" else None,
     )
 
 
@@ -137,19 +173,14 @@ def make_celeba_cnn(
         params["fcs"].append(dense_init(keys[n_conv + 1], fc_dim, num_classes))
         return params
 
-    def apply(params, x, key=None, train=False):
-        for conv_p in params["convs"]:
-            x = jax.nn.relu(conv2d(conv_p, x, dtype=cd, impl=ci))
-            x = max_pool(x)
-        x = x.reshape((x.shape[0], -1))
-        x = jax.nn.relu(dense(params["fcs"][0], x, cd))
-        return dense(params["fcs"][1], x, cd)
+    stacked = _stacked_forward((True,) * n_conv, cd, ci)
 
     return Model(
         name=name,
         init=init,
-        apply=apply,
+        apply=_one_node(stacked),
         evidential=False,
         input_shape=(image_size, image_size, 3),
         num_classes=num_classes,
+        apply_stacked=stacked if ci == "direct" else None,
     )

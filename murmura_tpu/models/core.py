@@ -41,6 +41,11 @@ class Model:
         evidential: whether outputs are Dirichlet concentration parameters.
         input_shape: per-sample input shape (no batch dim).
         num_classes: output arity.
+        apply_stacked: (params[N, ...], x[N, B, ...], keys, train) ->
+            [N, B, K], the forward of N nodes at once for a model whose
+            layers batch badly under ``vmap(apply)`` (convolutions: see
+            ``conv2d_folded``); ``None`` where ``vmap(apply)`` is the
+            stacked forward.  Nodes share nothing in it.
     """
 
     name: str
@@ -50,6 +55,7 @@ class Model:
     input_shape: Tuple[int, ...] = ()
     num_classes: int = 0
     meta: Dict[str, Any] = field(default_factory=dict)
+    apply_stacked: Optional[Callable] = None
 
 
 # ---------------------------------------------------------------------------
@@ -141,6 +147,40 @@ def conv2d(
         if dtype is not None:
             y = y.astype(jnp.float32)
         return y + p["b"]
+    return conv2d_folded({"w": w[None], "b": p["b"][None]}, x, padding, dtype)
+
+
+def fold_nodes(x: jnp.ndarray) -> jnp.ndarray:
+    """[N, B, H, W, C] -> [B, H, W, N*C], the node the major factor of the
+    folded axis (index n*C + c): the layout ``vmap``'s rule for
+    ``conv_general_dilated`` gives a grouped convolution's operand, so a
+    block sharding of the node axis stays a block sharding of the folded one."""
+    n, b, h, w, c = x.shape
+    return x.transpose(1, 2, 3, 0, 4).reshape(b, h, w, n * c)
+
+
+def unfold_nodes(x: jnp.ndarray, n: int) -> jnp.ndarray:
+    """[B, H, W, N*C] -> [N, B, H, W, C], the inverse of ``fold_nodes``."""
+    b, h, w, nc = x.shape
+    return x.reshape(b, h, w, n, nc // n).transpose(3, 0, 1, 2, 4)
+
+
+def conv2d_folded(
+    p: Params, x: jnp.ndarray, padding: str = "SAME", dtype=None
+) -> jnp.ndarray:
+    """``conv2d`` of N nodes as one grouped convolution whose activations
+    stay folded: kernels [N, kh, kw, Cin, Cout], biases [N, Cout],
+    x [B, H, W, N*Cin] -> [B, H, W, N*Cout], group g is node g.
+
+    ``vmap(conv2d)`` runs the same grouped convolution but hands its result
+    back as [N, B, H, W, Cout]: bias, relu and pooling then work on a minor
+    dimension of Cout (a quarter of a 128-lane tile at 32 channels) between
+    two materialised transposes (PERF.md §6 PR 31).  The operations and
+    their precisions are ``conv2d``'s (its mixed precision note holds).
+    """
+    w = p["w"]
+    n, kh, kw, cin, cout = w.shape
+    w = w.transpose(1, 2, 3, 0, 4).reshape(kh, kw, cin, n * cout)
     if dtype is not None:
         x = x.astype(dtype)
         w = w.astype(dtype)
@@ -150,10 +190,11 @@ def conv2d(
         window_strides=(1, 1),
         padding=padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=n,
     )
     if dtype is not None:
         y = y.astype(jnp.float32)
-    return y + p["b"]
+    return y + p["b"].reshape(n * cout)
 
 
 def max_pool(x: jnp.ndarray, window: int = 2, stride: int = 2) -> jnp.ndarray:

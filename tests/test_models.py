@@ -121,6 +121,36 @@ def test_dropout_only_active_in_train_mode():
     assert not np.allclose(np.asarray(train_a), np.asarray(train_b))
 
 
+@pytest.mark.parametrize("factory,params,offered", [
+    ("mlp", {"input_dim": 16, "num_classes": 5}, False),
+    ("leaf.femnist.tiny", {}, True),
+    ("leaf.femnist.xlarge", {}, True),
+    ("leaf.celeba", {}, True),
+    ("leaf.femnist.tiny", {"conv_impl": "im2col"}, False),
+    ("leaf.celeba", {"conv_impl": "im2col"}, False),
+    ("leaf.shakespeare", {}, False),
+    ("examples.wearables.uci_har", {}, False),
+])
+def test_stacked_forward_is_offered_by_direct_convolutions_only(
+    factory, params, offered
+):
+    """``Model.apply_stacked`` (the node-folded convolution stack,
+    tests/test_stacked_forward.py) is what the round program looks for: a
+    model has it because its layers are convolutions run directly, and
+    where it has, ``apply`` is its one-node case."""
+    model = build_model(factory, params)
+    assert (model.apply_stacked is not None) == offered
+    if offered:
+        p = model.init(jax.random.PRNGKey(0))
+        x = jax.random.normal(jax.random.PRNGKey(1), (2,) + tuple(model.input_shape))
+        stacked = model.apply_stacked(
+            jax.tree_util.tree_map(lambda l: l[None], p), x[None], None, False
+        )
+        np.testing.assert_array_equal(
+            np.asarray(stacked[0]), np.asarray(model.apply(p, x, None, False))
+        )
+
+
 def test_conv2d_im2col_matches_direct():
     """The im2col lowering (patch GEMM — the bench_sgd_micro local-SGD
     lever) must be numerically equivalent to lax.conv with the SAME HWIO

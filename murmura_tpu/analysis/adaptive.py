@@ -714,8 +714,8 @@ _ADAPTIVE_MEMO: Optional[List[Finding]] = None
 
 def check_adaptive(force: bool = False) -> List[Finding]:
     """Run MUR1000-1003; returns findings (empty = every adaptive-attack
-    contract holds).  Memoized per process — the CLI, the battery
-    pre-flight and the slow test gate share one sweep.  MUR1001 compiles
+    contract holds).  Memoized per process — the CLI and the slow test
+    gate share one sweep.  MUR1001 compiles
     and runs tiny programs (the check_durability cost profile), which is
     why the family runs only for the package-level check."""
     global _ADAPTIVE_MEMO

@@ -1,12 +1,12 @@
 """AOT cost budgets (MUR206) — committed FLOPs/bytes per aggregator cell.
 
-Generalizes ``Network.step_cost_analysis`` (core/network.py) from a bench
+Generalizes ``Network.step_cost_analysis`` (core/network.py) from a one-run
 diagnostic into a compile-time perf gate: every registry aggregator is
 AOT-compiled (``.lower().compile().cost_analysis()`` — nothing executes) on
 CPU over the canonical (n x dim x mode) grid from :mod:`analysis.ir`, and
 the measured flops/bytes are compared against the committed
 ``analysis/BUDGETS.json`` with a ±10% tolerance.  A +20% FLOPs change to
-any rule therefore fails ``murmura check --ir`` before a bench ever reaches
+any rule therefore fails ``murmura check --ir`` before a run ever reaches
 a chip, and ``murmura check --update-budgets`` rewrites the file so the
 diff itself becomes reviewable perf history — a budget bump nobody can
 explain in review is the regression, caught at the cheapest possible
@@ -121,8 +121,7 @@ _MEASURE_MEMO: Optional[Dict[str, Dict[str, float]]] = None
 
 def measure_all(force: bool = False) -> Dict[str, Dict[str, float]]:
     """Measured cost cells for every registry aggregator over the grid.
-    Memoized per process (shared by the tier-1 gate, the CLI test and the
-    battery pre-flight)."""
+    Memoized per process (shared by the tier-1 gate and the CLI test)."""
     global _MEASURE_MEMO
     if _MEASURE_MEMO is not None and not force:
         return dict(_MEASURE_MEMO)

@@ -1174,8 +1174,8 @@ _COMPOSITION_MEMO: Optional[List[Finding]] = None
 
 def check_composition(force: bool = False) -> List[Finding]:
     """Run MUR1400-1403; returns findings (empty = the declared grid and
-    the shipped code agree everywhere).  Memoized per process — the CLI,
-    the battery pre-flight and the test gate share one sweep."""
+    the shipped code agree everywhere).  Memoized per process — the CLI
+    and the test gate share one sweep."""
     global _COMPOSITION_MEMO
     if _COMPOSITION_MEMO is not None and not force:
         return list(_COMPOSITION_MEMO)

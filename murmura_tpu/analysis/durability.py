@@ -271,8 +271,8 @@ _DURABILITY_MEMO: Optional[List[Finding]] = None
 def check_durability(force: bool = False) -> List[Finding]:
     """Run MUR901/902 over the durability grid; returns findings (empty =
     every rule x mode resumes crash-equivalently with zero recompiles).
-    Memoized per process — the CLI, the battery pre-flight and the slow
-    test gate share one sweep.  Unlike check_flow this EXECUTES programs
+    Memoized per process — the CLI and the slow test gate share one
+    sweep.  Unlike check_flow this EXECUTES programs
     (compile + 6 tiny rounds per cell, ~2 min for the 36-cell grid on
     CPU), which is why it runs only for the package-level check."""
     global _DURABILITY_MEMO

@@ -1581,10 +1581,9 @@ class FlowCell:
 
 
 # Default cells memoized per (rule, mode): check_influence and
-# check_denominators sweep the same grid in one check_flow run, and the
-# battery pre-flight runs under a hard timeout — building each aggregator
-# and probe model once is the difference between one trace per cell and
-# two.
+# check_denominators sweep the same grid in one check_flow run — building
+# each aggregator and probe model once is the difference between one trace
+# per cell and two.
 _CELL_MEMO: Dict[Tuple[str, str], "FlowCell"] = {}
 
 
@@ -2326,8 +2325,8 @@ _FLOW_MEMO: Optional[List[Finding]] = None
 
 def check_flow(force: bool = False) -> List[Finding]:
     """Run MUR800-804 over the flow grid; returns findings (empty = every
-    dataflow contract holds).  Memoized per process — the tier-1 gate, the
-    CLI and the battery pre-flight share one sweep.  Trace-level only:
+    dataflow contract holds).  Memoized per process — the tier-1 gate and
+    the CLI share one sweep.  Trace-level only:
     nothing compiles, nothing needs a multi-device platform."""
     global _FLOW_MEMO
     if _FLOW_MEMO is not None and not force:

@@ -1796,8 +1796,8 @@ def _apply_suppressions(findings: List[Finding]) -> List[Finding]:
 
 def check_ir(force: bool = False) -> List[Finding]:
     """Run MUR200-205 over the canonical grid; returns findings (empty =
-    every IR contract holds).  Memoized per process — the tier-1 gate, the
-    CLI test and the battery pre-flight share one sweep.
+    every IR contract holds).  Memoized per process — the tier-1 gate and
+    the CLI test share one sweep.
 
     Cost budgets (MUR206) live in :mod:`murmura_tpu.analysis.budgets` and
     are composed by ``run_check``, not here — they need AOT compiles per

@@ -15,8 +15,8 @@ evidence, the way MUR206 made FLOPs/bytes reviewable perf history:
   :func:`normalize_memory_analysis` — the memory twin of
   ``normalize_cost_analysis``) is gated against the committed
   ``analysis/MEMORY.json`` within tolerance.  A change that silently
-  doubles a round program's live footprint is a finding, not a battery
-  surprise; ``murmura check --update-memory`` rewrites the file so the
+  doubles a round program's live footprint is a finding, not a surprise
+  on the chip; ``murmura check --update-memory`` rewrites the file so the
   diff itself is reviewable residency history (the BUDGETS.json
   etiquette).
 - **MUR1501 — sharded scaling law.**  For param-sharded cells, the
@@ -25,7 +25,7 @@ evidence, the way MUR206 made FLOPs/bytes reviewable perf history:
   [N, P]-class bytes satisfy d12 ~ 2 x d24 (fixed overhead cancels in
   the differences) and the 4-shard peak drops below a declared fraction
   of the unsharded peak.  This statically verifies the PR 15 residency
-  claim that previously rested on one committed CPU bench point.
+  claim.
 - **MUR1502 — donation completeness by leaf.**  Walk the
   ``input_output_alias`` header of each compiled cell: every carried
   leaf — params plus every ``*_STATE_KEYS`` group in the MUR900
@@ -148,8 +148,7 @@ _MEMORY_FIELDS: Tuple[Tuple[str, str], ...] = (
 def normalize_memory_analysis(mem) -> Dict[str, float]:
     """Flatten ``Compiled.memory_analysis()`` (a ``CompiledMemoryStats``
     object, or None where the backend reports nothing) into one flat dict.
-    Shared with ``Network.step_memory_analysis`` and the bench
-    ``memory{}`` blocks.
+    Shared with ``Network.step_memory_analysis``.
 
     ``peak_bytes`` is the derived live-footprint bound XLA does not
     expose directly: arguments + outputs - aliased (donated buffers are
@@ -356,8 +355,8 @@ _MEASURE_MEMO: Optional[Dict[str, Dict[str, float]]] = None
 
 def measure_all(force: bool = False) -> Dict[str, Dict[str, float]]:
     """Measured memory cells for every registry rule over the full
-    (topo x feature) grid.  Memoized per process (shared by the CLI, the
-    battery pre-flight and the test gate)."""
+    (topo x feature) grid.  Memoized per process (shared by the CLI and
+    the test gate)."""
     global _MEASURE_MEMO
     if _MEASURE_MEMO is not None and not force:
         return dict(_MEASURE_MEMO)
@@ -1077,9 +1076,9 @@ _MEMORY_MEMO: Optional[List[Finding]] = None
 
 def check_memory(force: bool = False) -> List[Finding]:
     """Run MUR1500-1503; returns findings (empty = every memory contract
-    holds).  Memoized per process — the CLI, the battery pre-flight and
-    the test gate share one sweep, and the families themselves share one
-    AOT compile per grid cell."""
+    holds).  Memoized per process — the CLI and the test gate share one
+    sweep, and the families themselves share one AOT compile per grid
+    cell."""
     global _MEMORY_MEMO
     if _MEMORY_MEMO is not None and not force:
         return list(_MEMORY_MEMO)

@@ -695,8 +695,8 @@ _PIPELINE_MEMO: Optional[List[Finding]] = None
 
 def check_pipeline(force: bool = False) -> List[Finding]:
     """Run MUR1200-1203; returns findings (empty = every pipelined-
-    rounds contract holds).  Memoized per process — the CLI, the battery
-    pre-flight and the slow test gate share one sweep.  MUR1201 compiles
+    rounds contract holds).  Memoized per process — the CLI and the slow
+    test gate share one sweep.  MUR1201 compiles
     and runs tiny programs (the check_durability cost profile), which is
     why the family runs only for the package-level check."""
     global _PIPELINE_MEMO

@@ -520,8 +520,8 @@ _SHARDED_MEMO: Optional[List[Finding]] = None
 
 def check_sharded(force: bool = False) -> List[Finding]:
     """Run MUR1300-1303; returns findings (empty = every param-axis
-    sharding contract holds).  Memoized per process — the CLI, the
-    battery pre-flight and the test gate share one sweep."""
+    sharding contract holds).  Memoized per process — the CLI and the
+    test gate share one sweep."""
     global _SHARDED_MEMO
     if _SHARDED_MEMO is not None and not force:
         return list(_SHARDED_MEMO)

@@ -676,8 +676,8 @@ _STALE_MEMO: Optional[List[Finding]] = None
 
 def check_staleness(force: bool = False) -> List[Finding]:
     """Run MUR1100-1103; returns findings (empty = every bounded-
-    staleness contract holds).  Memoized per process — the CLI, the
-    battery pre-flight and the slow test gate share one sweep.  MUR1101
+    staleness contract holds).  Memoized per process — the CLI and the
+    slow test gate share one sweep.  MUR1101
     compiles and runs tiny programs (the check_durability cost profile),
     which is why the family runs only for the package-level check."""
     global _STALE_MEMO

@@ -25,9 +25,9 @@ def make_gaussian_attack(
     # matmul instead of a scatter-add.  The scatter is both slower (~4x on
     # a [20, 6.5M] state) and poisons XLA's layout choice for every [N, P]
     # tensor downstream — scatter prefers a node-minor tiled layout that
-    # pads the node axis to 128 lanes (2x HBM at N=64, the 64-node OOM in
-    # bench_scaling's first run), and the layout copy propagates through
-    # the whole exchange.
+    # pads the node axis to 128 lanes (2x HBM at N=64, an OOM at 64 nodes
+    # on the chip), and the layout copy propagates through the whole
+    # exchange.
     scatter = np.zeros((num_nodes, len(comp_idx)), dtype=np.float32)
     scatter[comp_idx, np.arange(len(comp_idx))] = 1.0
 
@@ -36,8 +36,9 @@ def make_gaussian_attack(
             # Full-network view (the jitted round step): the compromised set
             # is static, so draw noise for those C rows only — a [C, P]
             # threefry instead of [N, P] (RNG generation is a measurable
-            # slice of the round on TPU; bench_breakdown.json).  The traced
-            # mask still gates the add, so semantics match the dense path.
+            # slice of the round on TPU: PERF.md §5, the attack's noise).
+            # The traced mask still gates the add, so semantics match the
+            # dense path.
             noise = (
                 jax.random.normal(key, (len(comp_idx),) + flat.shape[1:], flat.dtype)
                 * noise_std

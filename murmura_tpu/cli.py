@@ -1032,7 +1032,7 @@ def report(run_dir: Optional[Path], frontier_path: Optional[Path],
 
     Works on any producer's output — a `murmura_tpu run` with
     ``telemetry.enabled``, a distributed run's Monitor-folded manifest, or
-    a bench artifact (bench.py / bench_breakdown.py).  Sections: accuracy,
+    a serve tenant's run directory.  Sections: accuracy,
     robustness/rule statistics, time breakdown by dispatch mode,
     checkpoints, device memory, per-node audit taps (e.g. krum rejection
     counts), distributed counters.  See docs/OBSERVABILITY.md;
@@ -1202,8 +1202,8 @@ def top(socket_path: Path, interval_s: float, iterations):
               help="Emit one JSON object per indexed run (JSON lines)")
 def runs(roots, as_json: bool):
     """Cross-run registry: index every telemetry artifact under ROOTS
-    (default: the current directory) — ``telemetry_runs/``, serve state
-    dirs, bench manifests (ISSUE 19 leg 3).
+    (default: the current directory) — run directories and serve state
+    dirs (ISSUE 19 leg 3).
 
     One row per run/submission: kind, schema version, platform, rounds,
     best accuracy, terminal state, and whether the event stream has a

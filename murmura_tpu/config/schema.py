@@ -1051,24 +1051,12 @@ class TPUConfig(_Strict):
         description=(
             "Resident model-parameter dtype. None = auto: bfloat16 at "
             "num_nodes >= 64 (the documented large-N setting — halves the "
-            "[N, P] state and the SGD update's HBM traffic; bench_sgd_micro "
-            "measures the lever), float32 below. Set explicitly to pin."
-        ),
-    )
-    conv_impl: Literal["direct", "im2col"] = Field(
-        default="direct",
-        description=(
-            "CNN conv lowering: direct (lax.conv) or im2col (patch "
-            "extraction + batched GEMM — the other bench_sgd_micro "
-            "local-SGD lever candidate; same HWIO params, checkpoints "
-            "interchangeable). Chip-measurement-gated: flip per run."
+            "[N, P] state and the SGD update's HBM traffic), float32 below. "
+            "Set explicitly to pin."
         ),
     )
     compute_dtype: Literal["float32", "bfloat16"] = Field(
         default="bfloat16", description="Matmul/conv compute dtype (MXU-friendly)"
-    )
-    donate_state: bool = Field(
-        default=True, description="Donate round-step input buffers to XLA"
     )
     rounds_per_dispatch: int = Field(
         default=1,

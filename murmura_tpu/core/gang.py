@@ -3,7 +3,7 @@ experiment axis (ISSUE 5; docs/PERFORMANCE.md).
 
 The paper's evaluation is a grid: every (rule x attack x topology) cell is
 re-run across seeds, yet one network per process pays the full trace/compile
-(~40 s on the bench scenario) for seconds of rounds, and a small-N round
+for seconds of rounds, and a small-N round
 leaves the device mostly idle.  A *gang* stacks S independent experiments —
 differing in seed, and optionally in traced scalar hyperparameters (lr,
 attack intensity) — into leading-axis-``[S, ...]`` inputs and ``jax.vmap``s

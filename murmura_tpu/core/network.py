@@ -418,10 +418,10 @@ class Network:
         representation that actually crosses an edge — the full [P] row in
         the resident dtype, or the compressed payload (int8 blocks+scales /
         top-k values+indices) when the program was built with a
-        ``compression`` spec.  The bench's compression variants emit this
-        next to the measured ``cost{flops,bytes,mfu}`` line so the bytes
-        reduction is committed, attributable history (the MUR206 ethos),
-        not a claim.
+        ``compression`` spec.  Everything here is counted from shapes
+        (edges x payload bytes), nothing is timed: the bytes reduction of
+        a codec is attributable to it, not a claim (what it is worth in
+        milliseconds is a chip measurement, PERF.md).
         """
         import jax.numpy as _jnp
 
@@ -493,11 +493,11 @@ class Network:
         """XLA cost analysis of the compiled train step (flops, bytes).
 
         Uses the AOT path on the same shapes ``train`` runs, so the compile
-        cache is hit and nothing executes.  Basis for the bench's MFU
-        estimate (flops/round x rounds/sec / peak chip flops) and the
-        runtime twin of the per-aggregator budget sweep
-        (``murmura check --ir``, analysis/budgets.py — which also owns the
-        cross-version result normalization used here).  Covers the
+        cache is hit and nothing executes.  These are the compiler's
+        counts for one built run, not a measurement: the runtime twin of
+        the per-aggregator budget sweep (``murmura check --ir``,
+        analysis/budgets.py — which also owns the cross-version result
+        normalization used here).  Covers the
         per-round program only — eval is compiled separately and runs on the
         ``eval_every`` cadence, so its flops are not part of a round.
         """

@@ -135,8 +135,7 @@ def init_pipeline_state(
 
 
 # ---------------------------------------------------------------------------
-# The explicit one-round-delayed averaging reference (tests + the battery
-# --pipeline pre-flight).
+# The explicit one-round-delayed averaging reference (tests/test_pipeline.py).
 # ---------------------------------------------------------------------------
 
 

@@ -284,8 +284,8 @@ def make_stale_fold(
         # which is the same operator fact).  Scrub-withheld senders are
         # NOT expired — their cache is fresh enough, just quarantined
         # for the round — and counting them here would over-report
-        # cache expiry under attack (agg_stale_expired / the
-        # bench_breakdown manifest are read as the age signal).
+        # cache expiry under attack (agg_stale_expired is read as the
+        # age signal).
         expired = (
             (1.0 - deliver)
             * scrub_ok

@@ -20,8 +20,8 @@ daemon) three primitives:
 - :func:`require_tpu` / :func:`tpu_required` — the hard-fail:
   ``--require-tpu``, ``durability.require_tpu``, or
   ``MURMURA_REQUIRE_TPU=1`` abort loudly when the default JAX backend is
-  not a TPU, instead of producing CPU numbers under a device's name.  The
-  bench scripts and ``chip_smoke.py`` require it unconditionally.
+  not a TPU, instead of producing CPU numbers under a device's name.
+  ``chip_smoke.py`` requires it unconditionally.
 """
 
 import errno

@@ -200,9 +200,9 @@ class FaultSchedule:
         delivery inference — an APPROXIMATION of it: core/stale.py
         infers delivery from the fully-folded adjacency, so in-jit
         sentinels (quarantine/scrub) and total link isolation can veto
-        senders this method reports as delivering.  Consumed by
-        bench_breakdown's staleness cells as the schedule-side count
-        next to the observed in-jit stale-edge counts."""
+        senders this method reports as delivering.  The schedule-side
+        count to hold beside the observed in-jit stale-edge counts
+        (tests/test_staleness.py)."""
         self._ensure(round_idx)
         return self._alive[round_idx] * (
             1.0 - self._straggle[round_idx].astype(np.float32)

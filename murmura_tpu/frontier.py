@@ -24,8 +24,8 @@ Execution model — compile-compatible buckets, stages without recompiles:
   reset of params/RNG/state over the SAME warm executables, so a whole
   multi-stage cell costs the bucket's initial compiles and nothing more
   (<= 2: the fused train program and nothing else, or train + eval on
-  the per-round path; asserted by the battery's ``--frontier``
-  pre-flight under ``tpu.recompile_guard``).
+  the per-round path; tests/test_adaptive.py counts them under
+  ``tpu.recompile_guard``).
 - The attacks are ADAPTIVE (attacks/adaptive.py): each member's attacker
   bisects/walks its own strength multiplier against the acceptance taps
   *within* the member's base strength, so a strength-grid point reports

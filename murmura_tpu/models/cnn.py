@@ -18,7 +18,6 @@ import jax.numpy as jnp
 
 from murmura_tpu.models.core import (
     Model,
-    conv2d,
     conv2d_folded,
     conv_init,
     dense,
@@ -39,7 +38,7 @@ FEMNIST_VARIANTS = {
 }
 
 
-def _stacked_forward(pool_after: Sequence[bool], cd, ci: str):
+def _stacked_forward(pool_after: Sequence[bool], cd):
     """The one definition of both CNNs, over leaves with a leading node axis:
     (params[N, ...], x[N, B, H, W, C], keys, train) -> [N, B, K], a relu
     after every convolution and a 2x2 max-pool where ``pool_after`` says.
@@ -48,7 +47,7 @@ def _stacked_forward(pool_after: Sequence[bool], cd, ci: str):
     the input to the flatten before the first dense layer (``conv2d_folded``);
     the dense layers are ``vmap(dense)``.  ``murmura.conv`` and
     ``murmura.dense`` label the two stacks in a trace (and their backward
-    passes: docs/OBSERVABILITY.md).  ``im2col`` convolves one node only.
+    passes: docs/OBSERVABILITY.md).
     """
 
     def forward(params, x, keys=None, train=False):
@@ -58,11 +57,7 @@ def _stacked_forward(pool_after: Sequence[bool], cd, ci: str):
         with jax.named_scope("murmura.conv"):
             x = fold_nodes(x)
             for conv_p, pool in zip(params["convs"], pool_after):
-                if ci == "direct":
-                    x = conv2d_folded(conv_p, x, dtype=cd)
-                else:
-                    one = {k: v[0] for k, v in conv_p.items()}
-                    x = conv2d(one, x, dtype=cd, impl=ci)
+                x = conv2d_folded(conv_p, x, dtype=cd)
                 x = jax.nn.relu(x)
                 if pool:
                     x = max_pool(x)
@@ -94,21 +89,14 @@ def make_femnist_cnn(
     channels_in: int = 1,
     name: str = None,
     compute_dtype=None,
-    conv_impl: str = "direct",
 ) -> Model:
-    """Build a FEMNIST CNN ``Model`` for 28x28x1 inputs.
-
-    ``conv_impl="im2col"`` routes the conv layers through the
-    patch-GEMM formulation (models/core.py conv2d) — the local-SGD
-    lever candidate measured by bench_sgd_micro.py.
-    """
+    """Build a FEMNIST CNN ``Model`` for 28x28x1 inputs."""
     if variant not in FEMNIST_VARIANTS:
         raise ValueError(
             f"Unknown FEMNIST variant '{variant}' (choose from {list(FEMNIST_VARIANTS)})"
         )
     conv_channels, kernel, fc_dims = FEMNIST_VARIANTS[variant]
     cd = resolve_dtype(compute_dtype)
-    ci = conv_impl
     final_hw = image_size // 4
     flat_dim = final_hw * final_hw * conv_channels[-1]
     dense_dims = [flat_dim] + list(fc_dims) + [num_classes]
@@ -131,7 +119,7 @@ def make_femnist_cnn(
     # xlarge applies conv1,conv2 then pool, conv3 then pool (reference:
     # examples/leaf/models.py:159-169); others pool after every conv.
     pool_after = (True, True) if len(conv_channels) == 2 else (False, True, True)
-    stacked = _stacked_forward(pool_after, cd, ci)
+    stacked = _stacked_forward(pool_after, cd)
 
     return Model(
         name=name or f"leaf.femnist.{variant}",
@@ -141,7 +129,7 @@ def make_femnist_cnn(
         input_shape=(image_size, image_size, channels_in),
         num_classes=num_classes,
         meta={"variant": variant},
-        apply_stacked=stacked if ci == "direct" else None,
+        apply_stacked=stacked,
     )
 
 
@@ -152,12 +140,10 @@ def make_celeba_cnn(
     fc_dim: int = 256,
     name: str = "leaf.celeba",
     compute_dtype=None,
-    conv_impl: str = "direct",
 ) -> Model:
     """LeNet-style CelebA CNN for 84x84 RGB
     (reference: murmura/examples/leaf/datasets.py:235-297)."""
     cd = resolve_dtype(compute_dtype)
-    ci = conv_impl
     n_conv = len(channels)
     final_hw = image_size // (2**n_conv)
     flat_dim = final_hw * final_hw * channels[-1]
@@ -173,7 +159,7 @@ def make_celeba_cnn(
         params["fcs"].append(dense_init(keys[n_conv + 1], fc_dim, num_classes))
         return params
 
-    stacked = _stacked_forward((True,) * n_conv, cd, ci)
+    stacked = _stacked_forward((True,) * n_conv, cd)
 
     return Model(
         name=name,
@@ -182,5 +168,5 @@ def make_celeba_cnn(
         evidential=False,
         input_shape=(image_size, image_size, 3),
         num_classes=num_classes,
-        apply_stacked=stacked if ci == "direct" else None,
+        apply_stacked=stacked,
     )

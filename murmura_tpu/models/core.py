@@ -112,8 +112,7 @@ def conv_init(key: jax.Array, kh: int, kw: int, c_in: int, c_out: int) -> Params
 
 
 def conv2d(
-    p: Params, x: jnp.ndarray, padding: str = "SAME", dtype=None,
-    impl: str = "direct",
+    p: Params, x: jnp.ndarray, padding: str = "SAME", dtype=None
 ) -> jnp.ndarray:
     """NHWC conv with HWIO kernel.
 
@@ -121,33 +120,10 @@ def conv2d(
     operands under preferred_element_type, so the low-precision path keeps
     the conv uniformly in ``dtype`` (MXU accumulates f32 internally) and
     casts the result back to float32.
-
-    ``impl="im2col"`` expresses the conv as patch extraction + one GEMM
-    ([B*H*W, kh*kw*cin] @ [kh*kw*cin, cout]) — the local-SGD lever
-    candidate from bench_sgd_micro.py: under ``vmap`` over the node axis
-    the conv stack becomes MXU-native batched matmuls instead of whatever
-    XLA lowers a grouped convolution to.  Same math, same HWIO parameter
-    layout (checkpoints are interchangeable between impls); the transpose
-    matches conv_general_dilated_patches' channel-major feature order.
     """
-    w = p["w"]
-    if impl == "im2col":
-        kh, kw, cin, cout = w.shape
-        pat = jax.lax.conv_general_dilated_patches(
-            x.astype(dtype) if dtype is not None else x,
-            (kh, kw), (1, 1), padding,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        )  # [B, H, W, cin*kh*kw], input-channel-major feature order
-        b_, h_, w_ = pat.shape[0], pat.shape[1], pat.shape[2]
-        wm = w.transpose(2, 0, 1, 3).reshape(cin * kh * kw, cout)
-        if dtype is not None:
-            wm = wm.astype(dtype)
-        y = pat.reshape(b_ * h_ * w_, -1) @ wm
-        y = y.reshape(b_, h_, w_, cout)
-        if dtype is not None:
-            y = y.astype(jnp.float32)
-        return y + p["b"]
-    return conv2d_folded({"w": w[None], "b": p["b"][None]}, x, padding, dtype)
+    return conv2d_folded(
+        {"w": p["w"][None], "b": p["b"][None]}, x, padding, dtype
+    )
 
 
 def fold_nodes(x: jnp.ndarray) -> jnp.ndarray:

@@ -59,14 +59,12 @@ def build_model(factory: str, params: Dict[str, Any]) -> Model:
         return make_femnist_cnn(
             num_classes=int(params.pop("num_classes", 62)), variant=variant,
             compute_dtype=compute_dtype,
-            conv_impl=params.pop("conv_impl", "direct"),
         )
 
     if "celeba" in lowered:
         return make_celeba_cnn(
             num_classes=int(params.pop("num_classes", 2)),
             compute_dtype=compute_dtype,
-            conv_impl=params.pop("conv_impl", "direct"),
         )
 
     if "shakespeare" in lowered:

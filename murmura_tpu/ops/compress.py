@@ -97,8 +97,8 @@ class CompressionSpec:
 
     def payload_bytes(self, p: int, uncompressed_itemsize: int) -> int:
         """Analytic bytes of one node's exchanged representation for a [P]
-        row — what actually crosses an edge, the number the bench commits
-        next to the measured cost line (bench.py compression variants)."""
+        row — what actually crosses an edge (core/network.py reports it as
+        ``payload_bytes_per_edge``)."""
         if self.algorithm == "int8":
             nblocks = -(-p // self.block)
             return p * 1 + nblocks * 4  # int8 payload + f32 scale per block

@@ -7,8 +7,8 @@ fixed-size compiled round program.
 - the compiled round program is EXACTLY the plain N-node program — cohort
   membership arrives as input *values* (param rows, data rows), never as
   structure, so one compile covers the whole population (the fault-mask
-  mechanism, MUR302; the battery's ``--population`` pre-flight pins zero
-  recompiles across cohort swaps);
+  mechanism, MUR302; tests/test_population.py pins zero recompiles
+  across cohort swaps);
 - per-user model rows persist in a host-side :class:`PopulationBank`
   (memory-mapped, lazily initialized);
 - cohort draws are a pure function of ``(population.seed, draw_index)``

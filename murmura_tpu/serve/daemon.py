@@ -264,7 +264,6 @@ class ServeDaemon:
 
         return TelemetryWriter(
             str(self.state_dir / "telemetry" / sub_id),
-            kind="run",
             run_id=sub_id,
             config=config,
             record_taps=True,

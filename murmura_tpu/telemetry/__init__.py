@@ -1,7 +1,7 @@
 """Unified telemetry subsystem (ISSUE 4; docs/OBSERVABILITY.md).
 
 One versioned run manifest + JSONL event stream (schema.py, writer.py)
-that all three backends and the bench scripts emit through, plus the
+that all three backends emit through, plus the
 ``murmura report`` renderer (report.py).  Default off: with no
 ``telemetry:`` config block the compiled programs, histories, and random
 streams are byte-identical to a build without this package.
@@ -18,7 +18,6 @@ from murmura_tpu.telemetry.writer import (
     events_of_type,
     iter_events,
     read_manifest,
-    write_bench_manifest,
 )
 
 __all__ = [
@@ -30,5 +29,4 @@ __all__ = [
     "events_of_type",
     "iter_events",
     "read_manifest",
-    "write_bench_manifest",
 ]

@@ -8,15 +8,12 @@ snapshot never invents numbers the artifacts cannot reproduce.  That is
 the MUR1700 contract (analysis/observe.py): a scraped counter that a
 full replay of the stream + ledger cannot reconstruct is a finding.
 
-Three consumers:
+Two consumers:
 
 - the daemon's ``{"op": "metrics"}`` protocol op
   (:meth:`serve.daemon.ServeDaemon.metrics_registry` -> :func:`render_openmetrics`);
 - ``murmura metrics <socket|run_dir>`` (cli.py) — the offline twin folds
-  a run directory's stream through :func:`fold_run_events`;
-- the bench scripts, which drop a ``metrics.prom`` snapshot next to each
-  manifest (:func:`write_openmetrics_snapshot`) so BENCH trajectories
-  are scrapeable by stock Prometheus tooling.
+  a run directory's stream through :func:`fold_run_events`.
 
 Read path only: rendering takes the registry lock, touches no jax state,
 and therefore cannot recompile anything (MUR1701's half of the story;
@@ -344,34 +341,6 @@ def fold_run_events(
                 "murmura_resumes", labels=base,
                 help="durability restores that continued this run",
             )
-    return registry
-
-
-def fold_bench_payload(
-    registry: MetricsRegistry, name: str, payload: Mapping[str, Any],
-) -> MetricsRegistry:
-    """Flatten a bench payload's numeric leaves into labelled gauges.
-
-    One serializer for every bench script: scalar leaves become
-    ``murmura_bench{bench=..., key="a.b.c"}`` gauges; non-numeric leaves
-    are skipped (the manifest keeps full fidelity — the snapshot is the
-    scrapeable projection, not the artifact of record)."""
-
-    def walk(prefix: str, node: Any) -> None:
-        if isinstance(node, Mapping):
-            for k, v in node.items():
-                walk(f"{prefix}.{k}" if prefix else str(k), v)
-        elif isinstance(node, bool):
-            return
-        elif isinstance(node, (int, float)) and math.isfinite(node):
-            registry.set_gauge(
-                "murmura_bench", float(node),
-                labels={"bench": name, "key": prefix},
-                help="bench payload scalar leaves (see the adjacent "
-                     "manifest for full structure)",
-            )
-
-    walk("", payload)
     return registry
 
 

@@ -1,7 +1,7 @@
 """``murmura report <run_dir>``: render a run manifest + event stream.
 
 Reads only the telemetry schema (schema.py) — any producer's run directory
-works: a CLI run, a Monitor-folded distributed run, or a bench artifact.
+works: a CLI run, a Monitor-folded distributed run, or a serve tenant's.
 Sections render only when their data exists, so a minimal manifest still
 produces a useful summary instead of a wall of empty tables.
 """
@@ -9,7 +9,6 @@ produces a useful summary instead of a wall of empty tables.
 import math
 from typing import Any, Dict, List, Optional
 
-from murmura_tpu.telemetry.schema import KIND_BENCH
 from murmura_tpu.telemetry.writer import iter_events, read_manifest
 
 
@@ -114,9 +113,7 @@ def build_report(run_dir) -> Dict[str, Any]:
                 "note": (
                     "wall_s is the per-round critical path; the "
                     "exchange+aggregate bracket runs concurrently with "
-                    "training and must not be added to it (see "
-                    "bench_breakdown's pipeline hidden-fraction cells "
-                    "for the overlapped segment's size)"
+                    "training and must not be added to it"
                 ),
             }
     ckpt = [e for e in events if e.get("type") == "checkpoint"]
@@ -189,15 +186,14 @@ def build_report(run_dir) -> Dict[str, Any]:
     counters = manifest.get("counters") or {}
     if counters:
         report["counters"] = counters
-    if manifest.get("kind") == KIND_BENCH:
-        report["bench"] = manifest.get("summary") or {}
     return report
 
 
 def _declared_influence(manifest: dict) -> Optional[Dict[str, Any]]:
     """The configured rule's declared Byzantine influence contract, built
-    from the manifest's config snapshot.  Best-effort: bench manifests and
-    pre-influence runs have no (usable) aggregation config."""
+    from the manifest's config snapshot.  Best-effort: a manifest without
+    a config snapshot and pre-influence runs have no (usable) aggregation
+    config."""
     cfg = manifest.get("config") or {}
     agg_cfg = cfg.get("aggregation") or {}
     algo = agg_cfg.get("algorithm")
@@ -403,12 +399,6 @@ def render_report(run_dir, console=None) -> Dict[str, Any]:
         console.print(t)
     if "counters" in report:
         kv_table("Distributed counters", report["counters"])
-    if "bench" in report:
-        flat = {
-            k: v for k, v in report["bench"].items()
-            if isinstance(v, (int, float, str)) or v is None
-        }
-        kv_table("Bench summary", {k: "null" if v is None else v for k, v in flat.items()})
     extra = [e for e in iter_events(run_dir) if e.get("type") == "extra"]
     if extra:
         console.print(

@@ -1,9 +1,9 @@
 """The telemetry run schema: one manifest, one event stream.
 
 Every producer in the framework — the simulation/tpu orchestrator
-(core/network.py), the ZMQ Monitor (distributed/monitor.py), and the bench
-scripts (bench.py, bench_breakdown.py) — writes observability data through
-this one schema instead of private JSON shapes:
+(core/network.py), the ZMQ Monitor (distributed/monitor.py) and the serve
+daemon (serve/daemon.py) — writes observability data through this one
+schema instead of private JSON shapes:
 
     <run_dir>/manifest.json   versioned envelope: schema_version, kind,
                               run_id, config snapshot, summary, counters,
@@ -75,10 +75,9 @@ MANIFEST_SCHEMA_VERSION = 2
 MANIFEST_FILE = "manifest.json"
 EVENTS_FILE = "events.jsonl"
 
-# Manifest ``kind`` values: a training run (CLI / Network / Monitor) vs a
-# bench artifact (bench.py, bench_breakdown.py payloads in ``summary``).
+# The manifest's ``kind``: a training run (CLI / Network / Monitor / serve).
+# One value; committed manifests and ``murmura report`` read the key.
 KIND_RUN = "run"
-KIND_BENCH = "bench"
 
 # Metric keys the Monitor understands natively; anything else a node
 # reports is forwarded under ``extra.*`` (never silently dropped — the

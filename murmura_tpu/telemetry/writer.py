@@ -11,8 +11,9 @@ the append-only JSONL event stream (schema.py).  Design constraints:
 - **Resumable**: reopening an existing run directory appends to the event
   stream (the checkpoint/restore path keeps one stream per run) and marks
   the manifest ``resumed``.
-- **jax-free at import**: bench scripts construct writers before deciding
-  which backend they run on; only :meth:`memory_event` touches jax, lazily.
+- **jax-free at import**: a producer may construct a writer before it
+  decides which backend it runs on; only :meth:`memory_event` touches
+  jax, lazily.
 """
 
 import json
@@ -26,7 +27,6 @@ import numpy as np
 
 from murmura_tpu.telemetry.schema import (
     EVENTS_FILE,
-    KIND_BENCH,
     KIND_RUN,
     MANIFEST_FILE,
     MANIFEST_SCHEMA_VERSION,
@@ -58,7 +58,6 @@ class TelemetryWriter:
 
     Args:
         run_dir: directory to create/append; one run per directory.
-        kind: ``"run"`` or ``"bench"`` (schema.py).
         run_id: stable id across resumes; generated when omitted.
         config: optional validated Config — snapshotted (``model_dump``)
             into the manifest so a report is self-describing.
@@ -82,7 +81,6 @@ class TelemetryWriter:
         self,
         run_dir,
         *,
-        kind: str = KIND_RUN,
         run_id: Optional[str] = None,
         config=None,
         record_taps: bool = True,
@@ -95,7 +93,6 @@ class TelemetryWriter:
     ):
         self.run_dir = Path(run_dir)
         self.run_dir.mkdir(parents=True, exist_ok=True)
-        self.kind = kind
         self.record_taps = record_taps
         self.record_phase_times = phase_times
         self.memory_stats = memory_stats
@@ -126,7 +123,7 @@ class TelemetryWriter:
         self._events = open(events_path, "a", encoding="utf-8")
         self._manifest: Dict[str, Any] = {
             "schema_version": MANIFEST_SCHEMA_VERSION,
-            "kind": kind,
+            "kind": KIND_RUN,
             "run_id": run_id,
             "created_unix": (existing or {}).get("created_unix", time.time()),
             "finalized": False,
@@ -304,44 +301,3 @@ def iter_events(run_dir) -> Iterator[Dict[str, Any]]:
 
 def events_of_type(run_dir, etype: str) -> List[Dict[str, Any]]:
     return [e for e in iter_events(run_dir) if e.get("type") == etype]
-
-
-def write_bench_manifest(
-    run_dir,
-    name: str,
-    payload: Dict[str, Any],
-    legacy_path=None,
-) -> Path:
-    """One-schema bench artifact (satellite of ISSUE 4).
-
-    The bench's result blob becomes the ``summary`` of a ``kind: bench``
-    manifest in ``run_dir``; ``legacy_path`` (when given) keeps the
-    script's historical filename as a duplicated view of the same payload
-    for one release, so downstream readers migrate on their own clock.
-    """
-    w = TelemetryWriter(run_dir, kind=KIND_BENCH, run_id=name)
-    try:
-        w.emit("bench", name=name)
-        path = w.finalize(summary=payload)
-    finally:
-        w.close()
-    # Final OpenMetrics snapshot next to the manifest (ISSUE 19): the
-    # same serializer the daemon's ``metrics`` op uses, so batch and
-    # serve artifacts scrape identically.
-    from murmura_tpu.telemetry.metrics import (
-        MetricsRegistry,
-        fold_bench_payload,
-        write_openmetrics_snapshot,
-    )
-
-    reg = MetricsRegistry()
-    fold_bench_payload(reg, name, payload)
-    write_openmetrics_snapshot(run_dir, reg)
-    if legacy_path is not None:
-        legacy_path = Path(legacy_path)
-        legacy_path.parent.mkdir(parents=True, exist_ok=True)
-        durable_replace(
-            legacy_path.parent, legacy_path.name,
-            (json.dumps(_jsonable(payload), indent=2) + "\n").encode("utf-8"),
-        )
-    return path

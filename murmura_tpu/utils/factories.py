@@ -291,9 +291,7 @@ def default_telemetry_dir(config: Config) -> str:
     )
 
 
-def build_telemetry_writer(
-    config: Config, kind: str = "run", run_id=None, resume: bool = False
-):
+def build_telemetry_writer(config: Config, run_id=None, resume: bool = False):
     """TelemetryWriter from config.telemetry, or None when off.
 
     The single construction path for every consumer (the simulation/tpu
@@ -310,7 +308,6 @@ def build_telemetry_writer(
 
     return TelemetryWriter(
         default_telemetry_dir(config),
-        kind=kind,
         run_id=run_id,
         config=config,
         record_taps=True,
@@ -411,8 +408,7 @@ class ConfigError(ValueError):
 def resolved_param_dtype(config: Config) -> Optional[str]:
     """tpu.param_dtype with the documented large-N auto default: bfloat16
     from 64 nodes up (halves the [N, P] resident state and the SGD
-    update's HBM traffic — the bench_sgd_micro lever; bench.py's 256-node
-    north-star runs it), float32 below, explicit setting always wins."""
+    update's HBM traffic), float32 below, explicit setting always wins."""
     if config.backend != "tpu":
         return None
     if config.tpu.param_dtype is not None:
@@ -434,12 +430,6 @@ def resolve_model(config: Config, data):
         # MXU mixed precision: bfloat16 matmul/conv inputs, float32 params
         # and accumulation (tpu.compute_dtype, default bfloat16).
         model_params.setdefault("compute_dtype", config.tpu.compute_dtype)
-        factory_lc = config.model.factory.lower()
-        if config.tpu.conv_impl != "direct" and (
-            "femnist" in factory_lc or "celeba" in factory_lc
-        ):
-            # CNN-only lever; non-conv models have no im2col formulation.
-            model_params.setdefault("conv_impl", config.tpu.conv_impl)
     if (
         "wearables." in config.model.factory
         and "input_dim" not in model_params
@@ -484,8 +474,8 @@ def apply_compilation_cache() -> Optional[str]:
     is the only cache setting and nothing here touches the config.  Unset:
     the persistent cache goes to :data:`COMPILATION_CACHE_DIR`.  Every
     entry point that compiles round programs (``build_network_from_config``,
-    the ZMQ workers, the ``check`` sweeps, the bench scripts,
-    ``chip_smoke.py``) calls this and nothing else configures the cache.
+    the ZMQ workers, the ``check`` sweeps, ``chip_smoke.py``) calls this
+    and nothing else configures the cache.
 
     When that fixed directory cannot be created or written — the package
     installed non-editable under a read-only prefix, where ``parents[2]`` is
@@ -873,7 +863,6 @@ def build_gang_from_config(config: Config, seeds=None, mesh=None,
             ),
             mesh=mesh,
             num_devices=config.tpu.num_devices,
-            donate=config.tpu.donate_state,
             bucket=bucket,
             base_lr=config.training.lr,
             recompile_guard=config.tpu.recompile_guard,
@@ -1102,7 +1091,6 @@ def build_network_from_config(
         backend=config.backend if config.backend in ("simulation", "tpu") else "simulation",
         mesh=mesh,
         seed=seed,
-        donate=config.tpu.donate_state,
         profile_dir=config.tpu.profile_dir,
         recompile_guard=config.tpu.recompile_guard,
         transfer_guard=config.tpu.transfer_guard,

@@ -109,7 +109,7 @@ class TestMUR103ZeroDiagonal:
     def test_uncased_topology_type_flagged(self, monkeypatch):
         # A registered type with no _TOPOLOGY_CASES entry must be a
         # finding from check_contracts itself, not only a test assert —
-        # the battery pre-flight runs check, not the test suite.
+        # a user runs `murmura check`, not the test suite.
         from murmura_tpu.topology import generators
 
         monkeypatch.setattr(

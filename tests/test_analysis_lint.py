@@ -457,7 +457,7 @@ class TestScopeDiscovery:
 
     def test_unreadable_file_reports_mur000(self, tmp_path):
         # A non-UTF8 file must be a per-file finding, not a crash that
-        # aborts the whole `murmura check` run (battery pre-flight).
+        # aborts the whole `murmura check` run.
         f = tmp_path / "latin1.py"
         f.write_bytes(b"# caf\xe9\nx = 1\n")
         findings = lint_file(f)

@@ -4,8 +4,7 @@ Tier-1 pins the pure halves (budget comparison logic against fabricated
 measurements, the MUR1502 alias walk on fabricated HLO, the MUR1503
 def-use prover on the doctored combine) plus one representative compiled
 cell per contract; the full 108-cell grid sweep is the slow gate (also
-run as the package check and the `run_tpu_battery.sh --memory`
-pre-flight).
+run as the package check, `murmura check --memory`).
 """
 
 import json

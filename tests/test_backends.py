@@ -224,47 +224,6 @@ def test_ppermute_circulant_rule_matches_allgather(algo, params):
 
 
 @pytest.mark.slow
-def test_conv_impl_im2col_config_path_matches_direct():
-    """tpu.conv_impl: im2col through the full config path (factories ->
-    make_femnist_cnn -> round program): identical history to the direct
-    lowering on the same seeds — the flag only changes how XLA lowers the
-    convs, never the math."""
-    from murmura_tpu.config import Config
-
-    def cfg(conv_impl):
-        return Config.model_validate(
-            {
-                "experiment": {"name": f"ci-{conv_impl}", "seed": 5,
-                               "rounds": 2},
-                "topology": {"type": "ring", "num_nodes": 8},
-                "aggregation": {"algorithm": "fedavg"},
-                "training": {"local_epochs": 1, "batch_size": 8, "lr": 0.05},
-                "data": {
-                    "adapter": "synthetic",
-                    "params": {"num_samples": 128,
-                                "input_shape": [28, 28, 1],
-                                "num_classes": 10},
-                },
-                "model": {"factory": "examples.leaf.LEAFFEMNISTModel",
-                           "params": {"variant": "tiny",
-                                      "num_classes": 10}},
-                "backend": "tpu",
-                "tpu": {"compute_dtype": "float32",
-                         "conv_impl": conv_impl},
-            }
-        )
-
-    hist_direct = build_network_from_config(cfg("direct")).train(rounds=2)
-    hist_gemm = build_network_from_config(cfg("im2col")).train(rounds=2)
-    np.testing.assert_allclose(
-        hist_direct["mean_accuracy"], hist_gemm["mean_accuracy"], atol=1e-3
-    )
-    np.testing.assert_allclose(
-        hist_direct["mean_loss"], hist_gemm["mean_loss"], rtol=1e-3
-    )
-
-
-@pytest.mark.slow
 def test_64node_rules_scale_smoke(monkeypatch):
     """Structural scale coverage on CPU: 64 nodes crosses the bf16
     auto-default boundary (factories.resolved_param_dtype) and, with the

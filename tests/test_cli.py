@@ -121,8 +121,7 @@ def test_check_clean_file_exits_zero(tmp_path):
 
 
 def test_check_package_is_clean():
-    """The committed package must pass its own analyzer (with contracts) —
-    the same gate run_tpu_battery.sh uses as a pre-flight."""
+    """The committed package must pass its own analyzer (with contracts)."""
     import murmura_tpu
 
     pkg = str(Path(murmura_tpu.__file__).resolve().parent)

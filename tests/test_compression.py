@@ -485,8 +485,8 @@ class TestConfigWiring:
 
     def test_int8_accuracy_tracks_uncompressed(self):
         """int8 + error feedback stays close to the uncompressed run on
-        the attack scenario (the battery pre-flight's assertion, scaled
-        down): final mean accuracy within a loose tolerance."""
+        the attack scenario: final mean accuracy within a loose
+        tolerance."""
         from murmura_tpu.utils.factories import build_network_from_config
 
         atk = {
@@ -510,8 +510,7 @@ class TestConfigWiring:
         assert "agg_compress_error" in h1
         cost = net1.exchange_cost_analysis()
         # int8 payload (1 byte + scale amortized) vs f32 rows: >= 3x — the
-        # acceptance-criterion surface, also gated in the battery
-        # --compress pre-flight.
+        # acceptance-criterion surface.
         assert cost["exchange_bytes_reduction"] >= 3.0
         assert cost["exchange_bytes_per_round"] < (
             cost["uncompressed_exchange_bytes_per_round"]

@@ -1,6 +1,7 @@
 """Config schema + loader tests (reference surface: murmura/config/)."""
 
 import pytest
+from pydantic import ValidationError
 
 from murmura_tpu.config import Config, load_config, save_config
 
@@ -70,6 +71,21 @@ def test_extra_fields_forbidden():
         Config.model_validate({**BASIC, "bogus": 1})
 
 
+@pytest.mark.parametrize("field,value", [
+    ("conv_impl", "direct"),
+    ("donate_state", True),
+])
+def test_retired_tpu_switches_are_refused_by_name(field, value):
+    """``tpu.conv_impl`` and ``tpu.donate_state`` left the schema (the
+    direct convolution and donation are the only paths): a YAML that still
+    sets one, even to the old default, is refused with the field named."""
+    refused = rf"tpu\.{field}\s+Extra inputs are not permitted"
+    with pytest.raises(ValidationError, match=refused):
+        Config.model_validate(
+            {**BASIC, "backend": "tpu", "tpu": {field: value}}
+        )
+
+
 def test_roundtrip(tmp_path):
     cfg = Config.model_validate(BASIC)
     for name in ("c.yaml", "c.json"):
@@ -97,7 +113,7 @@ def test_dmtt_requires_mobility():
 
 def test_param_dtype_auto_large_n_default():
     """tpu.param_dtype None = auto: bfloat16 from 64 nodes (the documented
-    large-N setting bench.py's 256-node north-star runs), float32 below;
+    large-N setting), float32 below;
     an explicit setting always wins (factories.resolved_param_dtype)."""
     from murmura_tpu.utils.factories import resolved_param_dtype
 

@@ -406,7 +406,7 @@ class TestCrashMatrix:
         # round-crossing state: killing mid-bisection and dropping it
         # would resume a silently-cold adversary whose probe restarts
         # from scale_init — the frontier's curves would then depend on
-        # where the battery got interrupted.
+        # where the run got interrupted.
         over = {"attack": {"enabled": True, "type": "gaussian",
                            "percentage": 0.3,
                            "params": {"noise_std": 5.0},

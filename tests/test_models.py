@@ -126,18 +126,16 @@ def test_dropout_only_active_in_train_mode():
     ("leaf.femnist.tiny", {}, True),
     ("leaf.femnist.xlarge", {}, True),
     ("leaf.celeba", {}, True),
-    ("leaf.femnist.tiny", {"conv_impl": "im2col"}, False),
-    ("leaf.celeba", {"conv_impl": "im2col"}, False),
     ("leaf.shakespeare", {}, False),
     ("examples.wearables.uci_har", {}, False),
 ])
-def test_stacked_forward_is_offered_by_direct_convolutions_only(
+def test_stacked_forward_is_offered_by_the_cnns_only(
     factory, params, offered
 ):
     """``Model.apply_stacked`` (the node-folded convolution stack,
-    tests/test_stacked_forward.py) is what the round program looks for: a
-    model has it because its layers are convolutions run directly, and
-    where it has, ``apply`` is its one-node case."""
+    tests/test_stacked_forward.py) is what the round program looks for: the
+    CNNs have it, the MLPs and the LSTM do not, and where a model has it,
+    ``apply`` is its one-node case."""
     model = build_model(factory, params)
     assert (model.apply_stacked is not None) == offered
     if offered:
@@ -149,42 +147,3 @@ def test_stacked_forward_is_offered_by_direct_convolutions_only(
         np.testing.assert_array_equal(
             np.asarray(stacked[0]), np.asarray(model.apply(p, x, None, False))
         )
-
-
-def test_conv2d_im2col_matches_direct():
-    """The im2col lowering (patch GEMM — the bench_sgd_micro local-SGD
-    lever) must be numerically equivalent to lax.conv with the SAME HWIO
-    parameters; this also pins conv_general_dilated_patches' channel-major
-    feature order that the weight transpose in models/core.py relies on."""
-    import jax
-    import numpy as np
-
-    from murmura_tpu.models.core import conv2d, conv_init
-
-    key = jax.random.PRNGKey(0)
-    p = conv_init(key, 5, 5, 3, 8)
-    x = jax.random.normal(jax.random.PRNGKey(1), (4, 12, 12, 3))
-    direct = conv2d(p, x)
-    gemm = conv2d(p, x, impl="im2col")
-    np.testing.assert_allclose(
-        np.asarray(direct), np.asarray(gemm), rtol=1e-5, atol=1e-5
-    )
-
-
-def test_femnist_conv_impl_flag_equivalent_and_checkpoint_compatible():
-    """conv_impl='im2col' on the FEMNIST CNN: identical init tree (same
-    HWIO params — checkpoints interchangeable) and matching logits."""
-    import jax
-    import numpy as np
-
-    from murmura_tpu.models.cnn import make_femnist_cnn
-
-    direct = make_femnist_cnn(variant="tiny")
-    gemm = make_femnist_cnn(variant="tiny", conv_impl="im2col")
-    params = direct.init(jax.random.PRNGKey(3))
-    x = jax.random.normal(jax.random.PRNGKey(4), (2, 28, 28, 1))
-    np.testing.assert_allclose(
-        np.asarray(direct.apply(params, x)),
-        np.asarray(gemm.apply(params, x)),
-        rtol=1e-4, atol=1e-4,
-    )

@@ -37,7 +37,6 @@ from murmura_tpu.telemetry import top as top_mod
 from murmura_tpu.telemetry.metrics import (
     METRICS_SNAPSHOT_FILE,
     MetricsRegistry,
-    fold_bench_payload,
     fold_run_events,
     parse_openmetrics,
     render_openmetrics,
@@ -156,17 +155,6 @@ class TestMetricsRegistry:
         reg.max_gauge("peak", 10.0)
         reg.max_gauge("peak", 4.0)
         assert reg.value("peak") == 10.0
-
-    def test_bench_fold_flattens_numeric_leaves_only(self):
-        reg = MetricsRegistry()
-        fold_bench_payload(reg, "b", {
-            "a": {"b": 1.5}, "skip": "str", "flag": True, "n": 2,
-        })
-        assert reg.value("murmura_bench",
-                         {"bench": "b", "key": "a.b"}) == 1.5
-        assert reg.value("murmura_bench", {"bench": "b", "key": "n"}) == 2
-        assert reg.value("murmura_bench", {"bench": "b", "key": "flag"}) is None
-        assert reg.value("murmura_bench", {"bench": "b", "key": "skip"}) is None
 
 
 class TestFoldRunEvents:

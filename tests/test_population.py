@@ -160,8 +160,7 @@ class TestSparseTopology:
 # Tier-1 runs a representative subset of the 9-rule parity grid (the
 # repo's slow-gating pattern, e.g. test_durability's resume grid): one
 # linear rule, the flagship selection rule, a sort-based rule, and the
-# carried-state exception.  The full grid runs under -m slow and in the
-# battery.
+# carried-state exception.  The full grid runs under -m slow.
 _TIER1_SPARSE_PARITY = {"fedavg", "krum", "median", "evidential_trust"}
 
 

@@ -27,7 +27,6 @@ from murmura_tpu.telemetry.writer import (
     events_of_type,
     iter_events,
     read_manifest,
-    write_bench_manifest,
 )
 from murmura_tpu.utils.factories import build_network_from_config
 
@@ -62,7 +61,7 @@ def _tel(tmp_path, **overrides):
 
 class TestWriter:
     def test_manifest_and_event_roundtrip(self, tmp_path):
-        w = TelemetryWriter(tmp_path / "r", run_id="abc", kind="run")
+        w = TelemetryWriter(tmp_path / "r", run_id="abc")
         w.emit("phase_times", round=0, mode="per_round", wall_s=0.5)
         w.add_counters({"reconnects": 2})
         w.add_counters({"reconnects": 1, "send_failures": 1})
@@ -140,18 +139,6 @@ class TestWriter:
         w.emit("round", metrics={"loss": float("nan")})
         w.close()
         assert events_of_type(tmp_path / "r", "round")  # parseable
-
-    def test_bench_manifest_with_legacy_view(self, tmp_path):
-        payload = {"metric": "x", "value": 1.5, "segments": {"a": 2}}
-        write_bench_manifest(
-            tmp_path / "bench", "bench_x", payload,
-            legacy_path=tmp_path / "old_shape.json",
-        )
-        m = read_manifest(tmp_path / "bench")
-        assert m["kind"] == "bench"
-        assert m["summary"] == payload
-        # The legacy filename keeps the OLD private shape, verbatim.
-        assert json.loads((tmp_path / "old_shape.json").read_text()) == payload
 
 
 class TestDefaultOffByteIdentity:

@@ -24,9 +24,11 @@ from murmura_tpu.models.core import (
     dense_init,
     fold_nodes,
     max_pool,
+    nodes_a_group,
     resolve_dtype,
     unfold_nodes,
 )
+from murmura_tpu.parallel.mesh import nodes_a_device
 
 FEMNIST_VARIANTS = {
     # variant: (conv_channels, kernel, fc_dims)
@@ -44,8 +46,11 @@ def _stacked_forward(pool_after: Sequence[bool], cd):
     after every convolution and a 2x2 max-pool where ``pool_after`` says.
 
     The convolution stack keeps the node folded into the channel axis from
-    the input to the flatten before the first dense layer (``conv2d_folded``);
-    the dense layers are ``vmap(dense)``.  ``murmura.conv`` and
+    the input to the flatten before the first dense layer (``conv2d_folded``),
+    every convolution with the same number of nodes a group
+    (``nodes_a_group``, from the stack's narrowest output: 4 at 16 and 32
+    channels, 1 at 8, from 64 on and at N = 1); the dense layers are
+    ``vmap(dense)``.  ``murmura.conv`` and
     ``murmura.dense`` label the two stacks in a trace (and their backward
     passes: docs/OBSERVABILITY.md).
     """
@@ -54,10 +59,12 @@ def _stacked_forward(pool_after: Sequence[bool], cd):
         if x.ndim == 4:  # grayscale without a channel axis
             x = x[..., None]
         n, b = x.shape[:2]
+        per = nodes_a_group(
+            nodes_a_device(n), min(c["w"].shape[-1] for c in params["convs"]))
         with jax.named_scope("murmura.conv"):
             x = fold_nodes(x)
             for conv_p, pool in zip(params["convs"], pool_after):
-                x = conv2d_folded(conv_p, x, dtype=cd)
+                x = conv2d_folded(conv_p, x, dtype=cd, per_group=per)
                 x = jax.nn.relu(x)
                 if pool:
                     x = max_pool(x)

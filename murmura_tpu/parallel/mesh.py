@@ -17,6 +17,7 @@ __graft_entry__.dryrun_multichip).
 """
 
 import contextlib
+import functools
 from typing import Any, List, Optional, Tuple
 
 import jax
@@ -192,30 +193,48 @@ def flat_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P("nodes", "param"))
 
 
-# Trace-time ambient scope: (mesh, flat_dim) while a param-sharded round
-# program is being traced.  core/rounds.py pins its [N, P] intermediates
-# through :func:`constrain_flat`, aggregation/base.py aligns its P-chunk
-# loops through :func:`active_param_shards`, and ops/pallas_agg.py picks
-# shard-local grids through :func:`active_param_scope` — one context, three
-# consumers, zero plumbing through rule signatures.  Off-scope (simulation
-# backend, gang vmap, shards=1) every hook is the identity, keeping those
-# programs byte-identical (MUR1302).
-_PARAM_SCOPE: List[Tuple[Mesh, int]] = []
+# Trace-time ambient scope: (mesh, flat_dim) while a program jitted over a
+# mesh is being traced; flat_dim is None unless the mesh shards the param
+# axis.  core/rounds.py pins its [N, P] intermediates through
+# :func:`constrain_flat`, aggregation/base.py aligns its P-chunk loops
+# through :func:`active_param_shards`, ops/pallas_agg.py picks shard-local
+# grids through :func:`active_param_scope`, and models/cnn.py keeps a group
+# of the folded convolution inside one device's block of nodes through
+# :func:`nodes_a_device` — one context, four consumers, zero plumbing
+# through rule signatures.  Off-scope (simulation backend, one device)
+# every hook is the identity, and without a flat_dim (gang vmap, shards=1)
+# the three param hooks are, keeping those programs byte-identical (MUR1302).
+_MESH_SCOPE: List[Tuple[Mesh, Optional[int]]] = []
 
 
 @contextlib.contextmanager
-def param_axis_scope(mesh: Mesh, flat_dim: int):
-    """Activate the param-axis trace scope (see module note above)."""
-    _PARAM_SCOPE.append((mesh, int(flat_dim)))
+def param_axis_scope(mesh: Mesh, flat_dim: Optional[int] = None):
+    """Activate the trace scope (see module note above); the param-axis
+    hooks engage only with a ``flat_dim``."""
+    _MESH_SCOPE.append((mesh, None if flat_dim is None else int(flat_dim)))
     try:
         yield
     finally:
-        _PARAM_SCOPE.pop()
+        _MESH_SCOPE.pop()
+
+
+def _traced_on(mesh: Mesh, fn, flat_dim: Optional[int] = None):
+    """``fn`` under its name, traced inside ``param_axis_scope``."""
+
+    @functools.wraps(fn)
+    def scoped(*args):  # murmura: traced
+        with param_axis_scope(mesh, flat_dim):
+            return fn(*args)
+
+    return scoped
 
 
 def active_param_scope() -> Optional[Tuple[Mesh, int]]:
-    """(mesh, flat_dim) of the innermost active scope, or None."""
-    return _PARAM_SCOPE[-1] if _PARAM_SCOPE else None
+    """(mesh, flat_dim) of the innermost active scope if it shards the
+    param axis, or None."""
+    if _MESH_SCOPE and _MESH_SCOPE[-1][1] is not None:
+        return _MESH_SCOPE[-1]
+    return None
 
 
 def active_param_shards(p: Optional[int] = None) -> int:
@@ -230,6 +249,14 @@ def active_param_shards(p: Optional[int] = None) -> int:
     if p is not None and p % shards:
         return 1
     return shards
+
+
+def nodes_a_device(n: int) -> int:
+    """How many of ``n`` nodes one device holds under the mesh whose
+    program is being traced: its block of the node axis (``n`` off-scope;
+    1 where the axis does not split evenly, which ``shard_step`` refuses)."""
+    shards = mesh_node_axis(_MESH_SCOPE[-1][0]) if _MESH_SCOPE else 1
+    return n // shards if n % shards == 0 else 1
 
 
 def constrain_flat(x):
@@ -311,6 +338,7 @@ def _shard_round_fn(
     node_s, repl = make_shardings(mesh)
 
     param_ax = mesh_param_shards(mesh)
+    scope_dim = None
     if param_ax > 1:
         # Param-sharded layout: the program must have been built with a
         # matching shard count — its flat width is padded to a multiple of
@@ -329,14 +357,9 @@ def _shard_round_fn(
         agg_s = state_sharding_specs(program.init_agg_state, mesh, flat_dim)
         # The [N, P] intermediates inside the round body (own_flat, the
         # broadcast, the aggregation output) are pinned by constrain_flat
-        # at trace time — activate the ambient scope around the traced
-        # body so rounds.py / aggregation kernels see the layout.
-        inner = fn
-
-        def fn(*args):  # murmura: traced
-            with param_axis_scope(mesh, flat_dim):
-                return inner(*args)
-
+        # at trace time: the ambient scope carries the flat width, so
+        # rounds.py / aggregation kernels see the layout.
+        scope_dim = flat_dim
     else:
         params_s = _shard_leading_axis(program.init_params, node_s, repl)
         agg_s = _shard_leading_axis(program.init_agg_state, node_s, repl)
@@ -354,7 +377,7 @@ def _shard_round_fn(
     if program.faulted:
         in_shardings.insert(5, alive_sharding)  # alive mask / alive stack
     return jax.jit(
-        fn,
+        _traced_on(mesh, fn, scope_dim),
         in_shardings=tuple(in_shardings),
         out_shardings=(params_s, agg_s, repl),
         donate_argnums=(0, 1) if donate else (),
@@ -638,7 +661,7 @@ def _shard_gang_round_fn(
         # Param-sharded gang layout (the sharding x sweep lift): the
         # member program must have been built with a matching shard
         # count, exactly as in :func:`_shard_round_fn`.  Unlike the
-        # single-run path there is NO param_axis_scope here — under the
+        # single-run path the trace scope carries NO flat width here — under the
         # gang vmap the [N, P] intermediates carry a leading member axis
         # the scope's rank-2 constraints do not expect; the jit-boundary
         # shardings pin the [B, N, P] layout and GSPMD propagates it
@@ -670,7 +693,7 @@ def _shard_gang_round_fn(
     if program.faulted:
         in_shardings.insert(5, alive_sharding)
     return jax.jit(
-        vfn,
+        _traced_on(mesh, vfn),
         in_shardings=tuple(in_shardings),
         out_shardings=(params_s, agg_s, repl),
         donate_argnums=(0, 1) if donate else (),
@@ -703,7 +726,7 @@ def shard_gang_eval_step(veval, program, batch: int, mesh: Mesh):
     data_s = _gang_spec_from_template(program.data_arrays, mesh)
     repl = NamedSharding(mesh, P())
     return jax.jit(
-        veval,
+        _traced_on(mesh, veval),
         in_shardings=(params_s, data_s),
         out_shardings=repl,
     )
@@ -721,7 +744,7 @@ def shard_eval_step(eval_step, program, mesh: Mesh):
     params_s = _shard_leading_axis(program.init_params, node_s, repl)
     data_s = _shard_leading_axis(program.data_arrays, node_s, repl)
     return jax.jit(
-        eval_step,
+        _traced_on(mesh, eval_step),
         in_shardings=(params_s, data_s),
         out_shardings=repl,
     )

@@ -14,6 +14,8 @@ until it exits, so every chip compile of the suite lives in this one file
 and compiles in the test's own process.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -33,7 +35,8 @@ SKETCH_SIZE = 1000
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
+    """The four described devices of one v5e host."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -48,9 +51,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield list(topo.devices)
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    return SingleDeviceSharding(four_chips[0])
 
 
 def _np_operand(one_chip, n=NODES, p=FEMNIST_CNN_PARAMS, dtype=jnp.float32):
@@ -181,3 +189,145 @@ def test_north_star_shape_fits_hbm(one_chip, kernel):
     # kernel also writes an [N, P] output: three such tensors cannot fit.)
     x = _np_operand(one_chip, n=256, p=ALIGNED_PARAMS)
     _compiled_text(_lower_kernel(kernel, x, 256))
+
+
+# --- the CNNs' node-folded convolution stack (models/core.py) ---------------
+
+
+def _stacked_sgd_gradients(nodes, batch, place):
+    """Lowered gradients of ``leaf.femnist.baseline``'s stacked forward (bf16
+    compute, bf16-resident parameters, as ``cnn_sketchguard_er_n64`` trains),
+    its arguments placed by ``place(shape, dtype)``."""
+    from murmura_tpu.models.registry import build_model
+    from murmura_tpu.ops.losses import masked_cross_entropy
+
+    model = build_model("leaf.femnist.baseline", {"compute_dtype": "bfloat16"})
+    shapes = jax.eval_shape(
+        jax.vmap(model.init), jax.random.split(jax.random.PRNGKey(0), nodes))
+    params = jax.tree_util.tree_map(
+        lambda l: place(l.shape, jnp.bfloat16), shapes)
+
+    def gradients(params, x, y, mask):
+        def loss(p):
+            p = jax.tree_util.tree_map(lambda l: l.astype(jnp.float32), p)
+            out = model.apply_stacked(p, x, None, True)
+            per_node = jax.vmap(lambda o, yy, mm: masked_cross_entropy(o, yy, mm)[0])
+            return per_node(out, y, mask).sum()
+
+        return jax.grad(loss)(params)
+
+    return gradients, (params,
+                       place((nodes, batch, 28, 28, 1), jnp.float32),
+                       place((nodes, batch), jnp.int32),
+                       place((nodes, batch), jnp.float32))
+
+
+def _conv_activations(text):
+    """Result shapes of the compiled program's 5-D operations over a batch
+    of images: what the grouped-convolution emitter keeps between the
+    convolutions, [B, H, W, G, C/G]."""
+    dims = (tuple(int(d) for d in m.group(1).split(","))
+            for m in re.finditer(r"= \w+\[(\d+,\d+,\d+,\d+,\d+)\]\{", text))
+    return {d for d in dims if d[1] == d[2] and d[1] in (28, 14, 7)}
+
+
+def test_folded_stack_fills_the_lanes(one_chip):
+    """Four nodes a group at 32 channels: every activation the TPU compiler
+    keeps between the convolutions has 128 lanes or more (one node a group
+    keeps ``[B, H, W, 64, 32]``, a quarter of each tile: PERF.md §6 PR 33)."""
+    from murmura_tpu.models import cnn
+
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    gradients, args = _stacked_sgd_gradients(16, 8, place)
+    packed = _conv_activations(jax.jit(gradients).lower(*args).compile().as_text())
+    # 16 nodes in 4 groups: beside the input (4 nodes x 1 channel) a group is
+    # 128 or 256 channels wide, and nothing is 32 wide any more.  (What stays
+    # [B, H, W, 16, 64] is the unfold to a node's rows for the dense stack.)
+    groups = {d for d in packed if d[3] == 4 and d[4] != 4}
+    assert groups and all(d[4] in (128, 256) for d in groups), packed
+    assert not any(d[4] == 32 for d in packed), packed
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(cnn, "nodes_a_group", lambda *shapes: 1)
+        alone = _conv_activations(  # a new function: jit keeps a trace by function
+            jax.jit(lambda *a: gradients(*a)).lower(*args).compile().as_text())
+    assert any(d[3] == 16 and d[4] == 32 for d in alone), alone
+
+
+def test_folded_stack_stays_on_its_chip(four_chips):
+    """The node axis over the host's four chips, four nodes a chip: a group
+    of four is a chip's own block, and forward and backward of the stack
+    compile to no collective at all."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from murmura_tpu.analysis.ir import collective_names
+    from murmura_tpu.parallel import mesh as mesh_mod
+
+    mesh = Mesh(np.array(four_chips), ("nodes",))
+    by_node = NamedSharding(mesh, PartitionSpec("nodes"))
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=by_node)
+    gradients, args = _stacked_sgd_gradients(16, 8, place)
+    def scoped(*args):  # as the programs jitted in parallel/mesh.py are traced
+        with mesh_mod.param_axis_scope(mesh):
+            return gradients(*args)
+
+    lowered = jax.jit(scoped, out_shardings=by_node).lower(*args)
+    assert "feature_group_count = 4 " in lowered.as_text()  # 16 nodes, 4 a group
+    assert collective_names(lowered.compile().as_text()) == frozenset()
+
+
+def _collectives(text):
+    """(kind, result type) of every collective of a compiled program."""
+    kinds = "all-gather|all-reduce|collective-permute|all-to-all|reduce-scatter"
+    return sorted(
+        (m.group(2), m.group(1)) for m in re.finditer(
+            r"= (\w+\[[\d,]*\])\S* (%s)\(" % kinds, text))
+
+
+def test_sharded_round_step_gains_no_collective(four_chips):
+    """The whole round step (local SGD of ``leaf.femnist.baseline``, flatten,
+    exchange, FedAvg, metrics) with 16 nodes over the host's four chips, as
+    ``shard_step`` jits it: with four nodes a group it holds no collective
+    that one node a group's does not, and none inside the local-SGD loop.
+    (One node a group's holds one there: the partitioner gathers the first
+    convolution's input; PERF.md §7.)"""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from murmura_tpu.config import Config
+    from murmura_tpu.models import cnn
+    from murmura_tpu.parallel import mesh as mesh_mod
+    from murmura_tpu.utils.factories import build_network_from_config
+
+    net = build_network_from_config(Config.model_validate({
+        "experiment": {"name": "mesh", "seed": 1, "rounds": 1},
+        "topology": {"type": "ring", "num_nodes": 16},
+        "aggregation": {"algorithm": "fedavg", "params": {}},
+        "training": {"local_epochs": 1, "batch_size": 8, "lr": 0.05},
+        "data": {"adapter": "leaf.femnist",
+                 "params": {"num_samples": 96, "partition_method": "iid"}},
+        "model": {"factory": "leaf.femnist.baseline", "params": {}},
+        "backend": "simulation",
+        "tpu": {"compute_dtype": "bfloat16"},
+    }))
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype),
+        net._round_inputs(0, net.compromised))
+    mesh = Mesh(np.array(four_chips), ("nodes",))
+
+    def compiled():  # a new jit a call: the rule is read while it is traced
+        step = mesh_mod.shard_step(
+            net.program.train_step, net.program, mesh, donate=False)
+        return step.lower(*args).compile().as_text()
+
+    packed = compiled()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(cnn, "nodes_a_group", lambda *shapes: 1)
+        alone = compiled()
+    assert "murmura.pack" in packed and "murmura.pack" not in alone
+    mine, parents = _collectives(packed), _collectives(alone)
+    assert mine and not [c for c in mine if c not in parents], (mine, parents)
+    assert len(mine) <= len(parents)
+    in_loop = [line for line in packed.splitlines()
+               if "murmura.train" in line and _collectives(line)]
+    assert in_loop == []

@@ -21,12 +21,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.sharding import Mesh
+
 from murmura_tpu.config import Config
 from murmura_tpu.models.cnn import (
     FEMNIST_VARIANTS,
     make_celeba_cnn,
     make_femnist_cnn,
 )
+from murmura_tpu.models import cnn
+from murmura_tpu.models.core import nodes_a_group
+from murmura_tpu.parallel import mesh as mesh_mod
 from murmura_tpu.utils import factories
 from murmura_tpu.utils.factories import (
     build_gang_from_config,
@@ -34,6 +39,7 @@ from murmura_tpu.utils.factories import (
 )
 
 BF16_ULP = 2.0 ** -8  # of a value in [1, 2): one part in 256 of the largest
+F32_SUM = 2.0 ** -18  # a float32 product of some thousand terms, resummed
 
 
 def _make(kind, compute_dtype):
@@ -59,49 +65,123 @@ def _loss(logits, y):
 KINDS = list(FEMNIST_VARIANTS) + ["celeba"]
 
 
-@pytest.mark.parametrize("n", [1, 3])
+def _narrowest(kind):
+    return 32 if kind == "celeba" else min(FEMNIST_VARIANTS[kind][0])
+
+
+# The rule's table (narrowest output of the stack, nodes one device holds ->
+# nodes a group): four, or two where four do not divide, from a quarter of
+# half a tile (16 channels) to under half a tile (64); one elsewhere.
+@pytest.mark.parametrize("narrowest, held, want", [
+    (8, 1, 1), (8, 4, 1), (8, 16, 1), (8, 64, 1), (15, 4, 1),
+    (16, 1, 1), (16, 2, 2), (16, 4, 4), (16, 8, 4), (16, 64, 4),
+    (32, 1, 1), (32, 2, 2), (32, 3, 1), (32, 4, 4), (32, 6, 2), (32, 8, 4), (32, 64, 4),
+    (48, 4, 4), (63, 12, 4), (64, 2, 1), (64, 3, 1), (64, 4, 1), (64, 64, 1),
+    (128, 4, 1), (256, 64, 1),
+])
+def test_nodes_a_group_follows_the_shapes(narrowest, held, want):
+    assert nodes_a_group(held, narrowest) == want
+
+
+@pytest.mark.parametrize("kind, want", [
+    ("tiny", 1), ("small", 4), ("baseline", 4), ("large", 1), ("xlarge", 1), ("celeba", 4)])
+def test_nodes_a_group_of_each_model(kind, want):
+    """What the chip measured a local-SGD step at (PERF.md §6 PR 33): the
+    stacks under half a tile gain at four, the 64-channel ones do not."""
+    assert nodes_a_group(64, _narrowest(kind)) == want
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 virtual devices")
+def test_a_group_stays_inside_one_device():
+    """While a program jitted over a mesh is traced the rule is given the
+    nodes one device holds, not the network's; outside, the network's."""
+    mesh = Mesh(np.array(jax.devices()[:4]), ("nodes",))
+    held = lambda: [mesh_mod.nodes_a_device(n) for n in (16, 8, 6)]
+    with mesh_mod.param_axis_scope(mesh):
+        assert held() == [4, 2, 1]  # 6 nodes do not split four ways
+        assert mesh_mod.active_param_scope() is None  # no param axis: identity
+    assert held() == [16, 8, 6]
+    assert [nodes_a_group(h, 32) for h in (4, 2, 1)] == [4, 2, 1]
+
+
+@pytest.fixture(scope="module")
+def drawn():
+    """One draw of parameters and inputs a kind, cut to its first n nodes:
+    ``init`` does not read the compute dtype."""
+    cache = {}
+
+    def draw(kind, n):
+        if kind not in cache:
+            cache[kind] = _inputs(_make(kind, "float32"), 8)
+        return jax.tree_util.tree_map(lambda l: l[:n], cache[kind])
+
+    return draw
+
+
+def _run(f):
+    """``f`` as one program that rounds where its source rounds: left to
+    itself the CPU compiler takes a cast to bf16 and back for no cast
+    (operation by operation gives the same numbers in ten times the time)."""
+
+    def call(*args):
+        return jax.jit(f).lower(*args).compile(
+            compiler_options={"xla_allow_excess_precision": False})(*args)
+
+    return call
+
+
+# Nodes a group by N: 1 (``apply``'s case), 3 (neither four nor two divide
+# it), 4 (one group of four), 8 (two groups of four); the 8-channel stack
+# and the 64-channel ones keep one node a group throughout.
+@pytest.mark.parametrize("n", [1, 3, 4, 8])
 @pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("kind", KINDS)
-def test_stacked_forward_and_gradients_match_vmap(kind, compute_dtype, n):
+def test_stacked_forward_and_gradients_match_vmap(kind, compute_dtype, n, drawn):
     model = _make(kind, compute_dtype)
-    params, x, y = _inputs(model, n)
+    params, x, y = drawn(kind, n)
+    per = nodes_a_group(n, _narrowest(kind))
+    # One node a group repeats vmap's products; several sum a product's
+    # terms in another order, and a rounding to bf16 may then fall the
+    # other way: one more part in 256 of the largest value.
+    ulp = BF16_ULP * (1 if per == 1 else 2)
 
-    stacked = model.apply_stacked(params, x, None, True)
-    vmapped = jax.vmap(lambda p, xi: model.apply(p, xi, None, True))(params, x)
+    # Outputs and gradients of each path from one program.
+    (_, stacked), g_stacked = _run(jax.value_and_grad(
+        lambda p: (lambda out: (jax.vmap(_loss)(out, y).sum(), out))(
+            model.apply_stacked(p, x, None, True)), has_aux=True))(params)
+    (_, vmapped), g_vmapped = _run(jax.vmap(jax.value_and_grad(
+        lambda p, xi, yi: (lambda out: (_loss(out, yi), out))(
+            model.apply(p, xi, None, True)), has_aux=True)))(params, x, y)
     assert stacked.shape == (n, x.shape[1], model.num_classes)
-    if compute_dtype == "float32":
+    if compute_dtype == "float32" and per == 1:
         np.testing.assert_array_equal(np.asarray(stacked), np.asarray(vmapped))
     else:
         np.testing.assert_allclose(
-            np.asarray(stacked), np.asarray(vmapped),
-            atol=BF16_ULP * float(jnp.abs(vmapped).max()), rtol=0,
+            np.asarray(stacked), np.asarray(vmapped), rtol=0,
+            atol=(F32_SUM if compute_dtype == "float32" else ulp)
+            * float(jnp.abs(vmapped).max()),
         )
     # One node of the stacked forward is that node's apply.
     one = jax.tree_util.tree_map(lambda l: l[0], params)
     np.testing.assert_allclose(
-        np.asarray(stacked[0]), np.asarray(model.apply(one, x[0], None, True)),
-        atol=BF16_ULP * float(jnp.abs(stacked).max()), rtol=0,
+        np.asarray(stacked[0]), np.asarray(_run(
+            lambda p, xi: model.apply(p, xi, None, True))(one, x[0])),
+        atol=ulp * float(jnp.abs(stacked).max()), rtol=0,
     )
-
-    g_stacked = jax.grad(
-        lambda p: jax.vmap(_loss)(model.apply_stacked(p, x, None, True), y).sum()
-    )(params)
-    g_vmapped = jax.vmap(
-        jax.grad(lambda p, xi, yi: _loss(model.apply(p, xi, None, True), yi))
-    )(params, x, y)
     for a, b in zip(jax.tree_util.tree_leaves(g_stacked),
                     jax.tree_util.tree_leaves(g_vmapped)):
         assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b),
-            atol=BF16_ULP * float(jnp.abs(b).max()), rtol=0,
+            atol=ulp * float(jnp.abs(b).max()), rtol=0,
         )
 
 
 def test_stacked_forward_batches_under_vmap():
-    """A gang vmaps the round over members: the fold must batch."""
-    model = _make("tiny", "bfloat16")
-    params, x, _ = _inputs(model, 3)
+    """A gang vmaps the round over members: the fold, and the packing of
+    four nodes a group, must batch."""
+    model = _make("baseline", "bfloat16")
+    params, x, _ = _inputs(model, 4)
     gang = lambda t: jnp.stack([t, t[::-1]])
     out = jax.vmap(lambda p, xi: model.apply_stacked(p, xi, None, False))(
         jax.tree_util.tree_map(gang, params), gang(x)
@@ -109,6 +189,75 @@ def test_stacked_forward_batches_under_vmap():
     alone = model.apply_stacked(params, x, None, False)
     np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(alone))
     np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(alone[::-1]))
+
+
+# --- a node at fault stays alone ----------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def four_in_a_group():
+    """``baseline`` at N = 4, one group of four: outputs and gradients of
+    the stacked path and of ``vmap(apply)``, each compiled once."""
+    model = _make("baseline", "bfloat16")
+    params, x, y = _inputs(model, 4)
+    assert nodes_a_group(4, _narrowest("baseline")) == 4
+    once = lambda f, *args: jax.jit(f).lower(*args).compile(  # as ``_run``
+        compiler_options={"xla_allow_excess_precision": False})
+    stacked = once(jax.value_and_grad(
+        lambda p, x: (lambda out: (jax.vmap(_loss)(out, y).sum(), out))(
+            model.apply_stacked(p, x, None, True)), has_aux=True), params, x)
+    vmapped = once(jax.vmap(jax.value_and_grad(
+        lambda p, xi, yi: (lambda out: (_loss(out, yi), out))(
+            model.apply(p, xi, None, True)), has_aux=True)), params, x, y)
+    return params, x, stacked, (lambda p, x: vmapped(p, x, y))
+
+
+def _plant(params, x, where, value, node=1):
+    """One entry of ``node``'s leaf ``where`` (or of its images) set to
+    ``value``."""
+    if where == "images":
+        return params, x.at[node, 0, 3, 3, 0].set(value)
+    group, layer, leaf = where
+    params = jax.tree_util.tree_map(lambda l: l, params)
+    old = params[group][layer][leaf]
+    params[group][layer][leaf] = old.at[(node,) + (0,) * (old.ndim - 1)].set(value)
+    return params, x
+
+
+# Where the fault enters: the convolutions' input (forward: the zero blocks
+# of the group's kernel would multiply it), their kernels and biases, and
+# the dense stack behind them (backward only: the stack's forward is finite
+# and the cotangent that comes back is not).
+@pytest.mark.parametrize("value", [np.inf, -np.inf, np.nan], ids=["inf", "-inf", "nan"])
+@pytest.mark.parametrize("where", [
+    "images", ("convs", 0, "w"), ("convs", 1, "w"), ("convs", 1, "b"),
+    ("fcs", 0, "w"), ("fcs", 1, "w"),
+], ids=lambda w: w if isinstance(w, str) else "{}{}.{}".format(*w))
+def test_a_node_at_fault_stays_alone(four_in_a_group, where, value):
+    """One node of a group of four holds a value that is not finite: the
+    other three's outputs and gradients are ``vmap(apply)``'s, as under one
+    node a group (0 * inf is NaN: the zero blocks must never meet it), and
+    the node at fault ends non-finite where ``vmap(apply)`` ends it so, for
+    ``faults.nan_quarantine`` to find it alone."""
+    params, x, stacked, vmapped = four_in_a_group
+    params, x = _plant(params, x, where, value)
+    (_, out_s), grad_s = stacked(params, x)
+    (_, out_v), grad_v = vmapped(params, x)
+    others = np.array([0, 2, 3])
+    f32 = lambda l: np.asarray(l, np.float32)
+    np.testing.assert_allclose(
+        f32(out_s)[others], f32(out_v)[others], rtol=0,
+        atol=2 * BF16_ULP * float(np.abs(f32(out_v)[others]).max()))
+    at_fault = {"stacked": True, "vmapped": True}
+    for a, b in zip(jax.tree_util.tree_leaves(grad_s), jax.tree_util.tree_leaves(grad_v)):
+        a, b = f32(a), f32(b)
+        assert np.isfinite(a[others]).all()
+        np.testing.assert_allclose(
+            a[others], b[others], rtol=0, atol=2 * BF16_ULP * np.abs(b[others]).max())
+        at_fault["stacked"] &= bool(np.isfinite(a[1]).all())
+        at_fault["vmapped"] &= bool(np.isfinite(b[1]).all())
+    assert at_fault["stacked"] == at_fault["vmapped"]
+    assert np.isfinite(f32(out_s)[1]).all() == np.isfinite(f32(out_v)[1]).all()
 
 
 # --- whole jobs: the two paths through local SGD and eval --------------------
@@ -131,6 +280,9 @@ def _raw(**overrides):
     return raw
 
 
+# 32 and 64 channels: four nodes a group (``leaf.femnist.tiny``, the other
+# jobs' model, has 8 and 16 and keeps one).
+PACKED = {"factory": "leaf.femnist.baseline", "params": {}}
 MLP = dict(
     model={"factory": "mlp",
            "params": {"input_dim": 10, "hidden_dims": [16], "num_classes": 3}},
@@ -182,15 +334,47 @@ def _assert_histories_close(got, want):
         _assert_close([np.asarray(got[key])], [np.asarray(want[key])])
 
 
-@pytest.fixture(scope="module")
-def folded_job():
-    return _end_state(_build(_raw()))
+@pytest.mark.parametrize("model", [_raw()["model"], PACKED], ids=["tiny", "baseline"])
+def test_job_end_state_matches_vmap_path(model):
+    folded = _end_state(_build(_raw(model=model)))
+    history, state = _end_state(_build(_raw(model=model), folded=False))
+    _assert_close(folded[1], state)
+    _assert_histories_close(folded[0], history)
 
 
-def test_job_end_state_matches_vmap_path(folded_job):
-    history, state = _end_state(_build(_raw(), folded=False))
-    _assert_close(folded_job[1], state)
-    _assert_histories_close(folded_job[0], history)
+@pytest.mark.parametrize("quarantine", [True, False], ids=["sentinel", "no-sentinel"])
+def test_one_diverged_node_is_one_quarantined(quarantine):
+    """A node of a packed group diverges in local SGD (a kernel entry near
+    float32's largest, so its products overflow): with ``faults.nan_quarantine`` the sentinel counts one node a
+    round, not the group's four, and the other three train as they do
+    beside a healthy node; without it the exchange spreads the node's row
+    to the nodes it reaches under ``vmap(apply)``."""
+    raw = _raw(model=PACKED, faults={"enabled": True, "nan_quarantine": quarantine},
+               experiment={"name": "stacked", "seed": 1, "rounds": 1})
+
+    def trained(planted, folded=True):
+        net = _build(raw, folded)
+        if planted:
+            net.params = _plant(net.params, None, ("convs", 1, "w"), 3e38)[0]
+        return _end_state(net, rounds=1)
+
+    history, state = trained(True)
+    if not quarantine:
+        _, vmapped = trained(True, folded=False)
+        whole = lambda leaves: np.all(
+            [np.isfinite(l).reshape(4, -1).all(axis=1) for l in leaves], axis=0)
+        np.testing.assert_array_equal(whole(state), whole(vmapped))
+        return
+    assert history["agg_quarantined"] == [1.0]
+    _, healthy = trained(False)
+    kernel = [l for l in state if l.ndim == 5 and l.shape[-1] == 64][0]
+    assert kernel[1].max() > 1e38  # rolled back to what it started from
+    # Nodes 0 and 2 are the ring's neighbours of node 1 and aggregate
+    # without it; node 3 never hears from it and ends where it ends
+    # beside a healthy node 1.
+    for a, b in zip(state, healthy):
+        assert np.isfinite(a[[0, 2, 3]]).all()
+        np.testing.assert_allclose(a[3], b[3], atol=BF16_ULP * np.abs(b[3]).max(), rtol=0)
 
 
 def test_job_under_a_gang_matches_vmap_path():
@@ -208,15 +392,19 @@ def test_job_under_a_gang_matches_vmap_path():
 
 
 @pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 virtual devices")
-def test_job_on_a_mesh_matches_one_device():
+@pytest.mark.parametrize("model, nodes", [(_raw()["model"], 4), (PACKED, 16)],
+                         ids=["tiny-4", "baseline-16"])
+def test_job_on_a_mesh_matches_one_device(model, nodes):
     """Node axis sharded over a forced 4-device CPU mesh: the folded axis is
-    block-sharded by node, and the run equals the unsharded one."""
+    block-sharded by node (at 16 nodes a device's four are one group of the
+    convolutions), and the run equals the unsharded one."""
     runs = {}
     for devices in (1, 4):
         # float32 products: sharding is what differs, and at bf16 a node's
         # convolution alone rounds otherwise than the same node's in a group.
-        raw = _raw(backend="tpu", tpu={"compute_dtype": "float32",
-                                       "num_devices": devices})
+        raw = _raw(backend="tpu", model=model,
+                   topology={"type": "ring", "num_nodes": nodes},
+                   tpu={"compute_dtype": "float32", "num_devices": devices})
         net = _build(raw)
         assert dict(net.mesh.shape)["nodes"] == devices
         runs[devices] = _end_state(net)
@@ -248,43 +436,119 @@ def _ops(text, kind):
 
 
 def _group_counts(text):
-    return [int(g) for g in re.findall(r"feature_group_count=(\d+)", text)]
+    """Groups of every convolution of an HLO text, forward and backward
+    (a weight gradient's are its ``batch_group_count``; HLO prints neither
+    count where it is 1)."""
+    count = lambda line, key: int((re.search(key + r"=(\d+)", line) or [0, 1])[1])
+    return [max(count(line, "feature_group_count"), count(line, "batch_group_count"))
+            for line in text.splitlines() if " convolution(" in line]
 
 
-def _activation_transposes(text, batch, nodes=4):
-    """5-D transposes of what lies between the convolutions of
-    ``leaf.femnist.tiny`` (8 and 16 channels, 28x28 in): everything but
-    the input batch, the kernels and the last pool's output."""
+def _activation_transposes(text, batch, nodes, channels):
+    """5-D transposes of what lies between the two convolutions of a FEMNIST
+    CNN (28x28 in): everything but the input batch, the kernels and the last
+    pool's output."""
+    first, second = channels
     between = {tuple(sorted((nodes, batch, hw, hw, c)))
-               for hw, c in ((28, 8), (14, 8), (14, 16))}
+               for hw, c in ((28, first), (14, first), (14, second))}
     return [op for op in _ops(text, "transpose")
             if len(op[1]) == 5 and tuple(sorted(op[1])) in between]
 
 
 @pytest.fixture(scope="module")
 def cnn_steps():
-    return {
-        folded: [low.as_text(dialect="hlo") for low in _lowered(_build(_raw(), folded))]
-        for folded in (True, False)
-    }
+    cache = {}
+
+    def steps(variant, nodes, folded):
+        if (variant, nodes, folded) not in cache:
+            raw = _raw(model={"factory": f"leaf.femnist.{variant}", "params": {}},
+                       topology={"type": "ring", "num_nodes": nodes})
+            cache[variant, nodes, folded] = [
+                low.as_text(dialect="hlo") for low in _lowered(_build(raw, folded))]
+        return cache[variant, nodes, folded]
+
+    return steps
 
 
+# ``tiny`` (8 and 16 channels) keeps a node a group; ``baseline`` (32 and
+# 64) takes four: one group at 4 nodes, two at 8.
+@pytest.mark.parametrize("variant, nodes, groups", [
+    ("tiny", 4, 4), ("baseline", 4, 1), ("baseline", 8, 2)])
 @pytest.mark.parametrize("program", ["round step", "eval step"])
-def test_cnn_steps_keep_the_convolution_stack_folded(cnn_steps, program):
+def test_cnn_steps_keep_the_convolution_stack_folded(
+        cnn_steps, program, variant, nodes, groups):
     which = ("round step", "eval step").index(program)
-    text = cnn_steps[True][which]
-    assert set(_group_counts(text)) == {4}
+    channels = FEMNIST_VARIANTS[variant][0]
+    text = cnn_steps(variant, nodes, True)[which]
+    assert set(_group_counts(text)) == {groups}
     pools = _ops(text, "reduce-window") + _ops(text, "select-and-scatter")
     assert pools and all(len(dims) == 4 for _, dims, _ in pools)
-    assert all(dims[-1] in (4 * 8, 4 * 16) for _, dims, _ in pools)
+    assert all(dims[-1] in (nodes * channels[0], nodes * channels[1])
+               for _, dims, _ in pools)
     batch = pools[0][1][0]
-    assert _activation_transposes(text, batch) == []
-    # The vmapped path is what these assertions are about: it pools in 5-D
-    # between two transposes of each activation.
-    vmapped = cnn_steps[False][which]
+    assert _activation_transposes(text, batch, nodes, channels) == []
+    # The vmapped path is what these assertions are about: a node a group,
+    # pooling in 5-D between two transposes of each activation.
+    vmapped = cnn_steps(variant, nodes, False)[which]
+    assert set(_group_counts(vmapped)) == {nodes}
     assert any(len(dims) == 5 for _, dims, _ in
                _ops(vmapped, "reduce-window") + _ops(vmapped, "select-and-scatter"))
-    assert _activation_transposes(vmapped, batch)
+    assert _activation_transposes(vmapped, batch, nodes, channels)
+
+
+def test_one_node_lowers_to_the_ungrouped_convolution():
+    """``apply`` (the ZMQ backend's forward, N = 1) packs nothing: its
+    lowered program is one node a group's, text for text."""
+    model = _make("baseline", "bfloat16")
+    params, x, _ = _inputs(model, 4)
+    one = jax.tree_util.tree_map(lambda l: l[0], params)
+
+    def lowered(args, stacked=False, **text):
+        # A new function a call: jit keeps a trace by function.
+        if stacked:
+            return jax.jit(lambda p, xi: model.apply_stacked(p, xi, None, True)
+                           ).lower(*args).as_text(**text)
+        return jax.jit(lambda p, xi: model.apply(p, xi, None, True)
+                       ).lower(*args).as_text(**text)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(cnn, "nodes_a_group", lambda *shapes: 1)
+        alone = lowered((one, x[0]))
+        four_alone = lowered((params, x), stacked=True)
+    assert lowered((one, x[0])) == alone
+    assert "murmura.pack" not in lowered((one, x[0]), debug_info=True)
+    # Four nodes are packed, and the text differs from one node a group's.
+    assert "murmura.pack" in lowered((params, x), stacked=True, debug_info=True)
+    assert lowered((params, x), stacked=True) != four_alone
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 virtual devices")
+@pytest.mark.parametrize("program", ["round step", "eval step"])
+def test_a_sharded_node_axis_gains_no_collective(program):
+    """16 nodes over a forced 4-device CPU mesh, four a device, so that the
+    groups of four are the devices' own blocks: the compiled step holds no
+    kind of collective that one node a group's does not.  (Kinds, not
+    counts: the partitioner splits a grouped convolution by group only at
+    one input channel a group and gathers the operands of every other,
+    one node a group's second convolution included; PERF.md §7.)"""
+    from murmura_tpu.analysis.ir import collective_names
+
+    which = ("round step", "eval step").index(program)
+    raw = _raw(backend="tpu", model=PACKED,
+               topology={"type": "ring", "num_nodes": 16},
+               tpu={"compute_dtype": "bfloat16", "num_devices": 4})
+
+    def compiled(rule):
+        with pytest.MonkeyPatch.context() as patch:
+            if rule is not None:
+                patch.setattr(cnn, "nodes_a_group", rule)
+            return _lowered(_build(raw))[which].compile().as_text()
+
+    packed, alone = compiled(None), compiled(lambda *shapes: 1)
+    assert "feature_group_count=16" in alone
+    assert "feature_group_count=16" not in packed
+    assert "feature_group_count=4" in packed
+    assert collective_names(packed) == collective_names(alone) == {"all_gather"}
 
 
 def _products(lowered):

@@ -205,6 +205,14 @@ class AggregatorDef:
     # result's parity across dense/circulant/sparse/compressed modes
     # (MUR802).  None = undeclared, itself a finding for registered rules.
     influence: Optional[InfluenceDecl] = None
+    # The rule mixes the rows with weights that no row's values decide and
+    # treats every column alike, so applied to one leaf's columns it gives
+    # those columns of what it gives on the whole [N, P] row (FedAvg's
+    # dense mean), and it takes ``own``/``bcast`` of any rank [N, ...].
+    # core/rounds.py then hands a state of a GiB or more over leaf by
+    # leaf, each stacked leaf as it lies, and never builds its [N, P] row
+    # (``build_round_program``, "by leaf").  False: the rule needs the row.
+    leafwise: bool = False
 
     def declared_collectives(self, circulant) -> Optional[FrozenSet[str]]:
         """Allowed collective set for one exchange mode (``None`` =

@@ -33,6 +33,18 @@ def make_fedavg(
         raise ValueError("sparse_exchange requires exchange_offsets")
 
     def aggregate(own, bcast, adj, round_idx, state, ctx: AggContext):
+        if own.ndim > 2:
+            # ``leafwise`` (below): a stacked leaf [N, ...] handed over as
+            # it lies.  The same mean, contracting the node axis alone: no
+            # reshape, which on a TPU would relayout the whole leaf.
+            degree = adj.sum(axis=1)
+            neighbor_sum = jnp.tensordot(
+                adj.astype(bcast.dtype), bcast, axes=(1, 0),
+                preferred_element_type=jnp.float32,
+            )
+            by_node = (1.0 + degree).reshape((-1,) + (1,) * (own.ndim - 1))
+            new = ((own + neighbor_sum) / by_node).astype(own.dtype)
+            return new, state, {"num_neighbors": degree}
         if sparse_exchange:
             # Sparse exchange mode (topology/sparse.py): ``adj`` is the
             # [k, N] per-offset edge mask, never [N, N]; its rows weight
@@ -86,6 +98,8 @@ def make_fedavg(
         # only through the shared roll kernels, which move the int8
         # payload (MUR700).
         quantized_exchange=offsets is not None,
+        # The dense mean's weights are the graph's alone (base.py).
+        leafwise=offsets is None and not sparse_exchange,
         # MUR800: plain averaging has no Byzantine filter at all — every
         # neighbor's state enters the 1/(1+degree) mean.  Declared
         # unbounded on purpose: the flow analyzer must never be able to

@@ -302,7 +302,9 @@ class Network:
             )
 
         # Mutable run state
-        self.params = program.init_params
+        # A large initial state is the program's on the host
+        # (rounds.LARGE_STATE_BYTES); a device array passes through as it is.
+        self.params = jax.tree_util.tree_map(jnp.asarray, program.init_params)
         self.agg_state = {k: jnp.asarray(v) for k, v in program.init_agg_state.items()}
         self._data = {k: jnp.asarray(v) for k, v in program.data_arrays.items()}
         self._place_resident_state()
@@ -332,6 +334,45 @@ class Network:
         # evidential-loss annealing) and the mobility G^t keep advancing
         # across successive train() calls and checkpoint resumes.
         self.current_round = 0
+
+    @property
+    def params(self):
+        """The stacked [N, ...] state of the run."""
+        return self._params
+
+    @params.setter
+    def params(self, value) -> None:
+        # ``params = None`` is how the benchmark's harness gives the device
+        # back before its reference runs (benchmark/harness.py ``free``),
+        # and a PR that adds a cell may not edit the harness: until a
+        # ``benchmark`` PR calls ``unload()`` there, that assignment is the
+        # call (PERF.md section 7, item 6 h).  Then this property goes.
+        if value is None:
+            self.unload()
+        else:
+            self._params = value
+
+    def unload(self) -> None:
+        """Drop the state and this network's compiled programs, to have the
+        device back.  On a TPU a loaded program keeps its scratch memory
+        reserved (5.46 GiB for the round step of three 568M-parameter
+        nodes), and ``jax.clear_caches`` is the one handle that frees the
+        reservation (a jitted function's own ``clear_cache`` leaves the
+        loaded program where it is: read on the chip, PERF.md section 6,
+        PR 34), so **every compiled program of the process goes**: the next
+        call of any of them compiles, or loads from the persistent cache,
+        again.  A network that has compiled nothing clears nothing."""
+        self._params = None
+        programs = (
+            getattr(self, "_step", None), getattr(self, "_eval", None),
+            *getattr(self, "_fused_cache", {}).values(),
+        )
+        compiled = self.__dict__.get("_aot_compiled") is not None or any(
+            getattr(fn, "_cache_size", lambda: 0)() for fn in programs
+        )
+        self._aot_compiled = None
+        if compiled:
+            jax.clear_caches()
 
     def _place_resident_state(self) -> None:
         """Explicitly place params/agg_state/data on the mesh (tpu backend,

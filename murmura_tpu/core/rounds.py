@@ -71,6 +71,7 @@ from murmura_tpu.parallel.mesh import constrain_flat, constrain_replicated
 from murmura_tpu.ops.losses import (
     evidential_loss,
     masked_cross_entropy,
+    masked_next_token_cross_entropy,
     uncertainty_metrics,
 )
 
@@ -173,6 +174,19 @@ class RoundProgram:
     @property
     def stale(self) -> bool:
         return self.staleness is not None
+
+
+# From this size on the stacked initial state is drawn in one program and
+# kept on the host (``build_round_program``): a size no model of the paper's
+# reaches at the node counts one chip holds.
+LARGE_STATE_BYTES = 1 << 30
+
+
+def _state_bytes(model: Model, n: int, dtype) -> int:
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    return n * jnp.dtype(dtype).itemsize * sum(
+        int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(shapes)
+    )
 
 
 def _broadcast_to_leaf(mask: jnp.ndarray, leaf: jnp.ndarray) -> jnp.ndarray:
@@ -413,16 +427,32 @@ def build_round_program(
 
     # ---- initial stacked params ------------------------------------------
     init_keys = jax.random.split(jax.random.PRNGKey(seed), n)
-    init_params = jax.vmap(model.init)(init_keys)
-    if param_dtype not in (None, "float32"):
-        # tpu.param_dtype=bfloat16: store the stacked [N, ...] state (and
-        # therefore the gathered/exchanged [N, P] tensor) in bf16 — halves
-        # resident HBM and ICI bytes at the cost of parameter precision.
-        # compute_dtype independently controls matmul input precision.
-        dt = jnp.dtype(param_dtype)
-        init_params = jax.tree_util.tree_map(
-            lambda l: l.astype(dt), init_params
-        )
+    resident = jnp.dtype(
+        jnp.float32 if param_dtype in (None, "float32") else param_dtype
+    )
+    large_state = _state_bytes(model, n, resident) >= LARGE_STATE_BYTES
+    if large_state:
+        # A state of a GiB or more is drawn and cast in one program (leaf
+        # by leaf it would stand in float32 beside its cast, and compile a
+        # program a leaf's shape), and the program's record of it goes to
+        # the host: the network's own copy is the one on the device, and a
+        # second would stay there for the whole run.
+        init_params = jax.device_get(jax.jit(
+            lambda keys: jax.tree_util.tree_map(
+                lambda l: l.astype(resident), jax.vmap(model.init)(keys)
+            )
+        )(init_keys))
+    else:
+        init_params = jax.vmap(model.init)(init_keys)
+        if param_dtype not in (None, "float32"):
+            # tpu.param_dtype=bfloat16: store the stacked [N, ...] state (and
+            # therefore the gathered/exchanged [N, P] tensor) in bf16 — halves
+            # resident HBM and ICI bytes at the cost of parameter precision.
+            # compute_dtype independently controls matmul input precision.
+            dt = jnp.dtype(param_dtype)
+            init_params = jax.tree_util.tree_map(
+                lambda l: l.astype(dt), init_params
+            )
     template = jax.tree_util.tree_map(lambda l: l[0], init_params)
     if param_shards > 1:
         ravel, unravel, model_dim, flat_dim = make_sharded_flatteners(
@@ -431,6 +461,18 @@ def build_round_program(
     else:
         ravel, unravel, model_dim = make_flatteners(template)
         flat_dim = model_dim
+    # A state of a GiB or more under a rule that can take it leaf by leaf,
+    # with nothing between training and the rule that needs the [N, P] row
+    # (an attack, the fault sentinels, a codec, the stale cache, the
+    # pipeline's buffers, the trust protocol's probes): the round never
+    # flattens it (``_round_body_by_leaf``).  Shapes and the job decide;
+    # every other program is what it was.
+    by_leaf = (
+        agg.leafwise and large_state
+        and attack is None and faults is None and compression is None
+        and staleness is None and dmtt is None and not pipeline
+        and param_shards == 1 and not agg.init_state(n)
+    )
     if param_shards > 1 and compression is not None:
         # int8 per-block scales must shard WITH the payload: a quant block
         # straddling a shard boundary would compute its scale from two
@@ -503,7 +545,14 @@ def build_round_program(
 
     # A model that offers a stacked forward (models/core.py Model) trains
     # and evaluates through it; every other model through vmap(apply).
-    if model.apply_stacked is not None:
+    if model.apply_train is not None:
+        # Trained by ``local_training_by_node`` below, and evaluated one
+        # node after another too.
+        def stacked_apply(params, x):  # murmura: traced
+            return jax.lax.map(
+                lambda px: model.apply(px[0], px[1], None, False), (params, x)
+            )
+    elif model.apply_stacked is not None:
         grads_fn = jax.grad(summed_loss)
 
         def stacked_apply(params, x):  # murmura: traced
@@ -565,6 +614,109 @@ def build_round_program(
         params, _ = jax.lax.scan(epoch_body, params, epoch_keys)
         return params
 
+    def node_step_loss(params_i, xb, yb, mb, key):  # murmura: traced
+        """One node's loss with the part that is the model's own, and the
+        step's counts summed over the samples the batch's mask keeps
+        (``Model.apply_train``)."""
+        outputs, auxiliary = model.apply_train(params_i, xb, key)
+        with jax.named_scope("murmura.head"):
+            loss, _ = masked_next_token_cross_entropy(outputs, yb, mb)
+            own = (auxiliary["loss"] * mb).sum() / jnp.maximum(mb.sum(), 1.0)
+        counts = jax.tree_util.tree_map(
+            lambda c: jnp.tensordot(mb, c.astype(jnp.float32), axes=1),
+            auxiliary["step"],
+        )
+        return loss + own, counts
+
+    def local_training_by_node(params, d, honest, key):  # murmura: traced
+        """``local_training`` for a model with a training rule of its own
+        (``Model.apply_train``): the same batch schedule, keys and update
+        mask, one node's step after another in one loop over (node, epoch,
+        step).  A node's parameters leave the stacked state for a step and
+        go back in place, so one node's gradients and activations are live
+        at a time; after each step it takes, ``Model.after_step`` moves
+        what takes no gradient.  Returns the state and the nodes'
+        ``Model.step_metrics`` (each [N])."""
+        orders, step_keys = [], []
+        epoch_keys = jax.random.split(key, local_epochs)
+        for e in range(local_epochs):
+            perm_key, step_key = jax.random.split(epoch_keys[e])
+            u = constrain_replicated(
+                jax.random.uniform(perm_key, d["mask"].shape)
+            ) + (1.0 - d["mask"]) * 10.0
+            orders.append(jnp.argsort(u, axis=1))
+            step_keys.append(step_key)
+        orders, step_keys = jnp.stack(orders), jnp.stack(step_keys)  # [E, N, S], [E]
+        eff_lr = d["hp_lr"] if "lr" in hp_inputs else lr
+        j = jnp.arange(global_batch)
+        grad_fn = jax.value_and_grad(node_step_loss, has_aux=True)
+        row = lambda a, i: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+        template_i = jax.tree_util.tree_map(lambda l: l[0], params)
+        (_, counts0), _ = jax.eval_shape(
+            grad_fn, template_i, d["x"][0, :global_batch], d["y"][0, :global_batch],
+            jnp.zeros((global_batch,), jnp.float32), step_keys[0],
+        )
+        steps_a_node = local_epochs * max_steps
+
+        def one_step(k, carry):
+            params, counted = carry
+            i, e, t = (
+                k // steps_a_node, (k % steps_a_node) // max_steps, k % max_steps
+            )
+            eff, count = row(d["eff_batch"], i), row(d["num_samples"], i)
+            idx = row(row(orders, e), i)[(t * eff + j) % jnp.maximum(count, 1)]
+            node_key = row(
+                jax.random.split(jax.random.fold_in(row(step_keys, e), t), n), i
+            )
+            p = jax.tree_util.tree_map(lambda l: row(l, i), params)
+            (_, counts), grads = grad_fn(
+                p, row(d["x"], i)[idx], row(d["y"], i)[idx],
+                (j < eff).astype(jnp.float32), node_key,
+            )
+            update = row(honest, i) * (t < row(d["steps"], i)).astype(jnp.float32)
+            with jax.named_scope("murmura.update"):
+                stepped = jax.tree_util.tree_map(
+                    lambda l, g: l.astype(jnp.float32)
+                    - eff_lr * update * g.astype(jnp.float32),
+                    p, grads,
+                )
+            moved = stepped
+            if model.after_step is not None:
+                moved = model.after_step(stepped, counts)
+            with jax.named_scope("murmura.update"):
+                params = jax.tree_util.tree_map(
+                    lambda whole, l, m: jax.lax.dynamic_update_index_in_dim(
+                        whole,
+                        jnp.where(update > 0, m, l.astype(jnp.float32)).astype(l.dtype),
+                        i, 0,
+                    ),
+                    params, p, moved,
+                )
+            counted = jax.tree_util.tree_map(
+                lambda a, c: a.at[i].add(update * c), counted, counts
+            )
+            return params, counted
+
+        counted = jax.tree_util.tree_map(
+            lambda c: jnp.zeros((n,) + c.shape, c.dtype), counts0
+        )
+        params, counted = jax.lax.fori_loop(
+            0, n * steps_a_node, one_step, (params, counted)
+        )
+        stats = {}
+        if model.step_metrics is not None:
+            stats = jax.vmap(model.step_metrics)(params, counted)
+        return params, stats
+
+    def train_nodes(params, d, train_mask, key, round_idx):  # murmura: traced
+        """The round's local training by the path the model takes: the
+        trained state and the nodes' counters of the round ({} for a model
+        without ``Model.apply_train``)."""
+        with jax.named_scope("murmura.train"):
+            if model.apply_train is not None:
+                return local_training_by_node(params, d, train_mask, key)
+            return local_training(params, d, train_mask, key, round_idx), {}
+
     # ---- evaluation (node.py:111-196) ------------------------------------
     def evaluate(params, x, y, mask):  # murmura: traced
         s = x.shape[1]
@@ -573,11 +725,16 @@ def build_round_program(
         pad = n_chunks * chunk - s
         if pad:
             x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-            y = jnp.pad(y, [(0, 0), (0, pad)])
+            y = jnp.pad(y, [(0, 0), (0, pad)] + [(0, 0)] * (y.ndim - 2))
             mask = jnp.pad(mask, [(0, 0), (0, pad)])
 
         def chunk_rows(outputs, yc, mc):  # one node's chunk
             cnt = mc.sum()
+            if yc.ndim == 2:
+                # One target a position: a sample's loss and accuracy are
+                # the means over its positions.
+                loss, acc = masked_next_token_cross_entropy(outputs, yc, mc)
+                return {"loss": loss * cnt, "correct": acc * cnt, "count": cnt}
             if evidential:
                 unc = uncertainty_metrics(outputs)
                 probs = unc["probs"]
@@ -698,8 +855,9 @@ def build_round_program(
         # murmura.train in a faulted program and after it otherwise, so
         # it is no levers.STAGE_ORDER label (docs/OBSERVABILITY.md "Host
         # spans and device scopes").
-        with jax.named_scope("murmura.train"):
-            params = local_training(params, d, train_mask, train_key, round_idx)
+        params, train_stats = train_nodes(
+            params, d, train_mask, train_key, round_idx
+        )
 
         # 2. snapshot + attack on outgoing states (network.py:105-119).
         # constrain_flat pins the [N, P] tensors to ("nodes", "param")
@@ -872,6 +1030,7 @@ def build_round_program(
             "fault_stats": fault_stats,
             "compress_stats": compress_stats,
             "stale_stats": stale_stats,
+            "train_stats": train_stats,
         }
 
     def _step_ctx(d) -> AggContext:  # murmura: traced
@@ -993,6 +1152,7 @@ def build_round_program(
         metrics.update({f"agg_{k}": v for k, v in compress_stats.items()})
         metrics.update({f"agg_{k}": v for k, v in stale_stats.items()})
         metrics.update({f"agg_{k}": v for k, v in attack_round_stats.items()})
+        metrics.update({f"agg_{k}": v for k, v in prod["train_stats"].items()})
         return params, agg_state, metrics
 
     # Reserved agg_state keys a pipelined aggregation must never hand to
@@ -1115,12 +1275,41 @@ def build_round_program(
         metrics.update(
             {f"agg_{k}": v for k, v in prod["stale_stats"].items()}
         )
+        metrics.update({f"agg_{k}": v for k, v in prod["train_stats"].items()})
         # 0.0 on the warm-up round: this round's agg_* stats describe
         # the invalid placeholder aggregation, not a real exchange.
         metrics["agg_pipe_valid"] = valid
         return params, agg_state, metrics
 
+    def _round_body_by_leaf(params, agg_state, key, adj, compromised, alive, round_idx, d):  # murmura: traced
+        """The round of a large state under a rule that can take it leaf by
+        leaf (``by_leaf`` above): local training, then the rule on each
+        stacked leaf as it lies, [N, ...] (no reshape: on a TPU that is a
+        relayout of the leaf).  What it gives is
+        ``_round_body``'s state; what it never builds is the [N, P] row
+        and the copies of it the exchange keeps side by side."""
+        train_key, _ = jax.random.split(key)
+        params, train_stats = train_nodes(
+            params, d, 1.0 - compromised, train_key, round_idx
+        )
+        step_ctx, agg_stats = _step_ctx(d), {}
+
+        def mixed(leaf):
+            with jax.named_scope("murmura.aggregate"):
+                new, _, stats = agg.aggregate(
+                    leaf, leaf, adj, round_idx, {}, step_ctx
+                )
+            agg_stats.update(stats)
+            return new
+
+        params = jax.tree_util.tree_map(mixed, params)
+        metrics = {f"agg_{k}": v for k, v in agg_stats.items()}
+        metrics.update({f"agg_{k}": v for k, v in train_stats.items()})
+        return params, agg_state, metrics
+
     body = _round_body_pipelined if pipeline else _round_body
+    if by_leaf:
+        body = _round_body_by_leaf
     if faults is None:
         def train_round(params, agg_state, key, adj, compromised, round_idx, d):  # murmura: traced
             return body(

@@ -26,7 +26,7 @@ class FederatedArrays:
 
     Attributes:
         x: [N, S, ...] padded features.
-        y: [N, S] padded int labels.
+        y: [N, S] padded int labels ([N, S, T] with one target a position).
         mask: [N, S] validity mask (1.0 = real sample, 0.0 = padding).
         num_samples: [N] count of real samples per node.
         x_test / y_test / mask_test: optional separate held-out arrays; when
@@ -154,7 +154,8 @@ def stack_partitions(
         counts = np.array([len(p) for p in parts], dtype=np.int32)
         cap = max(1, int(counts.max()))
         fx = np.zeros((n_nodes, cap) + xs.shape[1:], dtype=xs.dtype)
-        fy = np.zeros((n_nodes, cap), dtype=np.int32)
+        # One label a sample, or one target a position ([.., T]).
+        fy = np.zeros((n_nodes, cap) + ys.shape[1:], dtype=np.int32)
         fm = np.zeros((n_nodes, cap), dtype=np.float32)
         for i, p in enumerate(parts):
             if p:

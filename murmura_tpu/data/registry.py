@@ -75,8 +75,10 @@ def build_federated_data(
             seq_len=int(params.get("seq_len", 80)),
             vocab_size=int(params.get("vocab_size", 81)),
             seed=seed,
+            targets=str(params.get("targets", "last")),
         )
-        parts = _partition(y, num_nodes, params, seed)
+        # One target a position: a sequence's last target is its label.
+        parts = _partition(y.reshape(len(y), -1)[:, -1], num_nodes, params, seed)
         parts, test_parts = _with_holdout(parts, params, seed)
         return stack_partitions(
             x, y, parts, max_samples=max_samples,

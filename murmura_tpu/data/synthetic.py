@@ -60,13 +60,21 @@ def make_synthetic_sequences(
     seq_len: int = 80,
     vocab_size: int = 81,
     seed: int = 0,
+    targets: str = "last",
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Synthetic next-char prediction data for the Shakespeare-style LSTM.
 
     Sequences follow a learnable periodic pattern with noise; the target is
     the next token (LEAF Shakespeare task shape: seq_len 80, vocab ~81 —
     reference: leaf/models/shakespeare/stacked_lstm.py:19-27).
+
+    ``targets``: ``"last"`` (the default) gives the one id after the
+    sequence, ``y`` [num_samples]; ``"next"`` gives one target a position,
+    the id that follows each, ``y`` [num_samples, seq_len] (a decoder's
+    next-token loss, models/decoder.py).
     """
+    if targets not in ("last", "next"):
+        raise ValueError(f"targets {targets!r} is not 'last' or 'next'")
     rng = np.random.default_rng(seed)
     starts = rng.integers(0, vocab_size, size=num_samples)
     steps = rng.integers(1, 4, size=num_samples)
@@ -74,4 +82,5 @@ def make_synthetic_sequences(
     seqs = (starts[:, None] + steps[:, None] * t[None, :]) % vocab_size
     noise = rng.random(size=seqs.shape) < 0.05
     seqs = np.where(noise, rng.integers(0, vocab_size, size=seqs.shape), seqs)
-    return seqs[:, :-1].astype(np.int32), seqs[:, -1].astype(np.int32)
+    y = seqs[:, 1:] if targets == "next" else seqs[:, -1]
+    return seqs[:, :-1].astype(np.int32), y.astype(np.int32)

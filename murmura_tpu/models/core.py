@@ -47,6 +47,25 @@ class Model:
             layers batch badly under ``vmap(apply)`` (convolutions: see
             ``conv2d_folded``); ``None`` where ``vmap(apply)`` is the
             stacked forward.  Nodes share nothing in it.
+        apply_train: (params, x, key) -> (outputs, auxiliary), the training
+            forward of a model whose training rule is more than
+            ``p - lr g`` (models/decoder.py): outputs [B, T, V] with one
+            target a position, ``auxiliary["loss"]`` [B], a sample's part
+            of the loss that is the model's own, weighted already (a
+            router's balance loss), and ``auxiliary["step"]``, counts with
+            the batch's axis first.  The round then trains and evaluates
+            one node after another (core/rounds.py
+            ``local_training_by_node``): such a model's products are wide
+            already, and a node axis would multiply what is live.  ``None``
+            (every other model): ``apply`` under ``vmap``.
+        after_step: (params, counts) -> params, what the training rule
+            moves after each SGD step a node takes, from that step's
+            counts summed over the samples the batch's mask keeps (a state
+            that takes no gradient).  Read only with ``apply_train``.
+        step_metrics: (params, counts) -> {name: scalar}, a node's counters
+            of a round, from the counts summed over the steps it took and
+            its trained state; they ride the round's metrics as
+            ``agg_<name>``.  Read only with ``apply_train``.
     """
 
     name: str
@@ -57,6 +76,9 @@ class Model:
     num_classes: int = 0
     meta: Dict[str, Any] = field(default_factory=dict)
     apply_stacked: Optional[Callable] = None
+    apply_train: Optional[Callable] = None
+    after_step: Optional[Callable] = None
+    step_metrics: Optional[Callable] = None
 
 
 # ---------------------------------------------------------------------------

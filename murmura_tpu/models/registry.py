@@ -9,6 +9,7 @@ from typing import Any, Dict
 
 from murmura_tpu.models.cnn import FEMNIST_VARIANTS, make_celeba_cnn, make_femnist_cnn
 from murmura_tpu.models.core import Model
+from murmura_tpu.models.decoder import make_deepseek_v3
 from murmura_tpu.models.lstm import make_char_lstm
 from murmura_tpu.models.mlp import make_mlp, make_wearable_mlp
 
@@ -32,6 +33,11 @@ def build_model(factory: str, params: Dict[str, Any]) -> Model:
       FEMNIST CNN family (variant in tiny/small/baseline/large/xlarge).
     - ``examples.leaf.LEAFCelebAModel`` / ``leaf.celeba`` — CelebA CNN.
     - ``leaf.shakespeare`` — char-LSTM.
+    - ``decoder.deepseek_v3`` — a DeepSeek-V3-family decoder (latent
+      attention, routed and shared experts, next-token output); params
+      are the published ``config.json``'s own keys plus ``seq_len``,
+      ``ep_size``/``ep_rank`` (the experts held here) and the training
+      rule's ``aux_loss_alpha``/``bias_update_speed`` (models/decoder.py).
     - ``examples.wearables.<uci_har|pamap2|ppg_dalia>`` /
       ``wearables.<...>`` — evidential wearable MLPs.
     """
@@ -49,6 +55,9 @@ def build_model(factory: str, params: Dict[str, Any]) -> Model:
             evidential=evidential,
             compute_dtype=compute_dtype,
         )
+
+    if f == "decoder.deepseek_v3":
+        return make_deepseek_v3(**params, compute_dtype=compute_dtype)
 
     lowered = f.lower()
     if "femnist" in lowered:

@@ -44,6 +44,28 @@ def masked_cross_entropy(
     return loss, acc
 
 
+def masked_next_token_cross_entropy(
+    logits: jnp.ndarray, targets: jnp.ndarray, mask: jnp.ndarray
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Mean CE loss and accuracy with one target a position.
+
+    A sample's loss is the mean negative log-likelihood over its positions
+    (its accuracy likewise); the batch's, the mean over valid samples.
+
+    Args:
+        logits: [B, T, V] unnormalized scores.
+        targets: [B, T] int ids, the one that follows each position.
+        mask: [B] sample validity (0/1).
+
+    Returns:
+        (mean_loss, accuracy) scalars.
+    """
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    hit = (jnp.argmax(logits, -1) == targets).astype(jnp.float32)
+    return _safe_mean(nll.mean(axis=-1), mask), _safe_mean(hit.mean(axis=-1), mask)
+
+
 def uncertainty_metrics(alpha: jnp.ndarray) -> Dict[str, jnp.ndarray]:
     """Dirichlet uncertainty decomposition (reference: wearables/models.py:49-86).
 

@@ -52,9 +52,9 @@ class Model:
             ``p - lr g`` (models/decoder.py): outputs [B, T, V] with one
             target a position, ``auxiliary["loss"]`` [B], a sample's part
             of the loss that is the model's own, weighted already (a
-            router's balance loss), and ``auxiliary["step"]``, counts with
-            the batch's axis first.  The round then trains and evaluates
-            one node after another (core/rounds.py
+            router's balance loss), and ``auxiliary["step"]``, a tree of
+            counts with the batch's axis first.  The round then trains
+            and evaluates one node after another (core/rounds.py
             ``local_training_by_node``): such a model's products are wide
             already, and a node axis would multiply what is live.  ``None``
             (every other model): ``apply`` under ``vmap``.

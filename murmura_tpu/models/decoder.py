@@ -45,7 +45,15 @@ norms, the softmax, the router and the residual stream are float32.
   the routing only over that floor (with a router far from even, as at
   drawn weights on Zipf-distributed ids, a layer meets 8 to 21 tiles
   where an even one meets 8, and a round's time would differ by 2 % from
-  one draw of the weights to the next).
+  one draw of the weights to the next).  The gathers and elementwise
+  passes around the products do run over the whole buffer, so the buffer
+  takes, a layer and a step, the shortest of a ladder of static sizes
+  that holds the groups as they were routed: the floor, doubled up to
+  the size with a row for every pair and every group's padding, which is
+  always the last step (at Moonlight's share 8,192, 16,384 and 28,672
+  rows where 2,700 to 3,400 pairs are held).  The step is chosen on the
+  device (``lax.switch``), a pair's row is the same in every one, and
+  the last holds any routing: that is why no pair is dropped.
 - *Training rule* (``Model.apply_train``, ``after_step``).  The loss gains
   ``aux_loss_alpha`` times DeepSeek-V3's sequence-wise balance loss
   (``seq_aux``): a sequence's ``sum_e f_e P_e``, ``f_e = n / (k T)`` times
@@ -72,6 +80,7 @@ SwiGLU), ``murmura.head`` (lookup, last norm, logits).
 """
 
 import math
+from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
@@ -188,6 +197,37 @@ def _take_bwd(saved, g):
 
 
 _take.defvjp(_take_fwd, _take_bwd)
+
+
+def _switched(branches, index, ints, floats):
+    """``branches[index](ints, floats)`` by ``lax.switch`` on the device,
+    differentiable in ``floats``, for branches that give the same result
+    by the same sums at static sizes of their own.  The backward pass is a
+    ``lax.switch`` too, each branch ``jax.vjp`` of its own forward, and its
+    residuals are the arguments: autodiff through a ``lax.switch`` would
+    give every branch a slot for every other branch's residuals and fill
+    it with zeros, so that the smallest wrote arrays of the largest's
+    shapes.  One branch: that branch, and plain autodiff."""
+    if len(branches) == 1:
+        return branches[0](ints, floats)
+
+    @jax.custom_vjp
+    def run(index, ints, floats):
+        return jax.lax.switch(index, branches, ints, floats)
+
+    def forward(index, ints, floats):
+        return run(index, ints, floats), (index, ints, floats)
+
+    def backward(saved, g):
+        index, ints, floats = saved
+        back = [
+            lambda ints, floats, g, f=f: jax.vjp(partial(f, ints), floats)[1](g)[0]
+            for f in branches
+        ]
+        return None, None, jax.lax.switch(index, back, ints, floats, g)
+
+    run.defvjp(forward, backward)
+    return run(index, ints, floats)
 
 
 def make_deepseek_v3(
@@ -354,8 +394,48 @@ def make_deepseek_v3(
         balance = (counts * (n_routed_experts / (top_k * x.shape[0])) * share).sum()
         return chosen, weights, counts, balance
 
+    def ladder(t):
+        """The static sizes the pairs' buffer may take for a sequence of
+        ``t`` positions, shortest first: the floor under the grouped
+        products, doubled up to ``rows_in_all`` (a row for every pair and
+        the padding of every group), which is always the last.  Without a
+        floor the buffer has the one size."""
+        pairs = t * top_k
+        rows_in_all = -(-(pairs + held * (GROUP_ALIGN - 1)) // GROUP_ALIGN) * GROUP_ALIGN
+        if not GROUP_FLOOR_SHARES:
+            return 0, [rows_in_all]
+        floor_rows = GROUP_FLOOR_SHARES * pairs * held / n_routed_experts
+        floor_rows += held * GROUP_ALIGN / 2
+        floor_rows = min(rows_in_all, -(-int(floor_rows) // GROUP_ALIGN) * GROUP_ALIGN)
+        sizes, size = [], floor_rows
+        while size < rows_in_all:
+            sizes.append(size)
+            size *= 2
+        return floor_rows, sizes + [rows_in_all]
+
+    def experts_at(buffer_rows, ints, floats):
+        """``experts`` through a buffer of ``buffer_rows`` rows that holds
+        every group as routed: ``row_of`` [pairs] a held pair's row."""
+        (row_of, mine, sizes), (p, x, weights) = ints, floats
+        t, pairs = x.shape[0], x.shape[0] * top_k
+        row_of = jnp.where(mine, row_of, buffer_rows)
+        pair_of = jnp.zeros((buffer_rows,), jnp.int32).at[row_of].set(
+            jnp.arange(pairs, dtype=jnp.int32), mode="drop"
+        )
+        filled = jnp.zeros((buffer_rows,), bool).at[row_of].set(True, mode="drop")
+        row_of = jnp.minimum(row_of, buffer_rows - 1)
+        rows = _take(jnp.repeat(x, top_k, axis=0), pair_of, filled, row_of, mine)
+        grouped = lambda a, w: _grouped(a, w, sizes, cd)
+        inner = jax.nn.silu(grouped(rows, p["gate"])) * grouped(rows, p["up"])
+        # What a product leaves behind the last group, forward or backward,
+        # is not defined: only rows that hold a pair are ever taken back
+        # (``_take``: a where, never a product with 0).
+        y = _take(grouped(inner, p["down"]), row_of, mine, pair_of, filled)
+        return (y.reshape(t, top_k, -1) * weights[..., None]).sum(axis=1)
+
     def experts(p, x, chosen, weights):
-        """The held experts' part of the layer's result for one sequence.
+        """The held experts' part of the layer's result for one sequence,
+        and the index of the ladder's step its buffer took.
 
         Every (position, chosen expert) pair whose expert is held gets a
         row of a buffer in which expert g's rows start at a multiple of
@@ -366,10 +446,11 @@ def make_deepseek_v3(
         rows behind the last group are never multiplied.  The last group
         is lengthened by rows of zeros up to the floor
         (``GROUP_FLOOR_SHARES``), under which the products meet the same
-        tiles whatever the routing.  The buffer has a row for every pair
-        and the padding of every group, so no pair is dropped at any
-        imbalance."""
-        t, pairs = x.shape[0], x.shape[0] * top_k
+        tiles whatever the routing.  The buffer is the shortest of
+        ``ladder`` that holds the groups as routed, chosen on the device;
+        the last has a row for every pair and the padding of every group,
+        so no pair is dropped at any imbalance.  A pair's row does not
+        depend on the buffer's size, so neither does the result."""
         local = chosen.reshape(-1) - first_held  # a pair's expert, from the first held
         mine = (local >= 0) & (local < held)
         one_hot = (local[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
@@ -378,26 +459,14 @@ def make_deepseek_v3(
         )[:, 0] - 1
         sizes = -(-one_hot.sum(axis=0) // GROUP_ALIGN) * GROUP_ALIGN
         starts = jnp.cumsum(sizes) - sizes
-        rows_in_all = -(-(pairs + held * (GROUP_ALIGN - 1)) // GROUP_ALIGN) * GROUP_ALIGN
-        if GROUP_FLOOR_SHARES:  # rows of zeros behind the last expert's own
-            even = pairs * held / n_routed_experts
-            floor_rows = GROUP_FLOOR_SHARES * even + held * GROUP_ALIGN / 2
-            floor_rows = min(rows_in_all, -(-int(floor_rows) // GROUP_ALIGN) * GROUP_ALIGN)
-            sizes = sizes.at[held - 1].add(jnp.maximum(floor_rows - sizes.sum(), 0))
-        row_of = jnp.where(mine, starts[jnp.clip(local, 0, held - 1)] + rank, rows_in_all)
-        pair_of = jnp.zeros((rows_in_all,), jnp.int32).at[row_of].set(
-            jnp.arange(pairs, dtype=jnp.int32), mode="drop"
-        )
-        filled = jnp.zeros((rows_in_all,), bool).at[row_of].set(True, mode="drop")
-        row_of = jnp.minimum(row_of, rows_in_all - 1)
-        rows = _take(jnp.repeat(x, top_k, axis=0), pair_of, filled, row_of, mine)
-        grouped = lambda a, w: _grouped(a, w, sizes, cd)
-        inner = jax.nn.silu(grouped(rows, p["gate"])) * grouped(rows, p["up"])
-        # What a product leaves behind the last group, forward or backward,
-        # is not defined: only rows that hold a pair are ever taken back
-        # (``_take``: a where, never a product with 0).
-        y = _take(grouped(inner, p["down"]), row_of, mine, pair_of, filled)
-        return (y.reshape(t, top_k, -1) * weights[..., None]).sum(axis=1)
+        needed = sizes.sum()
+        floor_rows, steps = ladder(x.shape[0])
+        # Rows of zeros behind the last expert's own, up to the floor.
+        sizes = sizes.at[held - 1].add(jnp.maximum(floor_rows - needed, 0))
+        row_of = starts[jnp.clip(local, 0, held - 1)] + rank
+        step = (needed > jnp.asarray(steps[:-1], jnp.int32)).sum()
+        branches = [partial(experts_at, n) for n in steps]
+        return _switched(branches, step, (row_of, mine, sizes), (p, x, weights)), step
 
     def dense_block(h, p):
         with jax.named_scope("murmura.attention"):
@@ -414,12 +483,13 @@ def make_deepseek_v3(
         with jax.named_scope("murmura.ffn"):
             h = h + swiglu(p["shared"], x, cd)
         with jax.named_scope("murmura.experts"):
-            h = h + experts(p["experts"], x, chosen, weights)
-        return h, (counts, balance)
+            routed, step = experts(p["experts"], x, chosen, weights)
+        return h + routed, (counts, balance, step)
 
     def sequence(params, ids):
-        """logits [T, V], the choice's counts [moe layers, experts], the
-        balance loss summed over the expert layers."""
+        """logits [T, V], the choice's counts [moe layers, experts], which
+        step of the buffer's ladder each expert layer took [moe layers,
+        steps] (one-hot), the balance loss summed over the expert layers."""
         with jax.named_scope("murmura.head"):
             h = params["embed"][ids].astype(jnp.float32)
         if dense_layers:
@@ -427,38 +497,44 @@ def make_deepseek_v3(
                 lambda h, p: (jax.checkpoint(dense_block)(h, p), None),
                 h, params["dense_layers"],
             )
+        steps = len(ladder(ids.shape[0])[1])
         counts = jnp.zeros((0, n_routed_experts), jnp.float32)
+        took = jnp.zeros((0, steps), jnp.float32)
         balance = jnp.zeros((), jnp.float32)
         if moe_layers:
-            h, (counts, per_layer) = jax.lax.scan(
+            h, (counts, per_layer, step) = jax.lax.scan(
                 jax.checkpoint(moe_block), h, params["moe_layers"]
             )
+            took = jax.nn.one_hot(step, steps, dtype=jnp.float32)
             balance = per_layer.sum()
         with jax.named_scope("murmura.head"):
             logits = _einsum(
                 "th,hv->tv", rms_norm(h, params["final_norm"], rms_norm_eps),
                 params["head"], cd,
             )
-        return logits, counts, balance
+        return logits, {"counts": counts, "ladder": took}, balance
 
     def apply_train(params, x, key=None):
         """``(logits [B, T, V], auxiliary)``: ``"loss"`` [B], a sample's
         weighted balance loss, which the round adds to its likelihood, and
-        ``"step"`` [B, moe layers, experts], the counts ``after_step`` takes
-        summed over the samples the batch's mask keeps."""
+        ``"step"``, what ``after_step`` and ``step_metrics`` take summed
+        over the samples the batch's mask keeps: ``"counts"`` [B, moe
+        layers, experts] of the choice and ``"ladder"`` [B, moe layers,
+        steps], one-hot, the step of the buffer's ladder a layer took."""
         # One sequence after another: a sequence's products are as wide as
         # the chip wants them, and a batch axis would multiply what is live.
-        logits, counts, balance = jax.lax.map(lambda ids: sequence(params, ids), x)
-        return logits, {"loss": aux_loss_alpha * balance, "step": counts}
+        logits, step, balance = jax.lax.map(lambda ids: sequence(params, ids), x)
+        return logits, {"loss": aux_loss_alpha * balance, "step": step}
 
     def apply(params, x, key=None, train=False):
         return apply_train(params, x, key)[0]
 
-    def after_step(params, counts):
+    def after_step(params, step):
         """The selection bias steps towards an even load: down for an
         expert chosen more often than the mean, up for one chosen less."""
         if not moe_layers:
             return params
+        counts = step["counts"]
         with jax.named_scope("murmura.router"):
             router = params["moe_layers"]["router"]
             step = bias_update_speed * jnp.sign(
@@ -468,11 +544,12 @@ def make_deepseek_v3(
         layers = {**params["moe_layers"], "router": {**router, "bias": moved}}
         return {**params, "moe_layers": layers}
 
-    def step_metrics(params, counts) -> Dict[str, Any]:
+    def step_metrics(params, step) -> Dict[str, Any]:
         """The router's counters of one node (docs/OBSERVABILITY.md), from
         the counts of the steps it took this round and its trained state."""
         if not moe_layers:
             return {}
+        counts, took = step["counts"], step["ladder"]
         total = jnp.maximum(counts.sum(), 1.0)
         mean = jnp.maximum(counts.mean(axis=-1), 1e-30)
         bias = params["moe_layers"]["router"]["bias"].astype(jnp.float32)
@@ -480,6 +557,7 @@ def make_deepseek_v3(
             "moe.load_max_over_mean": (counts.max(axis=-1) / mean).max(),
             "moe.held_share": counts[:, first_held:first_held + held].sum() / total,
             "moe.bias_abs_max": jnp.abs(bias).max(),
+            "moe.rows_first_step_share": took[:, 0].sum() / jnp.maximum(took.sum(), 1.0),
         }
 
     return Model(
@@ -494,7 +572,8 @@ def make_deepseek_v3(
             "layers": num_hidden_layers, "experts_held": (first_held, held),
             # One expert layer's parts, for the test that ties a share to
             # the model: route(router, x), experts(held experts, x, chosen,
-            # weights) -> this share's routed part of the layer's result.
+            # weights) -> this share's routed part of the layer's result
+            # and the step of the buffer's ladder it took.
             "route": route, "experts": experts,
         },
         apply_train=apply_train,

@@ -342,10 +342,13 @@ def test_decoder_expert_layer_step_compiles_and_fits(one_chip):
     a 1,024-row vocabulary so that the layer is what is compiled), forward,
     recomputed and backward with bf16-resident parameters: the TPU compiler
     takes the grouped products as its own kernel (``tpu_custom_call``: the
-    CPU tests see a dense product with masks in their place), holds no
+    CPU tests see a dense product with masks in their place), once for each
+    size the pairs' buffer may take (8,192, 16,384 and 28,672 rows: the
+    dispatch is two conditionals, forward and backward), holds no
     ``[heads, T, T]`` score array, and one layer's step (its recomputed
-    activations: 2.94 GiB when this was written) fits in a quarter of the
-    chip."""
+    activations and the longest buffer's branch: 3,500,164,608 B of
+    temporaries, 3.26 GiB, when this was written; 3,473,661,440 with the
+    one size before) fits in a quarter of the chip."""
     import json
     from pathlib import Path
 
@@ -375,5 +378,8 @@ def test_decoder_expert_layer_step_compiles_and_fits(one_chip):
     compiled = jax.jit(gradients).lower(tree, ids, ids).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ragged-dot" in text
+    kernels = set(re.findall(r"= f32\[(\d+),\d+\]\S* custom-call\([^\n]*ragged-dot", text))
+    assert kernels == {"8192", "16384", "28672"}
+    assert len(re.findall(r" conditional\(", text)) == 2
     assert not re.search(r"f32\[16,4096,4096\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30

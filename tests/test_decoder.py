@@ -14,6 +14,7 @@ and eval steps they lowered to before this path existed.
 """
 
 import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,8 +90,8 @@ def test_apply_matches_the_reference():  # (a), the forward
         params, ids[:, :-1])
     assert logits.shape == (3, 16, 96)
     _close(logits, want)
-    np.testing.assert_array_equal(np.asarray(aux["step"]), np.asarray(want_aux["step"]))
-    assert np.asarray(aux["step"]).sum(-1).tolist() == [[32.0, 32.0]] * 3
+    np.testing.assert_array_equal(np.asarray(aux["step"]["counts"]), np.asarray(want_aux["step"]))
+    assert np.asarray(aux["step"]["counts"]).sum(-1).tolist() == [[32.0, 32.0]] * 3
     _close(aux["loss"].mean(), COEFFICIENT * want_aux["loss"], 1e-6)
     _close(jax.jit(model.apply)(params, ids[:, :-1]), want)
 
@@ -171,7 +172,7 @@ def test_the_shares_add_up_to_the_uncut_layer():  # (b)
         chosen, weights, mine, _ = model.meta["route"](layer["router"], x)
         np.testing.assert_array_equal(np.asarray(mine), np.asarray(counts))
         share = {k: v[4 * rank:4 * rank + 4] for k, v in layer["experts"].items()}
-        part = model.meta["experts"](share, x, chosen, weights)
+        part, _ = model.meta["experts"](share, x, chosen, weights)
         assert np.abs(np.asarray(part)).max() > 0
         total = total + part
         held.append(float(counts[4 * rank:4 * rank + 4].sum()))
@@ -192,7 +193,7 @@ def test_no_pair_is_dropped_when_all_tokens_choose_one_expert(expert):  # (c)
     ids = _ids(seed=9)
     logits, aux = jax.jit(model.apply_train)(params, ids[:, :-1])
     want, _ = reference.apply(params, ids[:, :-1], "float32")
-    assert np.asarray(aux["step"])[..., expert].tolist() == [[16.0, 16.0]] * 3
+    assert np.asarray(aux["step"]["counts"])[..., expert].tolist() == [[16.0, 16.0]] * 3
     _close(logits, want)
     grads = jax.jit(jax.grad(lambda p: model.apply_train(p, ids[:, :-1])[0].sum()))(params)
     assert all(np.isfinite(np.asarray(g)).all() for g in jax.tree_util.tree_leaves(grads))
@@ -207,7 +208,7 @@ def test_no_expert_held_is_chosen_and_nothing_breaks():
     params["moe_layers"]["router"]["bias"] = bias
     ids = _ids(seed=9)
     logits, aux = jax.jit(model.apply_train)(params, ids[:, :-1])
-    assert np.asarray(aux["step"])[..., :4].sum() == 0
+    assert np.asarray(aux["step"]["counts"])[..., :4].sum() == 0
     _close(logits, reference.apply(params, ids[:, :-1], "float32")[0])
     grads = jax.jit(jax.grad(lambda p: model.apply_train(p, ids[:, :-1])[0].sum()))(params)
     assert all(np.isfinite(np.asarray(g)).all() for g in jax.tree_util.tree_leaves(grads))
@@ -324,7 +325,8 @@ def test_a_job_trains_and_the_bias_is_averaged():  # (d)
     np.testing.assert_allclose(bias[0], bias[2], atol=1e-7)
     assert (np.abs(np.round(bias / 0.001) - bias / 0.001) > 0.1).any()
     for name, low, high in (("moe.load_max_over_mean", 1.0, 8.0),
-                            ("moe.held_share", 0.2, 0.8), ("moe.bias_abs_max", 1e-3, 0.1)):
+                            ("moe.held_share", 0.2, 0.8), ("moe.bias_abs_max", 1e-3, 0.1),
+                            ("moe.rows_first_step_share", 0.0, 1.0)):
         values = history[f"agg_{name}"]
         assert len(values) == 3 and all(low <= v <= high for v in values), (name, values)
 
@@ -544,3 +546,100 @@ def test_groups_aligned_to_any_tile_give_the_same_layer(align, shares, monkeypat
     for a, b in zip(jax.tree_util.tree_leaves(mine["moe_layers"]["experts"]),
                     jax.tree_util.tree_leaves(theirs["moe_layers"]["experts"])):
         _close(a, b, 1e-4)
+
+
+# --- the ladder of the pairs' buffer -----------------------------------------
+
+# Two of eight experts held, tiles of 4 rows, a floor of one even share: 32
+# pairs a sequence, a buffer of 12, 24 or 40 rows.
+LADDER = dict(TINY, ep_size=4)
+LADDER_DOC = dict(DOC, n_routed_experts=2)
+# The selection bias of the two expert layers' first six experts ([2, 6]), the
+# step each layer's buffer then takes and the share that fits the first.
+# No held expert chosen: nothing to hold.  Every position to expert 0 and none
+# to expert 1: 16 rows.  Every position to both: all 32 pairs held.
+AWAY, ONE, BOTH = [0, 0, 0, 0, 10, 10], [10, -10, 0, 0, 0, 0], [10, 10, 0, 0, 0, 0]
+STEPS = {
+    "first": ([AWAY, AWAY], [0, 0], 1.0),
+    "between": ([ONE, ONE], [1, 1], 0.0),
+    "last": ([BOTH, BOTH], [2, 2], 0.0),
+    "a_layer_each": ([AWAY, ONE], [0, 1], 0.5),
+}
+
+
+def _ladder_model(monkeypatch):
+    monkeypatch.setattr(decoder, "GROUP_ALIGN", 4)
+    monkeypatch.setattr(decoder, "GROUP_FLOOR_SHARES", 1)
+    return build_model("decoder.deepseek_v3", LADDER)
+
+
+@pytest.mark.parametrize("case", sorted(STEPS))
+def test_every_step_of_the_ladder_gives_the_reference(case, monkeypatch):
+    """The buffer takes the shortest of 12, 24 and 40 rows that holds the
+    groups; whichever it takes, no pair is dropped: logits and the experts'
+    gradients are the reference's, which computes every expert of every
+    position, and ``moe.rows_first_step_share`` says which was taken."""
+    bias, steps, share = STEPS[case]
+    model, params, ids = _ladder_model(monkeypatch), _weights(LADDER_DOC), _ids(seed=9)
+    router = params["moe_layers"]["router"]
+    router["bias"] = router["bias"].at[:, :6].set(jnp.asarray(bias, jnp.float32))
+    logits, aux = jax.jit(model.apply_train)(params, ids[:, :-1])
+    took = np.asarray(aux["step"]["ladder"])
+    assert took.shape == (3, 2, 3)
+    assert took.argmax(-1).tolist() == [steps] * 3 and took.sum(-1).tolist() == [[1.0, 1.0]] * 3
+    held = np.asarray(aux["step"]["counts"])[..., :2].sum(-1)
+    assert held.tolist() == [[[0.0, 16.0, 32.0][s] for s in steps]] * 3
+    _close(logits, reference.apply(params, ids[:, :-1], "float32")[0])
+    loss = lambda f: lambda p: (f(p)[0] ** 2).mean()
+    mine = jax.jit(jax.grad(loss(lambda p: model.apply_train(p, ids[:, :-1]))))(params)
+    theirs = jax.grad(loss(lambda p: reference.apply(p, ids[:, :-1], "float32")))(params)
+    for a, b in zip(jax.tree_util.tree_leaves(mine["moe_layers"]["experts"]),
+                    jax.tree_util.tree_leaves(theirs["moe_layers"]["experts"])):
+        _close(a, b, 1e-4)
+    summed = jax.tree_util.tree_map(lambda c: c.sum(0), aux["step"])
+    assert float(model.step_metrics(params, summed)["moe.rows_first_step_share"]) == share
+
+
+def _computations(text):
+    """An HLO module's text as {computation: its lines}."""
+    out, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return {k: "\n".join(v) for k, v in out.items()}
+
+
+def _reached(computations, name, seen):
+    """``name`` and every computation it calls, however deep."""
+    if name not in seen and name in computations:
+        seen.add(name)
+        for callee in re.findall(r"%([\w.\-]+)", computations[name]):
+            _reached(computations, callee, seen)
+    return seen
+
+
+def test_the_compiled_gradient_switches_twice_and_keeps_each_size_apart(monkeypatch):
+    """The gradient of the scanned, recomputed expert layers: the dispatch
+    is one conditional in the forward scan and one in the backward (the
+    recomputed forward's is dead: its result is the block's last term), and
+    a branch holds arrays of its own size alone: the 12-row branches none
+    of 24 or 40 rows (no zero-filled residual of a longer step)."""
+    model = _ladder_model(monkeypatch)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    ids = jax.ShapeDtypeStruct((1, 16), jnp.int32)
+    gradient = jax.jit(jax.grad(lambda p, x: (model.apply_train(p, x)[0] ** 2).mean()))
+    text = gradient.lower(params, ids).compile().as_text()
+    switches = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}", text)
+    assert len(switches) == 2
+    computations = _computations(text)
+    for branches in switches:
+        names = [n.strip().lstrip("%") for n in branches.split(",")]
+        assert len(names) == 3
+        for name, own in zip(names, (12, 24, 40)):
+            body = "\n".join(computations[c] for c in _reached(computations, name, set()))
+            rows = {int(d) for d in re.findall(r"\w+\[(\d+)[,\]]", body)}
+            assert rows & {12, 24, 40} == {own}, (name, sorted(rows))

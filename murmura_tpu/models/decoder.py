@@ -53,7 +53,10 @@ norms, the softmax, the router and the residual stream are float32.
   always the last step (at Moonlight's share 8,192, 16,384 and 28,672
   rows where 2,700 to 3,400 pairs are held).  The step is chosen on the
   device (``lax.switch``), a pair's row is the same in every one, and
-  the last holds any routing: that is why no pair is dropped.
+  the last holds any routing: that is why no pair is dropped.  The
+  dispatch (``ladder``, ``experts``), the bias step and the router's
+  counters are this module's own functions: ``models/zaya.py`` runs them
+  too, at one pair a position.
 - *Training rule* (``Model.apply_train``, ``after_step``).  The loss gains
   ``aux_loss_alpha`` times DeepSeek-V3's sequence-wise balance loss
   (``seq_aux``): a sequence's ``sum_e f_e P_e``, ``f_e = n / (k T)`` times
@@ -230,6 +233,113 @@ def _switched(branches, index, ints, floats):
     return run(index, ints, floats)
 
 
+# ---- the routed experts' dispatch, shared by every decoder with a router --
+#
+# A layer's share of the experts: ``held`` experts of ``n_routed_experts``
+# from ``first_held`` on, ``top_k`` chosen a position, products in the
+# compute ``dtype``.
+
+
+def ladder(t, top_k, held, n_routed_experts):
+    """The static sizes the pairs' buffer may take for a sequence of ``t``
+    positions, shortest first: the floor under the grouped products,
+    doubled up to ``rows_in_all`` (a row for every pair and the padding of
+    every group), which is always the last.  Without a floor the buffer has
+    the one size."""
+    pairs = t * top_k
+    rows_in_all = -(-(pairs + held * (GROUP_ALIGN - 1)) // GROUP_ALIGN) * GROUP_ALIGN
+    if not GROUP_FLOOR_SHARES:
+        return 0, [rows_in_all]
+    floor_rows = GROUP_FLOOR_SHARES * pairs * held / n_routed_experts
+    floor_rows += held * GROUP_ALIGN / 2
+    floor_rows = min(rows_in_all, -(-int(floor_rows) // GROUP_ALIGN) * GROUP_ALIGN)
+    sizes, size = [], floor_rows
+    while size < rows_in_all:
+        sizes.append(size)
+        size *= 2
+    return floor_rows, sizes + [rows_in_all]
+
+
+def experts_at(buffer_rows, top_k, dtype, ints, floats):
+    """``experts`` through a buffer of ``buffer_rows`` rows that holds
+    every group as routed: ``row_of`` [pairs] a held pair's row."""
+    (row_of, mine, sizes), (p, x, weights) = ints, floats
+    t, pairs = x.shape[0], x.shape[0] * top_k
+    row_of = jnp.where(mine, row_of, buffer_rows)
+    pair_of = jnp.zeros((buffer_rows,), jnp.int32).at[row_of].set(
+        jnp.arange(pairs, dtype=jnp.int32), mode="drop"
+    )
+    filled = jnp.zeros((buffer_rows,), bool).at[row_of].set(True, mode="drop")
+    row_of = jnp.minimum(row_of, buffer_rows - 1)
+    rows = _take(jnp.repeat(x, top_k, axis=0), pair_of, filled, row_of, mine)
+    grouped = lambda a, w: _grouped(a, w, sizes, dtype)
+    inner = jax.nn.silu(grouped(rows, p["gate"])) * grouped(rows, p["up"])
+    # What a product leaves behind the last group, forward or backward,
+    # is not defined: only rows that hold a pair are ever taken back
+    # (``_take``: a where, never a product with 0).
+    y = _take(grouped(inner, p["down"]), row_of, mine, pair_of, filled)
+    return (y.reshape(t, top_k, -1) * weights[..., None]).sum(axis=1)
+
+
+def experts(p, x, chosen, weights, *, held, first_held, top_k, n_routed_experts,
+            dtype):
+    """The held experts' part of the layer's result for one sequence
+    (``chosen`` and ``weights`` [T, top_k]), and the index of the ladder's
+    step its buffer took.
+
+    Every (position, chosen expert) pair whose expert is held gets a row of
+    a buffer in which expert g's rows start at a multiple of
+    ``GROUP_ALIGN`` (the rows between a group's end and the next multiple
+    are exact zeros and belong to the group): the grouped product then
+    meets whole tiles, the same number of them whatever the routing as long
+    as no expert's share crosses a multiple, and rows behind the last group
+    are never multiplied.  The last group is lengthened by rows of zeros up
+    to the floor (``GROUP_FLOOR_SHARES``), under which the products meet
+    the same tiles whatever the routing.  The buffer is the shortest of
+    ``ladder`` that holds the groups as routed, chosen on the device; the
+    last has a row for every pair and the padding of every group, so no
+    pair is dropped at any imbalance.  A pair's row does not depend on the
+    buffer's size, so neither does the result."""
+    local = chosen.reshape(-1) - first_held  # a pair's expert, from the first held
+    mine = (local >= 0) & (local < held)
+    one_hot = (local[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
+    rank = jnp.take_along_axis(  # a pair's place among its expert's pairs
+        jnp.cumsum(one_hot, axis=0), jnp.clip(local, 0, held - 1)[:, None], axis=1
+    )[:, 0] - 1
+    sizes = -(-one_hot.sum(axis=0) // GROUP_ALIGN) * GROUP_ALIGN
+    starts = jnp.cumsum(sizes) - sizes
+    needed = sizes.sum()
+    floor_rows, steps = ladder(x.shape[0], top_k, held, n_routed_experts)
+    # Rows of zeros behind the last expert's own, up to the floor.
+    sizes = sizes.at[held - 1].add(jnp.maximum(floor_rows - needed, 0))
+    row_of = starts[jnp.clip(local, 0, held - 1)] + rank
+    step = (needed > jnp.asarray(steps[:-1], jnp.int32)).sum()
+    branches = [partial(experts_at, n, top_k, dtype) for n in steps]
+    return _switched(branches, step, (row_of, mine, sizes), (p, x, weights)), step
+
+
+def bias_step(bias, counts, speed):
+    """The selection bias steps towards an even load: down by ``speed`` for
+    an expert chosen more often than the mean of ``counts`` [..., experts],
+    up for one chosen less."""
+    step = speed * jnp.sign(counts.mean(axis=-1, keepdims=True) - counts)
+    return bias + step.astype(bias.dtype)
+
+
+def router_counters(counts, took, bias, first_held, held) -> Dict[str, Any]:
+    """A node's router counters (docs/OBSERVABILITY.md) from the counts of
+    its choice [layers, experts] and the ladder's steps taken [layers,
+    steps] over the steps of a round, and its selection bias."""
+    total = jnp.maximum(counts.sum(), 1.0)
+    mean = jnp.maximum(counts.mean(axis=-1), 1e-30)
+    return {
+        "moe.load_max_over_mean": (counts.max(axis=-1) / mean).max(),
+        "moe.held_share": counts[:, first_held:first_held + held].sum() / total,
+        "moe.bias_abs_max": jnp.abs(bias.astype(jnp.float32)).max(),
+        "moe.rows_first_step_share": took[:, 0].sum() / jnp.maximum(took.sum(), 1.0),
+    }
+
+
 def make_deepseek_v3(
     vocab_size: int,
     hidden_size: int,
@@ -299,6 +409,8 @@ def make_deepseek_v3(
     held = n_routed_experts // ep_size
     first_held = ep_rank * held
     top_k = num_experts_per_tok
+    dispatch = dict(held=held, first_held=first_held, top_k=top_k,
+                    n_routed_experts=n_routed_experts, dtype=cd)
     shared_width = n_shared_experts * moe_intermediate_size
     scale = 1.0 / math.sqrt(nope + rope)
 
@@ -394,80 +506,6 @@ def make_deepseek_v3(
         balance = (counts * (n_routed_experts / (top_k * x.shape[0])) * share).sum()
         return chosen, weights, counts, balance
 
-    def ladder(t):
-        """The static sizes the pairs' buffer may take for a sequence of
-        ``t`` positions, shortest first: the floor under the grouped
-        products, doubled up to ``rows_in_all`` (a row for every pair and
-        the padding of every group), which is always the last.  Without a
-        floor the buffer has the one size."""
-        pairs = t * top_k
-        rows_in_all = -(-(pairs + held * (GROUP_ALIGN - 1)) // GROUP_ALIGN) * GROUP_ALIGN
-        if not GROUP_FLOOR_SHARES:
-            return 0, [rows_in_all]
-        floor_rows = GROUP_FLOOR_SHARES * pairs * held / n_routed_experts
-        floor_rows += held * GROUP_ALIGN / 2
-        floor_rows = min(rows_in_all, -(-int(floor_rows) // GROUP_ALIGN) * GROUP_ALIGN)
-        sizes, size = [], floor_rows
-        while size < rows_in_all:
-            sizes.append(size)
-            size *= 2
-        return floor_rows, sizes + [rows_in_all]
-
-    def experts_at(buffer_rows, ints, floats):
-        """``experts`` through a buffer of ``buffer_rows`` rows that holds
-        every group as routed: ``row_of`` [pairs] a held pair's row."""
-        (row_of, mine, sizes), (p, x, weights) = ints, floats
-        t, pairs = x.shape[0], x.shape[0] * top_k
-        row_of = jnp.where(mine, row_of, buffer_rows)
-        pair_of = jnp.zeros((buffer_rows,), jnp.int32).at[row_of].set(
-            jnp.arange(pairs, dtype=jnp.int32), mode="drop"
-        )
-        filled = jnp.zeros((buffer_rows,), bool).at[row_of].set(True, mode="drop")
-        row_of = jnp.minimum(row_of, buffer_rows - 1)
-        rows = _take(jnp.repeat(x, top_k, axis=0), pair_of, filled, row_of, mine)
-        grouped = lambda a, w: _grouped(a, w, sizes, cd)
-        inner = jax.nn.silu(grouped(rows, p["gate"])) * grouped(rows, p["up"])
-        # What a product leaves behind the last group, forward or backward,
-        # is not defined: only rows that hold a pair are ever taken back
-        # (``_take``: a where, never a product with 0).
-        y = _take(grouped(inner, p["down"]), row_of, mine, pair_of, filled)
-        return (y.reshape(t, top_k, -1) * weights[..., None]).sum(axis=1)
-
-    def experts(p, x, chosen, weights):
-        """The held experts' part of the layer's result for one sequence,
-        and the index of the ladder's step its buffer took.
-
-        Every (position, chosen expert) pair whose expert is held gets a
-        row of a buffer in which expert g's rows start at a multiple of
-        ``GROUP_ALIGN`` (the rows between a group's end and the next
-        multiple are exact zeros and belong to the group): the grouped
-        product then meets whole tiles, the same number of them whatever
-        the routing as long as no expert's share crosses a multiple, and
-        rows behind the last group are never multiplied.  The last group
-        is lengthened by rows of zeros up to the floor
-        (``GROUP_FLOOR_SHARES``), under which the products meet the same
-        tiles whatever the routing.  The buffer is the shortest of
-        ``ladder`` that holds the groups as routed, chosen on the device;
-        the last has a row for every pair and the padding of every group,
-        so no pair is dropped at any imbalance.  A pair's row does not
-        depend on the buffer's size, so neither does the result."""
-        local = chosen.reshape(-1) - first_held  # a pair's expert, from the first held
-        mine = (local >= 0) & (local < held)
-        one_hot = (local[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
-        rank = jnp.take_along_axis(  # a pair's place among its expert's pairs
-            jnp.cumsum(one_hot, axis=0), jnp.clip(local, 0, held - 1)[:, None], axis=1
-        )[:, 0] - 1
-        sizes = -(-one_hot.sum(axis=0) // GROUP_ALIGN) * GROUP_ALIGN
-        starts = jnp.cumsum(sizes) - sizes
-        needed = sizes.sum()
-        floor_rows, steps = ladder(x.shape[0])
-        # Rows of zeros behind the last expert's own, up to the floor.
-        sizes = sizes.at[held - 1].add(jnp.maximum(floor_rows - needed, 0))
-        row_of = starts[jnp.clip(local, 0, held - 1)] + rank
-        step = (needed > jnp.asarray(steps[:-1], jnp.int32)).sum()
-        branches = [partial(experts_at, n) for n in steps]
-        return _switched(branches, step, (row_of, mine, sizes), (p, x, weights)), step
-
     def dense_block(h, p):
         with jax.named_scope("murmura.attention"):
             h = h + attention(p["attn"], rms_norm(h, p["attn_norm"], rms_norm_eps))
@@ -483,7 +521,7 @@ def make_deepseek_v3(
         with jax.named_scope("murmura.ffn"):
             h = h + swiglu(p["shared"], x, cd)
         with jax.named_scope("murmura.experts"):
-            routed, step = experts(p["experts"], x, chosen, weights)
+            routed, step = experts(p["experts"], x, chosen, weights, **dispatch)
         return h + routed, (counts, balance, step)
 
     def sequence(params, ids):
@@ -497,7 +535,7 @@ def make_deepseek_v3(
                 lambda h, p: (jax.checkpoint(dense_block)(h, p), None),
                 h, params["dense_layers"],
             )
-        steps = len(ladder(ids.shape[0])[1])
+        steps = len(ladder(ids.shape[0], top_k, held, n_routed_experts)[1])
         counts = jnp.zeros((0, n_routed_experts), jnp.float32)
         took = jnp.zeros((0, steps), jnp.float32)
         balance = jnp.zeros((), jnp.float32)
@@ -534,13 +572,9 @@ def make_deepseek_v3(
         expert chosen more often than the mean, up for one chosen less."""
         if not moe_layers:
             return params
-        counts = step["counts"]
         with jax.named_scope("murmura.router"):
             router = params["moe_layers"]["router"]
-            step = bias_update_speed * jnp.sign(
-                counts.mean(axis=-1, keepdims=True) - counts
-            )
-            moved = router["bias"] + step.astype(router["bias"].dtype)
+            moved = bias_step(router["bias"], step["counts"], bias_update_speed)
         layers = {**params["moe_layers"], "router": {**router, "bias": moved}}
         return {**params, "moe_layers": layers}
 
@@ -549,16 +583,8 @@ def make_deepseek_v3(
         the counts of the steps it took this round and its trained state."""
         if not moe_layers:
             return {}
-        counts, took = step["counts"], step["ladder"]
-        total = jnp.maximum(counts.sum(), 1.0)
-        mean = jnp.maximum(counts.mean(axis=-1), 1e-30)
-        bias = params["moe_layers"]["router"]["bias"].astype(jnp.float32)
-        return {
-            "moe.load_max_over_mean": (counts.max(axis=-1) / mean).max(),
-            "moe.held_share": counts[:, first_held:first_held + held].sum() / total,
-            "moe.bias_abs_max": jnp.abs(bias).max(),
-            "moe.rows_first_step_share": took[:, 0].sum() / jnp.maximum(took.sum(), 1.0),
-        }
+        return router_counters(step["counts"], step["ladder"],
+                               params["moe_layers"]["router"]["bias"], first_held, held)
 
     return Model(
         name=name,
@@ -574,7 +600,7 @@ def make_deepseek_v3(
             # the model: route(router, x), experts(held experts, x, chosen,
             # weights) -> this share's routed part of the layer's result
             # and the step of the buffer's ladder it took.
-            "route": route, "experts": experts,
+            "route": route, "experts": partial(experts, **dispatch),
         },
         apply_train=apply_train,
         after_step=after_step,

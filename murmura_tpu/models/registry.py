@@ -12,6 +12,7 @@ from murmura_tpu.models.core import Model
 from murmura_tpu.models.decoder import make_deepseek_v3
 from murmura_tpu.models.lstm import make_char_lstm
 from murmura_tpu.models.mlp import make_mlp, make_wearable_mlp
+from murmura_tpu.models.zaya import make_zaya1
 
 # Wearable dataset default dims (reference: murmura/examples/wearables/models.py:195-300:
 # UCI HAR 561/(256,128); PAMAP2 4000 = 100-window x 40 feats /(512,256,128);
@@ -38,6 +39,13 @@ def build_model(factory: str, params: Dict[str, Any]) -> Model:
       are the published ``config.json``'s own keys plus ``seq_len``,
       ``ep_size``/``ep_rank`` (the experts held here) and the training
       rule's ``aux_loss_alpha``/``bias_update_speed`` (models/decoder.py).
+    - ``decoder.zaya1`` — a ZAYA1 decoder (compressed convolutional
+      attention with grouped query heads, an MLP router over a state
+      averaged across depth choosing one expert, learned residual scales,
+      a tied head); params are the published ``config.json``'s own keys
+      (``rope_theta`` as its ``rope_parameters.hybrid`` gives it) plus
+      ``seq_len``, ``ep_size``/``ep_rank`` and ``bias_update_speed``
+      (models/zaya.py).
     - ``examples.wearables.<uci_har|pamap2|ppg_dalia>`` /
       ``wearables.<...>`` — evidential wearable MLPs.
     """
@@ -58,6 +66,9 @@ def build_model(factory: str, params: Dict[str, Any]) -> Model:
 
     if f == "decoder.deepseek_v3":
         return make_deepseek_v3(**params, compute_dtype=compute_dtype)
+
+    if f == "decoder.zaya1":
+        return make_zaya1(**params, compute_dtype=compute_dtype)
 
     lowered = f.lower()
     if "femnist" in lowered:

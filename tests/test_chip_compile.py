@@ -383,3 +383,56 @@ def test_decoder_expert_layer_step_compiles_and_fits(one_chip):
     assert len(re.findall(r" conditional\(", text)) == 2
     assert not re.search(r"f32\[16,4096,4096\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+
+# --- one ZAYA1 layer at its published widths (models/zaya.py) ---------------
+
+
+def test_zaya_layer_step_compiles_and_fits(one_chip):
+    """One layer of ``zaya1_8b_ep2`` (hidden 2048, compressed convolutional
+    attention of 8 query and 2 key/value heads of 128, a router 256 wide
+    over 16 experts, top-1, 8 held experts of 2048, 4,096 positions; a
+    1,024-row vocabulary so that the layer is what is compiled), forward,
+    recomputed and backward with bf16-resident parameters: the grouped
+    products are the TPU compiler's kernel at both sizes the pairs' buffer
+    may take at one pair a token (6,144 and 8,192 rows), the dispatch is three
+    conditionals (forward, recomputed forward, backward: the residual scale
+    on the experts' term needs their result in the backward pass, where the
+    decoder's plain sum does not), no ``[heads, T, T]`` score array exists,
+    and the layer's
+    step (1,037,348,864 B of temporaries, 0.97 GiB, when this was written)
+    fits in a tenth of the chip."""
+    import json
+    from pathlib import Path
+
+    from murmura_tpu.models.registry import build_model
+    from murmura_tpu.ops.losses import masked_next_token_cross_entropy
+
+    doc = json.loads((Path(__file__).resolve().parents[1]
+                      / "benchmark/configs/zaya1_8b_ep2.json").read_text())
+    params = dict(doc["model"]["params"], num_hidden_layers=1, vocab_size=1024,
+                  compute_dtype="bfloat16")
+    model = build_model(doc["model"]["factory"], params)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    tree = jax.tree_util.tree_map(lambda l: place(l.shape, jnp.bfloat16), shapes)
+    assert shapes["layers"]["experts"]["gate"].shape == (1, 8, 2048, 2048)
+    assert shapes["layers"]["cca"]["conv1"].shape == (1, 2, 10, 128, 128)
+    assert shapes["layers"]["router"]["w3"].shape == (1, 256, 16)
+
+    def gradients(p, x, y):
+        def loss(p):
+            logits, _ = model.apply_train(p, x, None)
+            return masked_next_token_cross_entropy(logits, y, jnp.ones((1,)))[0]
+
+        return jax.grad(loss)(p)
+
+    ids = place((1, 4096), jnp.int32)
+    compiled = jax.jit(gradients).lower(tree, ids, ids).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged-dot" in text
+    kernels = set(re.findall(r"= f32\[(\d+),\d+\]\S* custom-call\([^\n]*ragged-dot", text))
+    assert kernels == {"6144", "8192"}
+    assert len(re.findall(r" conditional\(", text)) == 3
+    assert not re.search(r"f32\[\d+,\d+,4096,4096\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6 * 2**30

@@ -18,11 +18,13 @@ norms, the softmax, the router and the residual stream are float32.
   turn by ``pos * theta^(-2i/rope)``), which is the published code's result
   (it de-interleaves, then rotates halves: the same pairs).
   ``s = (q_n . k_n + q_r . k_r) / sqrt(nope + rope)``, causal, softmax in
-  float32, ``out = (softmax(s) v) W_o``.  Queries go in blocks of
-  ``ATTENTION_BLOCK`` and a block meets the keys up to its own end, so no
-  ``[heads, T, T]`` array exists and the blocks above the diagonal are
-  never multiplied.  ``q_lora_rank`` is null in the configurations this
-  serves (a low-rank query projection is refused, not guessed).
+  float32, ``out = (softmax(s) v) W_o``: ``ops.attention.causal_attention``
+  of ``q = [q_n | q_r]`` over ``k = [k_n | k_r]`` (``k_r`` broadcast over
+  the heads), the scale applied to the float32 scores; on a TPU a flash
+  kernel, so no ``[heads, T, T]`` array exists and the blocks above the
+  diagonal are never multiplied.  ``q_lora_rank`` is null in the
+  configurations this serves (a low-rank query projection is refused, not
+  guessed).
 - *Expert layer.*  ``sc = sigmoid(x W_r)`` in float32 over **all**
   ``n_routed_experts``; chosen = top-k of ``sc + b`` (``b``: the selection
   bias, for the choice only); ``w = sc[chosen] / (sum sc[chosen] + 1e-20)
@@ -67,7 +69,9 @@ norms, the softmax, the router and the residual stream are float32.
   the training carry, is exchanged, averaged and checkpointed like every
   other.
 - *Memory.*  Each layer is recomputed in the backward pass
-  (``jax.checkpoint`` around the block), the expert layers are one scanned
+  (``jax.checkpoint`` around the block; attention's result and the
+  log-sum-exp of its scores are kept, so the backward pass runs no
+  attention forward again), the expert layers are one scanned
   stack, and the round trains and evaluates such a model one node at a
   time (core/rounds.py ``local_training_by_node``): its products are
   already ``[T, hidden]`` wide, and a node axis would only multiply what
@@ -90,8 +94,8 @@ import jax
 import jax.numpy as jnp
 
 from murmura_tpu.models.core import Model, resolve_dtype
+from murmura_tpu.ops.attention import KEEP_RESIDUALS, causal_attention
 
-ATTENTION_BLOCK = 512  # queries a block; a test's sequence is one block
 # Rows to which an expert's group is aligned: the tile of rows in which the
 # TPU compiler's grouped product works (read off its compiled metadata: 55
 # entries for 24,576 rows in 8 groups, 48 tiles + 7).
@@ -475,20 +479,12 @@ def make_deepseek_v3(
         k_r = rotate(kv[:, kv_lora_rank:], rope_theta)  # [T, rope], all heads'
         kn_v = _einsum("tc,cd->td", c, p["kv_b"], cd).reshape(t, heads, nope + vdim)
         k_n, v = kn_v[..., :nope], kn_v[..., nope:]
-        q_n, q_r = q[..., :nope], rotate(q[..., nope:], rope_theta)
-        block = min(ATTENTION_BLOCK, t)
-        out = []
-        for start in range(0, t, block):  # a block meets the keys up to its end
-            end = min(start + block, t)
-            s = _einsum("qhd,khd->hqk", q_n[start:end], k_n[:end], cd)
-            s = (s + _einsum("qhd,kd->hqk", q_r[start:end], k_r[:end], cd)) * scale
-            causal = (
-                jnp.arange(start, end)[:, None] >= jnp.arange(end)[None, :]
-            )
-            w = jax.nn.softmax(jnp.where(causal[None], s, -jnp.inf), axis=-1)
-            out.append(_einsum("hqk,khd->qhd", w, v[:end], cd))
-        o = jnp.concatenate(out, axis=0).reshape(t, heads * vdim)
-        return _einsum("td,dh->th", o, p["o"], cd)
+        q = jnp.concatenate([q[..., :nope], rotate(q[..., nope:], rope_theta)], axis=-1)
+        k = jnp.concatenate([k_n, jnp.broadcast_to(k_r[:, None], (t, heads, rope))], axis=-1)
+        heads_first = lambda a: a.transpose(1, 0, 2)
+        o = causal_attention(heads_first(q), heads_first(k), heads_first(v),
+                             jnp.full((heads,), scale, jnp.float32), cd)
+        return _einsum("td,dh->th", heads_first(o).reshape(t, heads * vdim), p["o"], cd)
 
     def route(p, x):
         """Scores, the choice and its weights over all experts; the counts
@@ -532,7 +528,7 @@ def make_deepseek_v3(
             h = params["embed"][ids].astype(jnp.float32)
         if dense_layers:
             h, _ = jax.lax.scan(
-                lambda h, p: (jax.checkpoint(dense_block)(h, p), None),
+                lambda h, p: (jax.checkpoint(dense_block, policy=KEEP_RESIDUALS)(h, p), None),
                 h, params["dense_layers"],
             )
         steps = len(ladder(ids.shape[0], top_k, held, n_routed_experts)[1])
@@ -541,7 +537,7 @@ def make_deepseek_v3(
         balance = jnp.zeros((), jnp.float32)
         if moe_layers:
             h, (counts, per_layer, step) = jax.lax.scan(
-                jax.checkpoint(moe_block), h, params["moe_layers"]
+                jax.checkpoint(moe_block, policy=KEEP_RESIDUALS), h, params["moe_layers"]
             )
             took = jax.nn.one_hot(step, steps, dtype=jnp.float32)
             balance = per_layer.sum()

@@ -36,8 +36,8 @@ channels, ``G = Hq / Hk``, ``g(h) = h // G``.
   channels, the same in ``q`` and ``k``).  ``s[h, t, t'] = tau_h q[t, h] .
   k[t', g(h)]`` for ``t' <= t`` with ``tau_h`` a learned temperature a
   query head, softmax in float32, ``o[t, h] = sum softmax(s) v[t', g(h)]``,
-  out ``o W_o``.  Queries go in blocks of ``decoder.ATTENTION_BLOCK`` that
-  meet the keys up to their own end.
+  out ``o W_o``: ``ops.attention.causal_attention``, the temperatures
+  applied to the float32 scores; on a TPU a flash kernel.
 - *Router.*  ``r_l = x W_down [T, router_hidden_size]``; the depth average
   ``r^_l = lam_l r^_(l-1) + (1 - lam_l) r_l``, ``lam_l = sigmoid(gamma_l)``
   (``gamma`` a learned scalar a layer, initial 0), ``r^ = r`` in the
@@ -53,7 +53,8 @@ channels, ``G = Hq / Hk``, ``g(h) = h // G``.
   every layer's ``b_e += bias_update_speed * sign(mean_e(count) -
   count_e)`` (``decoder.bias_step``).
 - *Memory.*  Layers are one scanned stack, each recomputed in the
-  backward pass; the carry is ``(h, r^)``.
+  backward pass but for attention's result and log-sum-exp, which are
+  kept; the carry is ``(h, r^)``.
 
 Labels (``jax.named_scope``; docs/OBSERVABILITY.md): ``murmura.cca`` (the
 whole attention sublayer), inside it ``murmura.mix`` (the two
@@ -68,11 +69,11 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from murmura_tpu.models import decoder
 from murmura_tpu.models.core import Model, resolve_dtype
 from murmura_tpu.models.decoder import (
     HIGHEST, _einsum, bias_step, experts, ladder, rms_norm, rotate, router_counters,
 )
+from murmura_tpu.ops.attention import KEEP_RESIDUALS, causal_attention
 
 
 def shifted(x, lag):
@@ -243,17 +244,11 @@ def make_zaya1(
         with jax.named_scope("murmura.mix"):
             q, k, v = mix(p, q0, k0, v)
         q, k = turned(l2_normalized(q)), turned(l2_normalized(k))
-        tau = p["temperature"].astype(jnp.float32).reshape(hk, group)[..., None, None]
-        block = min(decoder.ATTENTION_BLOCK, t)
-        out = []
-        for start in range(0, t, block):  # a block meets the keys up to its end
-            end = min(start + block, t)
-            s = _einsum("qgrd,kgd->grqk", q[start:end], k[:end], cd) * tau
-            causal = jnp.arange(start, end)[:, None] >= jnp.arange(end)[None, :]
-            w = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-            out.append(_einsum("grqk,kgd->qgrd", w, v[:end], cd))
-        o = jnp.concatenate(out, axis=0).reshape(t, hq * dh)
-        return _einsum("td,dh->th", o, p["o"], cd)
+        heads_first = lambda a: a.transpose(1, 0, 2)
+        # Query head h = g * G + r: the temperatures' order, and k's g.
+        o = causal_attention(heads_first(q.reshape(t, hq, dh)), heads_first(k),
+                             heads_first(v), p["temperature"], cd)
+        return _einsum("td,dh->th", heads_first(o).reshape(t, hq * dh), p["o"], cd)
 
     def route(p, x, carried, first):
         """The choice [T], its weight ``p_chosen`` [T], the counts of the
@@ -294,7 +289,7 @@ def make_zaya1(
             h = params["embed"][ids].astype(jnp.float32)
         carried = jnp.zeros((ids.shape[0], router_hidden_size), jnp.float32)
         (h, _), (counts, chosen_weight, step) = jax.lax.scan(
-            jax.checkpoint(layer), (h, carried),
+            jax.checkpoint(layer, policy=KEEP_RESIDUALS), (h, carried),
             (params["layers"], jnp.arange(layers) == 0),
         )
         steps = len(ladder(ids.shape[0], 1, held, num_experts)[1])

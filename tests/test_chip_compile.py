@@ -436,3 +436,61 @@ def test_zaya_layer_step_compiles_and_fits(one_chip):
     assert len(re.findall(r" conditional\(", text)) == 3
     assert not re.search(r"f32\[\d+,\d+,4096,4096\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.6 * 2**30
+
+
+# --- the decoders' causal attention (ops/attention.py) ----------------------
+
+
+@pytest.mark.parametrize("config,layer,label", [
+    ("moonlight_16b_a3b_ep8", {"num_hidden_layers": 1, "first_k_dense_replace": 1},
+     "murmura.attention"),
+    ("zaya1_8b_ep2", {"num_hidden_layers": 1}, "murmura.cca"),
+], ids=["moonlight", "zaya1"])
+def test_decoder_attention_is_the_flash_kernel_under_its_label(
+        one_chip, config, layer, label, monkeypatch):
+    """One layer of each decoder at its published widths (Moonlight's dense
+    layer: 16 heads of 192 against values of 128; ZAYA1's: 8 query and 2
+    key/value heads of 128), 4,096 positions, forward, recomputed and
+    backward with bf16-resident parameters, as the chip compiles it (the
+    backend is the TPU's there, and ``causal_attention`` takes its
+    kernels): the forward kernel once (the recomputed layer keeps its
+    result and log-sum-exp, ``KEEP_RESIDUALS``, and runs no attention
+    forward again), the backward kernel once, both under the sublayer's
+    label, the backward's in the transposed pass, so that the device time of
+    ``train_attention_ops_ms`` and ``train_cca_ops_ms`` still holds the
+    whole sublayer; and no float32 score block ``[heads, 512, <=4096]``
+    of the ``jnp`` path is left."""
+    import json
+    from pathlib import Path
+
+    from murmura_tpu.models.registry import build_model
+    from murmura_tpu.ops.losses import masked_next_token_cross_entropy
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    doc = json.loads((Path(__file__).resolve().parents[1]
+                      / f"benchmark/configs/{config}.json").read_text())
+    params = dict(doc["model"]["params"], vocab_size=1024, compute_dtype="bfloat16", **layer)
+    model = build_model(doc["model"]["factory"], params)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    tree = jax.tree_util.tree_map(lambda l: place(l.shape, jnp.bfloat16), shapes)
+
+    def gradients(p, x, y):
+        def loss(p):
+            logits, aux = model.apply_train(p, x, None)
+            return masked_next_token_cross_entropy(
+                logits, y, jnp.ones((1,)))[0] + aux["loss"].sum()
+
+        return jax.grad(loss)(p)
+
+    ids = place((1, 4096), jnp.int32)
+    text = jax.jit(gradients).lower(tree, ids, ids).compile().as_text()
+    kernels = sorted(
+        (m.group(1), m.group(2)) for m in re.finditer(
+            r"%(causal_attention_\w+?)\.\d+ = [^\n]*tpu_custom_call[^\n]*op_name=\"([^\"]*)\"",
+            text))
+    assert [name for name, _ in kernels] == [
+        "causal_attention_bwd", "causal_attention_fwd"], kernels
+    assert all(label in op_name for _, op_name in kernels), kernels
+    assert "transpose(" in kernels[0][1]
+    assert not re.search(r"f32\[[\d,]*,512,\d+\]", text)

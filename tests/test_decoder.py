@@ -32,6 +32,7 @@ from murmura_tpu.data.base import FederatedArrays
 from murmura_tpu.data.registry import build_federated_data
 from murmura_tpu.models import decoder
 from murmura_tpu.models.registry import build_model
+from murmura_tpu.ops import attention
 from murmura_tpu.ops.flatten import make_flatteners
 from murmura_tpu.ops.losses import masked_next_token_cross_entropy
 from murmura_tpu.utils.factories import build_network_from_config
@@ -251,7 +252,7 @@ def test_rotary_pairs_are_the_published_codes():
 def test_attention_in_blocks_is_attention(monkeypatch):
     model, params, ids = build_model("decoder.deepseek_v3", TINY), _weights(), _ids()
     whole = jax.jit(model.apply)(params, ids[:, :-1])
-    monkeypatch.setattr(decoder, "ATTENTION_BLOCK", 4)
+    monkeypatch.setattr(attention, "ATTENTION_BLOCK", 4)
     blocked = jax.jit(build_model("decoder.deepseek_v3", TINY).apply)(params, ids[:, :-1])
     _close(blocked, whole)
 

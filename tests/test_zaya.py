@@ -30,6 +30,7 @@ from murmura_tpu.core.rounds import build_round_program
 from murmura_tpu.data.base import FederatedArrays
 from murmura_tpu.models import decoder
 from murmura_tpu.models.registry import build_model
+from murmura_tpu.ops import attention
 from murmura_tpu.ops.flatten import make_flatteners
 from murmura_tpu.utils.factories import build_network_from_config
 
@@ -301,7 +302,7 @@ def test_bfloat16_products_follow_the_bfloat16_reference():
 def test_attention_in_blocks_is_attention(monkeypatch):
     model, params, ids = build_model("decoder.zaya1", TINY), _weights(), _ids()
     whole = jax.jit(model.apply)(params, ids[:, :-1])
-    monkeypatch.setattr(decoder, "ATTENTION_BLOCK", 4)
+    monkeypatch.setattr(attention, "ATTENTION_BLOCK", 4)
     blocked = jax.jit(build_model("decoder.zaya1", TINY).apply)(params, ids[:, :-1])
     _close(blocked, whole)
 

@@ -16,7 +16,7 @@ import numpy as np
 
 from murmura_tpu.attacks.base import Attack
 from murmura_tpu.core.rounds import RoundProgram
-from murmura_tpu.telemetry.host_spans import span
+from murmura_tpu.telemetry.host_spans import add_counters, span
 from murmura_tpu.topology.base import Topology
 from murmura_tpu.topology.dynamic import MobilityModel
 
@@ -1084,6 +1084,7 @@ class Network:
             self.history, round_num, metrics, self.compromised,
             self.program.evidential, self.attack is not None,
         )
+        add_counters({f"agg_{k}": self.history[f"agg_{k}"][-1] for k in last_stats})
 
         if self.telemetry is not None:
             # Per-node arrays of the recorded round (accuracy, agg_* rule

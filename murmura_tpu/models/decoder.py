@@ -81,8 +81,12 @@ norms, the softmax, the router and the residual stream are float32.
 
 Labels (``jax.named_scope``; docs/OBSERVABILITY.md): ``murmura.attention``,
 ``murmura.router`` (scores, top-k, counts; the bias step in
-``after_step``), ``murmura.experts`` (the pairs' rows, the dispatch, the
-grouped products, combine), ``murmura.ffn`` (the dense layers' and the shared experts'
+``after_step``), ``murmura.experts`` (the grouped products with
+``silu(gate) * up`` between them, the conditional that chooses the
+buffer's size, and inside it, forward and backward, ``murmura.rows``:
+where each pair goes; ``murmura.pairs``: the pairs' side, the repeat of
+``x``, the gathers into the buffer and back out, the weighted combine),
+``murmura.ffn`` (the dense layers' and the shared experts'
 SwiGLU), ``murmura.head`` (lookup, last norm, logits).
 """
 
@@ -269,20 +273,24 @@ def experts_at(buffer_rows, top_k, dtype, ints, floats):
     every group as routed: ``row_of`` [pairs] a held pair's row."""
     (row_of, mine, sizes), (p, x, weights) = ints, floats
     t, pairs = x.shape[0], x.shape[0] * top_k
-    row_of = jnp.where(mine, row_of, buffer_rows)
-    pair_of = jnp.zeros((buffer_rows,), jnp.int32).at[row_of].set(
-        jnp.arange(pairs, dtype=jnp.int32), mode="drop"
-    )
-    filled = jnp.zeros((buffer_rows,), bool).at[row_of].set(True, mode="drop")
-    row_of = jnp.minimum(row_of, buffer_rows - 1)
-    rows = _take(jnp.repeat(x, top_k, axis=0), pair_of, filled, row_of, mine)
+    with jax.named_scope("murmura.rows"):
+        row_of = jnp.where(mine, row_of, buffer_rows)
+        pair_of = jnp.zeros((buffer_rows,), jnp.int32).at[row_of].set(
+            jnp.arange(pairs, dtype=jnp.int32), mode="drop"
+        )
+        filled = jnp.zeros((buffer_rows,), bool).at[row_of].set(True, mode="drop")
+        row_of = jnp.minimum(row_of, buffer_rows - 1)
+    with jax.named_scope("murmura.pairs"):
+        rows = _take(jnp.repeat(x, top_k, axis=0), pair_of, filled, row_of, mine)
     grouped = lambda a, w: _grouped(a, w, sizes, dtype)
     inner = jax.nn.silu(grouped(rows, p["gate"])) * grouped(rows, p["up"])
+    down = grouped(inner, p["down"])
     # What a product leaves behind the last group, forward or backward,
     # is not defined: only rows that hold a pair are ever taken back
     # (``_take``: a where, never a product with 0).
-    y = _take(grouped(inner, p["down"]), row_of, mine, pair_of, filled)
-    return (y.reshape(t, top_k, -1) * weights[..., None]).sum(axis=1)
+    with jax.named_scope("murmura.pairs"):
+        y = _take(down, row_of, mine, pair_of, filled)
+        return (y.reshape(t, top_k, -1) * weights[..., None]).sum(axis=1)
 
 
 def experts(p, x, chosen, weights, *, held, first_held, top_k, n_routed_experts,
@@ -304,20 +312,21 @@ def experts(p, x, chosen, weights, *, held, first_held, top_k, n_routed_experts,
     last has a row for every pair and the padding of every group, so no
     pair is dropped at any imbalance.  A pair's row does not depend on the
     buffer's size, so neither does the result."""
-    local = chosen.reshape(-1) - first_held  # a pair's expert, from the first held
-    mine = (local >= 0) & (local < held)
-    one_hot = (local[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
-    rank = jnp.take_along_axis(  # a pair's place among its expert's pairs
-        jnp.cumsum(one_hot, axis=0), jnp.clip(local, 0, held - 1)[:, None], axis=1
-    )[:, 0] - 1
-    sizes = -(-one_hot.sum(axis=0) // GROUP_ALIGN) * GROUP_ALIGN
-    starts = jnp.cumsum(sizes) - sizes
-    needed = sizes.sum()
-    floor_rows, steps = ladder(x.shape[0], top_k, held, n_routed_experts)
-    # Rows of zeros behind the last expert's own, up to the floor.
-    sizes = sizes.at[held - 1].add(jnp.maximum(floor_rows - needed, 0))
-    row_of = starts[jnp.clip(local, 0, held - 1)] + rank
-    step = (needed > jnp.asarray(steps[:-1], jnp.int32)).sum()
+    with jax.named_scope("murmura.rows"):
+        local = chosen.reshape(-1) - first_held  # a pair's expert, from the first held
+        mine = (local >= 0) & (local < held)
+        one_hot = (local[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
+        rank = jnp.take_along_axis(  # a pair's place among its expert's pairs
+            jnp.cumsum(one_hot, axis=0), jnp.clip(local, 0, held - 1)[:, None], axis=1
+        )[:, 0] - 1
+        sizes = -(-one_hot.sum(axis=0) // GROUP_ALIGN) * GROUP_ALIGN
+        starts = jnp.cumsum(sizes) - sizes
+        needed = sizes.sum()
+        floor_rows, steps = ladder(x.shape[0], top_k, held, n_routed_experts)
+        # Rows of zeros behind the last expert's own, up to the floor.
+        sizes = sizes.at[held - 1].add(jnp.maximum(floor_rows - needed, 0))
+        row_of = starts[jnp.clip(local, 0, held - 1)] + rank
+        step = (needed > jnp.asarray(steps[:-1], jnp.int32)).sum()
     branches = [partial(experts_at, n, top_k, dtype) for n in steps]
     return _switched(branches, step, (row_of, mine, sizes), (p, x, weights)), step
 
@@ -330,17 +339,31 @@ def bias_step(bias, counts, speed):
     return bias + step.astype(bias.dtype)
 
 
-def router_counters(counts, took, bias, first_held, held) -> Dict[str, Any]:
+def rows_multiplied(counts, t, top_k, held, first_held, n_routed_experts):
+    """The rows the grouped products multiply for one sequence of ``t``
+    positions in each expert layer, from the counts of its choice [layers,
+    experts]: every held expert's pairs in whole tiles, at least the floor
+    (``experts``' ``sizes`` summed)."""
+    floor_rows, _ = ladder(t, top_k, held, n_routed_experts)
+    mine = counts[..., first_held:first_held + held]
+    return jnp.maximum((jnp.ceil(mine / GROUP_ALIGN) * GROUP_ALIGN).sum(axis=-1),
+                       float(floor_rows))
+
+
+def router_counters(counts, took, rows, bias, first_held, held) -> Dict[str, Any]:
     """A node's router counters (docs/OBSERVABILITY.md) from the counts of
-    its choice [layers, experts] and the ladder's steps taken [layers,
-    steps] over the steps of a round, and its selection bias."""
+    its choice [layers, experts], the ladder's steps taken [layers, steps]
+    and the rows the grouped products multiplied [layers] over the steps
+    of a round, and its selection bias."""
     total = jnp.maximum(counts.sum(), 1.0)
     mean = jnp.maximum(counts.mean(axis=-1), 1e-30)
+    pairs = counts[:, first_held:first_held + held].sum()
     return {
         "moe.load_max_over_mean": (counts.max(axis=-1) / mean).max(),
-        "moe.held_share": counts[:, first_held:first_held + held].sum() / total,
+        "moe.held_share": pairs / total,
         "moe.bias_abs_max": jnp.abs(bias.astype(jnp.float32)).max(),
         "moe.rows_first_step_share": took[:, 0].sum() / jnp.maximum(took.sum(), 1.0),
+        "moe.padding_share": 1.0 - pairs / jnp.maximum(rows.sum(), 1.0),
     }
 
 
@@ -523,7 +546,8 @@ def make_deepseek_v3(
     def sequence(params, ids):
         """logits [T, V], the choice's counts [moe layers, experts], which
         step of the buffer's ladder each expert layer took [moe layers,
-        steps] (one-hot), the balance loss summed over the expert layers."""
+        steps] (one-hot), the rows its grouped products multiplied [moe
+        layers], the balance loss summed over the expert layers."""
         with jax.named_scope("murmura.head"):
             h = params["embed"][ids].astype(jnp.float32)
         if dense_layers:
@@ -541,20 +565,23 @@ def make_deepseek_v3(
             )
             took = jax.nn.one_hot(step, steps, dtype=jnp.float32)
             balance = per_layer.sum()
+        rows = rows_multiplied(counts, ids.shape[0], top_k, held, first_held, n_routed_experts)
         with jax.named_scope("murmura.head"):
             logits = _einsum(
                 "th,hv->tv", rms_norm(h, params["final_norm"], rms_norm_eps),
                 params["head"], cd,
             )
-        return logits, {"counts": counts, "ladder": took}, balance
+        return logits, {"counts": counts, "ladder": took, "rows": rows}, balance
 
     def apply_train(params, x, key=None):
         """``(logits [B, T, V], auxiliary)``: ``"loss"`` [B], a sample's
         weighted balance loss, which the round adds to its likelihood, and
         ``"step"``, what ``after_step`` and ``step_metrics`` take summed
         over the samples the batch's mask keeps: ``"counts"`` [B, moe
-        layers, experts] of the choice and ``"ladder"`` [B, moe layers,
-        steps], one-hot, the step of the buffer's ladder a layer took."""
+        layers, experts] of the choice, ``"ladder"`` [B, moe layers,
+        steps], one-hot, the step of the buffer's ladder a layer took, and
+        ``"rows"`` [B, moe layers], the rows its grouped products
+        multiplied (``rows_multiplied``)."""
         # One sequence after another: a sequence's products are as wide as
         # the chip wants them, and a batch axis would multiply what is live.
         logits, step, balance = jax.lax.map(lambda ids: sequence(params, ids), x)
@@ -579,7 +606,7 @@ def make_deepseek_v3(
         the counts of the steps it took this round and its trained state."""
         if not moe_layers:
             return {}
-        return router_counters(step["counts"], step["ladder"],
+        return router_counters(step["counts"], step["ladder"], step["rows"],
                                params["moe_layers"]["router"]["bias"], first_held, held)
 
     return Model(

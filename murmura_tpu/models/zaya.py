@@ -59,7 +59,9 @@ channels, ``G = Hq / Hk``, ``g(h) = h // G``.
 Labels (``jax.named_scope``; docs/OBSERVABILITY.md): ``murmura.cca`` (the
 whole attention sublayer), inside it ``murmura.mix`` (the two
 convolutions, the value shift and the q-k mean: what no other attention
-has), ``murmura.router``, ``murmura.experts``, ``murmura.head``.
+has), ``murmura.router``, ``murmura.experts`` (with ``decoder.experts``'
+labels inside it, and the residual scales' sum under ``murmura.pairs``),
+``murmura.head``.
 """
 
 import math
@@ -72,6 +74,7 @@ import jax.numpy as jnp
 from murmura_tpu.models.core import Model, resolve_dtype
 from murmura_tpu.models.decoder import (
     HIGHEST, _einsum, bias_step, experts, ladder, rms_norm, rotate, router_counters,
+    rows_multiplied,
 )
 from murmura_tpu.ops.attention import KEEP_RESIDUALS, causal_attention
 
@@ -277,14 +280,18 @@ def make_zaya1(
         with jax.named_scope("murmura.router"):
             chosen, weight, counts, carried = route(p["router"], x, carried, first)
         with jax.named_scope("murmura.experts"):
-            y, step = experts(p["experts"], x, chosen[:, None], weight[:, None], **dispatch)
-            h = s["moe_h"] * h + s["moe_out"] * y
+            with jax.named_scope("murmura.pairs"):  # top-1: a pair a position
+                pair_expert, pair_weight = chosen[:, None], weight[:, None]
+            y, step = experts(p["experts"], x, pair_expert, pair_weight, **dispatch)
+            with jax.named_scope("murmura.pairs"):
+                h = s["moe_h"] * h + s["moe_out"] * y
         return (h, carried), (counts, weight.sum(), step)
 
     def sequence(params, ids):
         """logits [T, V]; the choice's counts [layers, experts], the sum of
         ``p_chosen`` over positions [layers], the step of the buffer's
-        ladder each layer took [layers, steps] (one-hot)."""
+        ladder each layer took [layers, steps] (one-hot), the rows its
+        grouped products multiplied [layers]."""
         with jax.named_scope("murmura.head"):
             h = params["embed"][ids].astype(jnp.float32)
         carried = jnp.zeros((ids.shape[0], router_hidden_size), jnp.float32)
@@ -293,19 +300,22 @@ def make_zaya1(
             (params["layers"], jnp.arange(layers) == 0),
         )
         steps = len(ladder(ids.shape[0], 1, held, num_experts)[1])
+        rows = rows_multiplied(counts, ids.shape[0], 1, held, first_held, num_experts)
         with jax.named_scope("murmura.head"):
             logits = _einsum(
                 "th,vh->tv", rms_norm(h, params["final_norm"], eps), params["embed"], cd
             )
         return logits, {"counts": counts, "chosen_weight": chosen_weight,
-                        "ladder": jax.nn.one_hot(step, steps, dtype=jnp.float32)}
+                        "ladder": jax.nn.one_hot(step, steps, dtype=jnp.float32),
+                        "rows": rows}
 
     def apply_train(params, x, key=None):
         """``(logits [B, T, V], auxiliary)``: ``"loss"`` [B], zeros (no
         auxiliary loss), and ``"step"``, what ``after_step`` and
         ``step_metrics`` take summed over the samples the batch's mask
         keeps: ``"counts"`` [B, layers, experts], ``"chosen_weight"`` [B,
-        layers] and ``"ladder"`` [B, layers, steps] (``sequence``)."""
+        layers], ``"ladder"`` [B, layers, steps] and ``"rows"`` [B, layers]
+        (``sequence``)."""
         logits, step = jax.lax.map(lambda ids: sequence(params, ids), x)
         return logits, {"loss": jnp.zeros((x.shape[0],), jnp.float32), "step": step}
 
@@ -326,7 +336,7 @@ def make_zaya1(
         goes to 1 stops learning."""
         counts = step["counts"]
         return {
-            **router_counters(counts, step["ladder"],
+            **router_counters(counts, step["ladder"], step["rows"],
                               params["layers"]["router"]["bias"], first_held, held),
             "moe.chosen_weight_mean": step["chosen_weight"].sum()
             / jnp.maximum(counts.sum(), 1.0),

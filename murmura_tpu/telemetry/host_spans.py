@@ -25,6 +25,10 @@ one bracket:
   closed (the untraced truth of the same loop) the rise less the ring.
   With no session the ring stays empty.
 
+Beside the spans, ``add_counters`` keeps a table of the round's counters
+(``core/network.py`` adds every recorded round's ``agg_*`` means), with the
+same snapshot at the start of the newest session.
+
 There is no switch of its own: "tracing on" is "a profiler session is
 active".  Off, a span costs two clock reads, one flag test and a dict
 update.
@@ -44,18 +48,20 @@ _lock = threading.Lock()
 _spans: Dict[str, List[float]] = {}  # name -> [count, seconds]
 _first_dispatch: Dict[str, List[float]] = {}  # the same, compiling spans only
 _before_session: Dict[str, List[float]] = {}  # _spans as the newest session began
+_counters: Dict[str, List[float]] = {}  # name -> [rounds, sum of the values]
+_counters_before_session: Dict[str, List[float]] = {}
 _ring: "collections.deque[Dict[str, Any]]" = collections.deque(maxlen=RING_SPANS)
 _ids = itertools.count(1)
 _open = threading.local()  # .stack: ids of this thread's open recorded spans
 _tracing = False  # whether the last span entered under a profiler session
 
 
-def _add(table: Dict[str, List[float]], name: str, seconds: float) -> None:
+def _add(table: Dict[str, List[float]], name: str, value: float) -> None:
     row = table.get(name)
     if row is None:
         row = table[name] = [0, 0.0]
     row[0] += 1
-    row[1] += seconds
+    row[1] += value
 
 
 def _copy(table: Dict[str, List[float]]) -> Dict[str, List[float]]:
@@ -81,12 +87,13 @@ class span:
         self._annotation = self._record = None
 
     def __enter__(self) -> "span":
-        global _tracing, _before_session
+        global _tracing, _before_session, _counters_before_session
         tracing = TraceAnnotation.is_enabled()
         if tracing and not _tracing:
             with _lock:  # a new session: the ring is this one's
                 _ring.clear()
                 _before_session = _copy(_spans)
+                _counters_before_session = _copy(_counters)
         _tracing = tracing
         if tracing:
             if self._step:
@@ -132,17 +139,28 @@ class span:
                 _ring.append(record)
 
 
+def add_counters(values: Dict[str, float]) -> None:
+    """One recorded round's counters into the table ``counters``."""
+    with _lock:
+        for name, value in values.items():
+            _add(_counters, name, value)
+
+
 def totals() -> Dict[str, Dict[str, List[float]]]:
-    """The tables, ``name -> [count, seconds]`` since the process started:
-    ``spans`` (every span), ``first_dispatch`` (the spans with a
-    ``compiles`` counter during which a program was compiled or loaded) and
-    ``spans_before_session`` (``spans`` as it stood when the newest
-    profiler session's first span opened; empty before any session)."""
+    """The tables since the process started: ``spans`` (every span),
+    ``first_dispatch`` (the spans with a ``compiles`` counter during which a
+    program was compiled or loaded), ``name -> [count, seconds]``;
+    ``counters``, ``name -> [rounds, sum]`` (``add_counters``); and
+    ``spans_before_session`` and ``counters_before_session``, the two
+    tables as they stood when the newest profiler session's first span
+    opened (empty before any session)."""
     with _lock:
         return {
             "spans": _copy(_spans),
             "first_dispatch": _copy(_first_dispatch),
             "spans_before_session": _copy(_before_session),
+            "counters": _copy(_counters),
+            "counters_before_session": _copy(_counters_before_session),
         }
 
 

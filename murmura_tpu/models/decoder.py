@@ -84,8 +84,11 @@ Labels (``jax.named_scope``; docs/OBSERVABILITY.md): ``murmura.attention``,
 ``after_step``), ``murmura.experts`` (the grouped products with
 ``silu(gate) * up`` between them, the conditional that chooses the
 buffer's size, and inside it, forward and backward, ``murmura.rows``:
-where each pair goes; ``murmura.pairs``: the pairs' side, the repeat of
-``x``, the gathers into the buffer and back out, the weighted combine),
+where each pair goes; ``murmura.pairs``: the pairs' side, the gather of
+``x`` into the buffer, the weighted combine of the ``down`` product's rows
+back into ``[T, hidden]`` (``ops.row_scatter.row_scatter_add`` where a
+position has several pairs) and their cotangents: no array of a row for
+every pair, ``[T * top_k, hidden]``, is written),
 ``murmura.ffn`` (the dense layers' and the shared experts'
 SwiGLU), ``murmura.head`` (lookup, last norm, logits).
 """
@@ -99,6 +102,7 @@ import jax.numpy as jnp
 
 from murmura_tpu.models.core import Model, resolve_dtype
 from murmura_tpu.ops.attention import KEEP_RESIDUALS, causal_attention
+from murmura_tpu.ops.row_scatter import row_scatter_add
 
 # Rows to which an expert's group is aligned: the tile of rows in which the
 # TPU compiler's grouped product works (read off its compiled metadata: 55
@@ -189,25 +193,80 @@ def swiglu(p, x, dtype):
     return _einsum("tf,fh->th", jax.nn.silu(gate) * up, p["down"], dtype)
 
 
+def _to_positions(rows, scale, place, pair_of, row_of, mine):
+    """Each position's held pairs' rows of ``rows`` times their ``scale``
+    [T, top_k], summed: [T, hidden] float32.  ``place`` and ``pair_of``
+    [rows] are a row's position (-1 where it holds no pair) and pair,
+    ``row_of`` and ``mine`` [pairs] a pair's row and whether it is held; a
+    row that holds no pair never enters a sum.  At one pair a position, a
+    gather of each position's row, which writes [T, hidden]; at several,
+    where a gather would write a row for every pair ([T * top_k, hidden]),
+    the rows are added into their positions (``row_scatter_add``)."""
+    t, top_k = scale.shape
+    if top_k == 1:
+        return jnp.where(mine[:, None], scale * rows[row_of], 0.0)
+    return row_scatter_add(rows, place, scale.reshape(-1)[pair_of], t)
+
+
 @jax.custom_vjp
-def _take(x, index, keep, back, back_keep):
-    """``x[index[r]]`` where ``keep[r]``, zeros elsewhere, for an ``index``
-    that names every row ``q`` with ``back_keep[q]`` exactly once, at
-    ``r = back[q]``: the cotangent is then a gather too (``g[back[q]]``
-    where ``back_keep[q]``), where autodiff would scatter."""
-    return jnp.where(keep[:, None], x[index], 0.0)
+def _into_buffer(x, place, pair_of, row_of, mine):
+    """The pairs' buffer straight from the sequence ``x`` [T, hidden]: a row
+    holds its pair's position's ``x``, zeros where it holds no pair.  The
+    cotangent of ``x`` is the buffer's cotangent taken back to the
+    positions (``_to_positions``), where autodiff would scatter."""
+    return jnp.where((place >= 0)[:, None], x[jnp.maximum(place, 0)], 0.0)
 
 
-def _take_fwd(x, index, keep, back, back_keep):
-    return _take(x, index, keep, back, back_keep), (back, back_keep)
+def _into_buffer_fwd(x, *pairs_at):
+    # A zero-size stand-in carries the sequence's length and dtype.
+    return _into_buffer(x, *pairs_at), (pairs_at, jnp.zeros((x.shape[0], 0), x.dtype))
 
 
-def _take_bwd(saved, g):
-    back, back_keep = saved
-    return jnp.where(back_keep[:, None], g[back], 0.0), None, None, None, None
+def _into_buffer_bwd(saved, g):
+    pairs_at, like = saved
+    t = like.shape[0]
+    ones = jnp.ones((t, pairs_at[-1].shape[0] // t), jnp.float32)
+    return (_to_positions(g, ones, *pairs_at).astype(like.dtype),) + (None,) * len(pairs_at)
 
 
-_take.defvjp(_take_fwd, _take_bwd)
+_into_buffer.defvjp(_into_buffer_fwd, _into_buffer_bwd)
+
+
+@jax.custom_vjp
+def _combine(down, weights, place, pair_of, row_of, mine):
+    """The routed result [T, hidden]: ``_to_positions`` of ``down`` with the
+    pairs' ``weights`` [T, top_k].  Both cotangents are gathers: ``down``'s
+    a row's weight times its position's cotangent, over the buffer's rows;
+    that of ``weights`` a row's dot with its position's cotangent, taken
+    over the buffer's rows and back to the row's pair (at one pair a
+    position, over the positions, as the combine itself)."""
+    return _to_positions(down, weights, place, pair_of, row_of, mine)
+
+
+def _combine_fwd(*args):
+    return _combine(*args), args
+
+
+def _combine_bwd(saved, g):
+    down, weights, place, pair_of, row_of, mine = saved
+    held = (place >= 0)[:, None]
+    at = jnp.maximum(place, 0)
+    if weights.shape[1] == 1:
+        # One pair a position: the weight and the dot are taken at the
+        # positions, [T, hidden], and only the product goes to the rows.
+        d_down = jnp.where(held, (weights * g)[at], 0.0)
+        dot = (down[row_of] * g).sum(axis=-1, keepdims=True)
+        return d_down, jnp.where(mine[:, None], dot, 0.0), None, None, None, None
+    g_rows = jnp.where(held, g[at], 0.0)
+    weight = weights.reshape(-1)[pair_of]
+    d_down = jnp.where(held, weight[:, None] * g_rows, 0.0)
+    # What lies in a row that holds no pair is never taken.
+    dot = (jnp.where(held, down, 0.0) * g_rows).sum(axis=-1)
+    d_weights = jnp.where(mine, dot[row_of], 0.0).reshape(weights.shape)
+    return d_down, d_weights, None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _switched(branches, index, ints, floats):
@@ -272,7 +331,7 @@ def experts_at(buffer_rows, top_k, dtype, ints, floats):
     """``experts`` through a buffer of ``buffer_rows`` rows that holds
     every group as routed: ``row_of`` [pairs] a held pair's row."""
     (row_of, mine, sizes), (p, x, weights) = ints, floats
-    t, pairs = x.shape[0], x.shape[0] * top_k
+    pairs = x.shape[0] * top_k
     with jax.named_scope("murmura.rows"):
         row_of = jnp.where(mine, row_of, buffer_rows)
         pair_of = jnp.zeros((buffer_rows,), jnp.int32).at[row_of].set(
@@ -281,16 +340,17 @@ def experts_at(buffer_rows, top_k, dtype, ints, floats):
         filled = jnp.zeros((buffer_rows,), bool).at[row_of].set(True, mode="drop")
         row_of = jnp.minimum(row_of, buffer_rows - 1)
     with jax.named_scope("murmura.pairs"):
-        rows = _take(jnp.repeat(x, top_k, axis=0), pair_of, filled, row_of, mine)
+        pairs_at = (jnp.where(filled, pair_of // top_k, -1), pair_of, row_of, mine)
+        rows = _into_buffer(x, *pairs_at)
     grouped = lambda a, w: _grouped(a, w, sizes, dtype)
     inner = jax.nn.silu(grouped(rows, p["gate"])) * grouped(rows, p["up"])
     down = grouped(inner, p["down"])
     # What a product leaves behind the last group, forward or backward,
     # is not defined: only rows that hold a pair are ever taken back
-    # (``_take``: a where, never a product with 0).
+    # (``_combine`` reads no other row: a where or a row skipped, never a
+    # product with 0).
     with jax.named_scope("murmura.pairs"):
-        y = _take(down, row_of, mine, pair_of, filled)
-        return (y.reshape(t, top_k, -1) * weights[..., None]).sum(axis=1)
+        return _combine(down, weights, *pairs_at)
 
 
 def experts(p, x, chosen, weights, *, held, first_held, top_k, n_routed_experts,

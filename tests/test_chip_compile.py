@@ -336,25 +336,51 @@ def test_sharded_round_step_gains_no_collective(four_chips):
 # --- the decoder's expert layer at Moonlight's widths (models/decoder.py) ---
 
 
-def test_decoder_expert_layer_step_compiles_and_fits(one_chip):
+def _unfused(text):
+    """The instructions of a compiled module's text that lie outside every
+    fused computation, as ``(computation, line)``."""
+    computations, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            computations[name] = []
+        elif name is not None and line.startswith("  "):
+            computations[name].append(line)
+    fused = {callee for lines in computations.values() for line in lines if " fusion(" in line
+             for callee in re.findall(r"calls=%([\w.\-]+)", line)}
+    return [(name, line) for name, lines in computations.items() if name not in fused
+            for line in lines]
+
+
+def test_decoder_expert_layer_step_compiles_and_fits(one_chip, monkeypatch):
     """One expert layer of ``moonlight_16b_a3b_ep8`` (hidden 2048, latent
     attention of 16 heads, 64 experts routed and 8 held, 4,096 positions;
     a 1,024-row vocabulary so that the layer is what is compiled), forward,
-    recomputed and backward with bf16-resident parameters: the TPU compiler
-    takes the grouped products as its own kernel (``tpu_custom_call``: the
-    CPU tests see a dense product with masks in their place), once for each
+    recomputed and backward with bf16-resident parameters, as the chip
+    compiles it (the backend is the TPU's there): the TPU compiler takes
+    the grouped products as its own kernel (``tpu_custom_call``: the CPU
+    tests see a dense product with masks in their place), once for each
     size the pairs' buffer may take (8,192, 16,384 and 28,672 rows: the
-    dispatch is two conditionals, forward and backward), holds no
-    ``[heads, T, T]`` score array, and one layer's step (its recomputed
-    activations and the longest buffer's branch: 3,500,164,608 B of
-    temporaries, 3.26 GiB, when this was written; 3,473,661,440 with the
-    one size before) fits in a quarter of the chip."""
+    dispatch is two conditionals, forward and backward), the pairs' rows
+    go back to the positions through ``row_scatter_add`` (once a size each
+    way: the experts' result, and the cotangent of what fed the buffer;
+    under ``murmura.pairs``, which ``train_experts_pairs_ops_ms`` reads),
+    and outside the fused computations no instruction writes an array of
+    a row for every pair (``[24576, 2048]``, ``[4096, 6, 2048]`` or
+    ``[6, 4096, 2048]``, in any dtype: 36 of them when the pairs' side
+    repeated ``x``), no ``[heads, T, T]`` score array exists, and one
+    layer's step (its recomputed activations and the longest buffer's
+    branch: 1,456,727,552 B of temporaries, 1.36 GiB, when this was
+    written; 1,508,376,576 with ``x`` repeated) fits in a quarter of the
+    chip."""
     import json
     from pathlib import Path
 
     from murmura_tpu.models.registry import build_model
     from murmura_tpu.ops.losses import masked_next_token_cross_entropy
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     doc = json.loads((Path(__file__).resolve().parents[1]
                       / "benchmark/configs/moonlight_16b_a3b_ep8.json").read_text())
     params = dict(doc["model"]["params"], num_hidden_layers=1, first_k_dense_replace=0,
@@ -378,9 +404,19 @@ def test_decoder_expert_layer_step_compiles_and_fits(one_chip):
     compiled = jax.jit(gradients).lower(tree, ids, ids).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ragged-dot" in text
-    kernels = set(re.findall(r"= f32\[(\d+),\d+\]\S* custom-call\([^\n]*ragged-dot", text))
+    kernels = set(re.findall(r"%ragged-dot[\w.\-]* = f32\[(\d+),\d+\]\S* custom-call\(", text))
     assert kernels == {"8192", "16384", "28672"}
+    added = re.findall(r"%row_scatter_add\.\d+ = f32\[4096,2048\]\S* custom-call\("
+                       r"[^\n]*tpu_custom_call[^\n]*s32\[(\d+)\][^\n]*op_name=\"([^\"]*)\"", text)
+    assert sorted(rows for rows, _ in added) == sorted(["8192", "16384", "28672"] * 2)
+    assert all("murmura.experts/" in name and "murmura.pairs" in name for _, name in added)
     assert len(re.findall(r" conditional\(", text)) == 2
+    # An instruction's result type: what stands between ``=`` and its opcode.
+    result = lambda line: re.match(r"\s*%\S+ = (.*?) [a-z][\w.\-]*\(", line)
+    pairs_rows = re.compile(r"\[(24576,2048|4096,6,2048|6,4096,2048)\]")
+    written = [line for _, line in _unfused(text) if result(line)]
+    assert len(written) > 1000
+    assert not [line for line in written if pairs_rows.search(result(line).group(1))]
     assert not re.search(r"f32\[16,4096,4096\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
 
